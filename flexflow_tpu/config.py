@@ -9,12 +9,36 @@ spellings so reference launch scripts port over directly.
 from __future__ import annotations
 
 import dataclasses
+import os
 import sys
 from typing import List, Optional
 
 import jax
 
 from .ff_types import CompMode
+
+
+def enable_compile_cache() -> str:
+    """Place JAX's persistent compilation cache, once, where the program
+    first needs JAX (FFConfig()), and return the directory.
+
+    Where JAX_COMPILATION_CACHE_DIR is set, JAX already reads it: nothing
+    is set in code. Otherwise the cache is `<checkout>/.jax_cache`,
+    resolved from this package's own location — a fixed path, because a
+    directory that moves between runs (a tempdir, a pid, a timestamp)
+    never hits — and every executable is kept, so that a second run of
+    the same program compiles nothing and adds nothing."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache",
+    )
+    jax.config.update("jax_compilation_cache_dir", path)
+    if "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS" not in os.environ:
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 @dataclasses.dataclass
@@ -149,18 +173,15 @@ class FFConfig:
     iterations_per_dispatch: int = 1
 
     def __post_init__(self):
+        enable_compile_cache()
+        # a backend that cannot initialise raises here, at the first line
+        # of every program, instead of being counted as one worker
         if self.workersPerNode == 0:
-            try:
-                self.workersPerNode = max(1, jax.local_device_count())
-            except Exception:  # pragma: no cover - no backend at all
-                self.workersPerNode = 1
+            self.workersPerNode = max(1, jax.local_device_count())
         if self.numNodes == 1:
-            try:
-                # multi-host (runtime/distributed.py): one "node" per
-                # process, like the reference's one-Legion-rank-per-host
-                self.numNodes = max(1, jax.process_count())
-            except Exception:  # pragma: no cover  # fflint: disable=FFL002
-                pass
+            # multi-host (runtime/distributed.py): one "node" per
+            # process, like the reference's one-Legion-rank-per-host
+            self.numNodes = max(1, jax.process_count())
         argv = sys.argv[1:]
         if argv:
             self.parse_args(argv)

@@ -1317,6 +1317,7 @@ class FFModel:
         (default) or "decode" — the single-token HBM-roofline pricing
         compile_decode()'s second search runs under."""
         from ..search import CostModel, MachineModel, parse_machine_config
+        from ..search.machine_model import chip_spec_for
 
         cfg = self.config
         override = getattr(self, "_machine_override", None)
@@ -1332,7 +1333,8 @@ class FFModel:
                      else cfg.numNodes)
             workers = (cfg.search_num_workers if cfg.search_num_workers > 0
                        else cfg.workersPerNode)
-            machine = MachineModel(num_nodes=nodes, workers_per_node=workers)
+            machine = MachineModel(num_nodes=nodes, workers_per_node=workers,
+                                   chip=chip_spec_for(jax.devices()[0]))
         if cfg.machine_model_version >= 1 and not hasattr(machine, "topology"):
             from ..search.network import TopologyAwareMachineModel
 
@@ -2178,19 +2180,12 @@ class FFModel:
     # ------------------------------------------------------------------
     def _rng_key_data(self) -> list:
         """self._rng as a JSON-serializable list (checkpoint cursor)."""
-        try:
-            data = jax.random.key_data(self._rng)
-        except Exception:
-            data = self._rng
-        return np.asarray(data).tolist()
+        return np.asarray(jax.random.key_data(self._rng)).tolist()
 
     def _set_rng_from_key_data(self, data) -> None:
         arr = jnp.asarray(np.asarray(data, np.uint32))
-        try:
-            if jnp.issubdtype(self._rng.dtype, jax.dtypes.prng_key):
-                arr = jax.random.wrap_key_data(arr)
-        except Exception:  # fflint: disable=FFL002 — old jax: raw uint32 key
-            pass
+        if jnp.issubdtype(self._rng.dtype, jax.dtypes.prng_key):
+            arr = jax.random.wrap_key_data(arr)
         self._rng = arr
 
     def _save_resilient_ckpt(self, manager, step, epoch, batch_index,

@@ -30,8 +30,29 @@ import os
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+
+
+def pallas_compiled() -> bool:
+    """THE decision between the compiled (Mosaic) Pallas kernels and every
+    other attention path: compiled exactly when JAX's default backend is
+    the TPU. No other code asks the backend that question. Under the
+    default (auto) dispatch a backend that is not "tpu" takes the jnp
+    paths, counted in ff_attention_fallback_total where a kernel was
+    asked for — never the Pallas interpreter, which runs only where a
+    test passes interpret=True or FF_DECODE_IMPL=paged is set by hand
+    off the TPU."""
+    return jax.default_backend() == "tpu"
+
+
+def out_struct(shape, dtype, like):
+    """pallas_call out_shape that varies over the same manual mesh axes
+    as `like`: inside shard_map (check_vma) a kernel output without a vma
+    is rejected, and outside it the set is empty."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 def _chunk_scan(q, k, v, *, causal: bool, chunk_size: int, q_offset=0,
@@ -315,15 +336,6 @@ def _flash_bwd_kernel(*refs, causal: bool, scale: float,
         dq_ref[i] = (dq_acc * scale).astype(dq_ref.dtype)
 
 
-try:  # Pallas import is lazy-safe: CPU tests run interpret mode
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
-
-    HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    HAS_PALLAS = False
-
-
 def _bhsd_to_fold(x):
     b, s, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -387,8 +399,8 @@ def _flash_fwd_folded(qf, kf, vf, *, causal: bool, interpret: bool,
             pl.BlockSpec((g, 1, sq), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, dv), qf.dtype),
-            jax.ShapeDtypeStruct((bh, 1, sq), jnp.float32),
+            out_struct((bh, sq, dv), qf.dtype, qf),
+            out_struct((bh, 1, sq), jnp.float32, qf),
         ],
         interpret=interpret,
     )(*args)
@@ -440,9 +452,9 @@ def _flash_bwd_folded(qf, kf, vf, of, lse, dof, *, causal: bool,
             pl.BlockSpec((gg, sk, dv_d), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, sq, d), qf.dtype),
-            jax.ShapeDtypeStruct((bh, sk, d), kf.dtype),
-            jax.ShapeDtypeStruct((bh, sk, dv_d), vf.dtype),
+            out_struct((bh, sq, d), qf.dtype, qf),
+            out_struct((bh, sk, d), kf.dtype, qf),
+            out_struct((bh, sk, dv_d), vf.dtype, qf),
         ],
         interpret=interpret,
     )(*args)
@@ -519,14 +531,12 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 256,
     return _fold_to_bhsd(out, b, h)
 
 
-def local_attention(q, k, v, *, causal: bool = False,
-                    interpret: bool = False):
+def local_attention(q, k, v, *, causal: bool = False):
     """The single device-local streaming dispatch: fused Pallas kernel on
     TPU while its VMEM tile fits, chunked scan otherwise. Both the MHA
     op's streaming branch (ops/attention.py) and ulysses_attention route
     through here so the selection policy cannot drift between them."""
-    if (HAS_PALLAS and not interpret and jax.default_backend() == "tpu"
-            and flash_supported(q.shape[1], k.shape[1])):
+    if pallas_compiled() and flash_supported(q.shape[1], k.shape[1]):
         return flash_attention(q, k, v, causal)
     return chunked_attention(q, k, v, causal=causal)
 
@@ -535,8 +545,7 @@ def local_attention(q, k, v, *, causal: bool = False,
 # Ring attention (sequence/context parallelism over a mesh axis)
 # ---------------------------------------------------------------------------
 
-def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
-                      interpret: bool = False):
+def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False):
     """DeepSpeed-Ulysses-style sequence parallelism: q/k/v arrive sharded
     along the sequence dim over `axis_name` (LOCAL shards, inside
     shard_map). One all_to_all re-shards sequence->heads so each device
@@ -548,9 +557,7 @@ def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
     No reference equivalent (SURVEY §5: sequence parallelism absent
     there); the head-scatter recipe follows the public Ulysses pattern
     (PAPERS.md)."""
-    # psum of the literal 1 constant-folds to the axis size on every
-    # jax we support; lax.axis_size only exists on newer releases
-    n = getattr(lax, "axis_size", lambda a: lax.psum(1, a))(axis_name)
+    n = lax.axis_size(axis_name)
     h = q.shape[2]
     assert h % n == 0, f"heads {h} must divide the {axis_name} axis {n}"
     # (b, s/n, h, d) -> (b, s, h/n, d)
@@ -563,7 +570,7 @@ def ulysses_attention(q, k, v, axis_name: str, *, causal: bool = False,
                               tiled=True)
 
     qh, kh, vh = scatter_heads(q), scatter_heads(k), scatter_heads(v)
-    out = local_attention(qh, kh, vh, causal=causal, interpret=interpret)
+    out = local_attention(qh, kh, vh, causal=causal)
     return gather_heads(out)
 
 
@@ -578,9 +585,7 @@ def ring_attention(q, k, v, axis_name: str, *, causal: bool = False,
     No reference equivalent — this is the TPU build's first-class CP
     (SURVEY §5 gap); the blockwise formulation follows the public
     ring-attention recipe (PAPERS.md)."""
-    # psum of the literal 1 constant-folds to the axis size on every
-    # jax we support; lax.axis_size only exists on newer releases
-    n = getattr(lax, "axis_size", lambda a: lax.psum(1, a))(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     b, sq_local, h, d = q.shape
 

@@ -39,12 +39,10 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .attention import HAS_PALLAS, NEG_INF
-
-if HAS_PALLAS:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+from .attention import NEG_INF, out_struct
 
 
 def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -69,7 +67,7 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(start < length)
     def _accumulate():
-        q = q_ref[0].astype(jnp.float32)          # (1, d)
+        q = q_ref[0, 0].astype(jnp.float32)       # (1, d)
         k = k_ref[0, 0].astype(jnp.float32)       # (page_size, d)
         v = v_ref[0, 0].astype(jnp.float32)       # (page_size, dv)
         s = jax.lax.dot_general(
@@ -92,8 +90,8 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(page == n_pages - 1)
     def _finish():
-        o_ref[0] = (acc_ref[...] /
-                    jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] /
+                       jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
@@ -102,9 +100,8 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
 
     q (slots, heads, d); k_pages/v_pages (heads, num_pages, page_size,
     d/dv); page_table (slots, pages_per_slot) int32; lengths (slots,)
-    int32. Returns (slots, heads, dv). Requires Pallas (interpret=True
-    runs the same kernel on CPU)."""
-    assert HAS_PALLAS, "paged_flash_decode needs Pallas (jax.experimental)"
+    int32. Returns (slots, heads, dv). interpret=True runs the same
+    kernel on CPU."""
     b, h, d = q.shape
     page_size = k_pages.shape[2]
     dv = v_pages.shape[-1]
@@ -113,29 +110,36 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, h, n_pages),
+        # Mosaic tiles the LAST TWO block dims (multiples of (8, 128), or
+        # the array's full extent). q and the output carry one row per
+        # (slot, head), so they ride as (slots, heads, 1, d): the block's
+        # last two dims (1, d) are then the array's own, like the K/V
+        # page blocks' (page_size, d).
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda s, hh, i, pt, ln: (s, hh, 0)),
+            pl.BlockSpec((1, 1, 1, d),
+                         lambda s, hh, i, pt, ln: (s, hh, 0, 0)),
             pl.BlockSpec((1, 1, page_size, d),
                          lambda s, hh, i, pt, ln: (hh, pt[s, i], 0, 0)),
             pl.BlockSpec((1, 1, page_size, dv),
                          lambda s, hh, i, pt, ln: (hh, pt[s, i], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, dv),
-                               lambda s, hh, i, pt, ln: (s, hh, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, dv),
+                               lambda s, hh, i, pt, ln: (s, hh, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
             pltpu.VMEM((1, dv), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, page_size=page_size,
                           scale=scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
+        out_shape=out_struct((b, h, 1, dv), q.dtype, q),
         interpret=interpret,
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
-      q, k_pages, v_pages)
+      q[:, :, None, :], k_pages, v_pages)
+    return out[:, :, 0, :]
 
 
 def paged_decode_reference(q, k_pages, v_pages, page_table, lengths):

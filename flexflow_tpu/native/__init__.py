@@ -7,21 +7,30 @@ gather, reference python/flexflow_dataloader.cc) and the task-graph
 simulator + MCMC annealing loop (src/simulator.cc — reference
 src/runtime/simulator.cc + model.cc mcmc_optimize).
 
-The shared library is built on first use with g++ (cached next to the
-sources); every consumer has a pure-Python fallback so the framework works
-without a toolchain.
+The shared library is built on first use with g++ into a fixed path next
+to the sources, with the sources' content hash beside it: a library whose
+recorded hash is not the hash of the sources here is stale and is rebuilt
+(file times say nothing — a copy of the tree can reorder them). Every
+consumer has a pure-Python fallback so the framework works without a
+toolchain; a failed build is logged, never silent.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 import threading
 from typing import Optional
 
+logger = logging.getLogger(__name__)
+
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src")
 _LIB_PATH = os.path.join(_HERE, "libffnative.so")
+_HASH_PATH = _LIB_PATH + ".sha256"
+_BUILD_CMD = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread"]
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
@@ -33,11 +42,23 @@ def _sources():
     )
 
 
+def _source_hash() -> str:
+    """sha256 over the build command and every source's name + bytes."""
+    h = hashlib.sha256(" ".join(_BUILD_CMD).encode())
+    for src in _sources():
+        h.update(os.path.basename(src).encode())
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
 def _needs_build() -> bool:
-    if not os.path.exists(_LIB_PATH):
+    try:
+        with open(_HASH_PATH) as f:
+            recorded = f.read().strip()
+    except OSError:
         return True
-    lib_mtime = os.path.getmtime(_LIB_PATH)
-    return any(os.path.getmtime(s) > lib_mtime for s in _sources())
+    return not os.path.exists(_LIB_PATH) or recorded != _source_hash()
 
 
 def build(force: bool = False) -> Optional[str]:
@@ -46,15 +67,24 @@ def build(force: bool = False) -> Optional[str]:
     with _lock:
         if not force and not _needs_build():
             return _LIB_PATH
-        cmd = [
-            "g++", "-O3", "-shared", "-fPIC", "-std=c++17", "-pthread",
-            "-o", _LIB_PATH, *_sources(),
-        ]
         try:
-            subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+            # the hash goes first and comes back last, so an interrupted
+            # build leaves a library that reads as stale
+            if os.path.exists(_HASH_PATH):
+                os.unlink(_HASH_PATH)
+            subprocess.run([*_BUILD_CMD, "-o", _LIB_PATH, *_sources()],
+                           check=True, capture_output=True, timeout=120)
+            with open(_HASH_PATH, "w") as f:
+                f.write(_source_hash() + "\n")
             _build_failed = False
             return _LIB_PATH
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
+            detail = getattr(e, "stderr", b"") or b""
+            logger.warning(
+                "native build failed (%r)%s — the Python simulator and "
+                "data loader run instead", e,
+                ": " + detail.decode(errors="replace")[-500:]
+                if detail else "")
             _build_failed = True
             return None
 

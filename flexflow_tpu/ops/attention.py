@@ -70,8 +70,6 @@ def _dropout_fallback(impl: str, op_name: str, reason: str) -> None:
         # paged flash-decode fallbacks (serving): the requested paged
         # kernel cannot prove exactness for this step, so the dense
         # per-row masked path runs instead
-        "paged_pallas": "the paged flash-decode kernel needs Pallas "
-                        "(jax.experimental.pallas unavailable)",
         "paged_block": "the paged flash-decode kernel attends ONE query "
                        "token per slot; multi-token blocks (prefill) "
                        "keep the dense masked path",
@@ -178,7 +176,9 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
             f"FF_ATTENTION_IMPL={impl!r}: "
             "expected auto|dense|flash|chunked|ring|ulysses"
         )
-    from ..kernels.attention import flash_supported
+    from ..kernels.attention import flash_supported, pallas_compiled
+
+    on_tpu = pallas_compiled()
 
     # RNG-threaded flash dropout: the fused Pallas kernels regenerate a
     # counter-based keep-mask per VMEM tile (kernels/attention.py), so
@@ -189,7 +189,7 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     flash_dropout_ok = (
         use_dropout
         and impl in ("auto", "flash")
-        and jax.default_backend() == "tpu"
+        and on_tpu
         and flash_supported(seq_len, kv_len)
         and data_degree * model_degree * seq_degree * expert_degree == 1
     )
@@ -198,10 +198,9 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
             _dropout_fallback(impl, ctx.op_name, "kernel")
         elif impl == "flash" or (
                 impl == "auto"
-                and (jax.default_backend() == "tpu"
-                     or score_bytes > 256 * 1024 * 1024)):
+                and (on_tpu or score_bytes > 256 * 1024 * 1024)):
             # without dropout this call would have streamed
-            if jax.default_backend() != "tpu":
+            if not on_tpu:
                 _dropout_fallback(impl, ctx.op_name, "backend")
             elif not flash_supported(seq_len, kv_len):
                 _dropout_fallback(impl, ctx.op_name, "seq")
@@ -213,7 +212,7 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     # projection einsum for free instead of costing a per-layer HBM
     # round-trip each way (fold + unfold, fwd and bwd).
     if (impl in ("auto", "flash")
-            and jax.default_backend() == "tpu"
+            and on_tpu
             and (not use_dropout or flash_dropout_ok)
             and flash_supported(seq_len, kv_len)
             and data_degree * model_degree * seq_degree * expert_degree
@@ -283,7 +282,7 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     # chunked — never on a GSPMD-sharded pallas_call.
     prefer_flash = (
         impl == "auto"
-        and jax.default_backend() == "tpu"
+        and on_tpu
         and flash_supported(seq_len, kv_len)
         and (not mesh_nontrivial or flash_shardable or seq_degree > 1)
     )
@@ -334,19 +333,17 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
         from jax.sharding import PartitionSpec as P
 
         from ..kernels.attention import ring_attention, ulysses_attention
-        from ..parallel.pipeline import shard_map
 
         if use_ulysses:
             fn = functools.partial(
-                ulysses_attention, axis_name="seq", causal=params.causal,
-                interpret=jax.default_backend() != "tpu",
+                ulysses_attention, axis_name="seq", causal=params.causal
             )
         else:
             fn = functools.partial(
                 ring_attention, axis_name="seq", causal=params.causal
             )
         spec = P("data", "seq", "model", None)
-        attn = shard_map(
+        attn = jax.shard_map(
             fn,
             mesh=ctx.mesh,
             in_specs=(spec, spec, spec),
@@ -377,10 +374,8 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
             if flash_shardable:
                 from jax.sharding import PartitionSpec as P
 
-                from ..parallel.pipeline import shard_map
-
                 spec = P("data", None, "model", None)
-                attn = shard_map(
+                attn = jax.shard_map(
                     functools.partial(local_attention, causal=params.causal),
                     mesh=ctx.mesh,
                     in_specs=(spec, spec, spec),
@@ -499,19 +494,17 @@ def _forward_decode(params, weights, inputs, ctx, cache, t):
     if impl not in ("auto", "dense", "paged"):
         raise ValueError(
             f"FF_DECODE_IMPL={impl!r}: expected one of auto|dense|paged")
-    use_paged = False
-    if impl != "dense":
-        from ..kernels.attention import HAS_PALLAS
-        if impl == "paged":
-            if not HAS_PALLAS:
-                _dropout_fallback(impl, ctx.op_name, "paged_pallas")
-            elif q.shape[1] != 1:
-                _dropout_fallback(impl, ctx.op_name, "paged_block")
-            else:
-                use_paged = True
-        else:  # auto: interpret mode on CPU would lose to the XLA dense
-            use_paged = (HAS_PALLAS and q.shape[1] == 1
-                         and jax.default_backend() == "tpu")
+    from ..kernels.attention import pallas_compiled
+
+    use_paged = interpret = False
+    if impl == "paged":
+        if q.shape[1] != 1:
+            _dropout_fallback(impl, ctx.op_name, "paged_block")
+        else:
+            # asked for by hand: off the TPU that means the interpreter
+            use_paged, interpret = True, not pallas_compiled()
+    elif impl == "auto":  # interpret mode on CPU would lose to XLA dense
+        use_paged = q.shape[1] == 1 and pallas_compiled()
     if use_paged:
         from ..kernels.decode import (
             decode_page_size,
@@ -526,8 +519,7 @@ def _forward_decode(params, weights, inputs, ctx, cache, t):
         lengths = (t.astype(jnp.int32) if per_row_t
                    else jnp.full((b,), t, jnp.int32)) + 1
         attn = paged_flash_decode(
-            q[:, 0], kp, vp, table, lengths,
-            interpret=jax.default_backend() != "tpu",
+            q[:, 0], kp, vp, table, lengths, interpret=interpret,
         )[:, None]                     # (b, 1, h, dv)
     else:
         scale = 1.0 / jnp.sqrt(jnp.asarray(params.qk_head_dim, jnp.float32))

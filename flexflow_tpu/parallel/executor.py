@@ -1042,13 +1042,13 @@ class PCGExecutor:
         """donate_argnums for the train state: donate on accelerators,
         where in-place buffer reuse halves peak weight/opt-state HBM —
         but NOT on CPU. On the CPU backend, an executable deserialized
-        from the persistent compilation cache can lose the input/output
-        aliasing metadata for donated buffers (observed on jax 0.4.37:
-        the final state's buffers get reclaimed while still referenced,
-        and live `model.state` arrays read back garbage once a later
-        computation reuses the memory). CPU donation buys nothing —
-        host RAM is not the scarce resource — so the safe choice costs
-        nothing where it applies."""
+        from the persistent compilation cache has been seen to lose the
+        input/output aliasing metadata for donated buffers (the final
+        state's buffers get reclaimed while still referenced, and live
+        `model.state` arrays read back garbage once a later computation
+        reuses the memory); not re-checked since, because CPU donation
+        buys nothing — host RAM is not the scarce resource — so the safe
+        choice costs nothing where it applies."""
         return (0,) if jax.default_backend() != "cpu" else ()
 
     def build_train_step(self, donate: bool = True) -> Callable:

@@ -30,23 +30,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-try:  # jax >= 0.4.35 exposes shard_map at top level
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
-# lax.pcast / lax.pvary exist only on newer jax (the varying-manual-axes
-# rep type system); older releases draw no replicated/varying distinction
-# inside shard_map, so identity is the correct fallback for both
-_pcast = getattr(lax, "pcast", lambda x, _axes, to=None: x)
-_pvary = getattr(lax, "pvary", lambda x, _axes: x)
-# without pcast there is no way to give every lax.switch branch one rep
-# type, so the old releases' rep checker must be off for the nonuniform
-# (switch-based) pipeline; the kwarg only exists there, hence the gate
-_NONUNIFORM_SHARD_MAP_KW = (
-    {} if hasattr(lax, "pcast") else {"check_rep": False}
-)
-
 
 def scan_blocks(block_fn: Callable, stacked_params, x):
     """Degenerate (single-stage) path: run all stacked layers sequentially.
@@ -107,8 +90,8 @@ def gpipe_spmd(
         ticks = n_micro + n_stages - 1
         # carries become pipe-varying inside the loop (ppermute / stage
         # predicates), so the initial zeros must carry that vma type too
-        zero_x = _pcast(jnp.zeros_like(mbs[0]), (axis_name,), to="varying")
-        zero_out = _pcast(jnp.zeros_like(mbs), (axis_name,), to="varying")
+        zero_x = lax.pcast(jnp.zeros_like(mbs[0]), (axis_name,), to="varying")
+        zero_out = lax.pcast(jnp.zeros_like(mbs), (axis_name,), to="varying")
         perm = [(j, (j + 1) % n_stages) for j in range(n_stages)]
 
         def tick(carry, t):
@@ -137,7 +120,7 @@ def gpipe_spmd(
         lambda l: P(*((axis_name,) + (None,) * (l.ndim - 1))), stacked_params
     )
     x_spec = P(*((data_axis,) + (None,) * (x.ndim - 1)))
-    fn = shard_map(
+    fn = jax.shard_map(
         pipelined,
         mesh=mesh,
         in_specs=(param_specs, x_spec),
@@ -277,7 +260,7 @@ def gpipe_pcg(
         # ppermute and cannot race.
         axes = (data_axis, axis_name)
         params = jax.tree_util.tree_map(
-            lambda l: _pvary(l, axes), params
+            lambda l: lax.pcast(l, axes, to="varying"), params
         )
         stage = lax.axis_index(axis_name)
         mb = inputs_local[0].shape[0] // n_micro
@@ -286,11 +269,11 @@ def gpipe_pcg(
         # carriers are varying over BOTH the pipe axis (ppermute/stage
         # predicates) and the data axis (they mix with data-sharded
         # activations inside the branches)
-        zero_buf = _pcast(
+        zero_buf = lax.pcast(
             jnp.zeros((mb, buf_elems), jnp.float32),
             (data_axis, axis_name), to="varying",
         )
-        zero_out = _pcast(
+        zero_out = lax.pcast(
             jnp.zeros((n_micro, mb, out_flat), jnp.float32),
             (data_axis, axis_name), to="varying",
         )
@@ -318,7 +301,7 @@ def gpipe_pcg(
             # injected inputs must carry the pipe-varying vma type so every
             # switch branch (buf-derived or inj-derived) has one output type
             inj = [
-                _pcast(
+                lax.pcast(
                     lax.dynamic_index_in_dim(
                         m, jnp.clip(t, 0, n_micro - 1), 0, keepdims=False
                     ),
@@ -353,11 +336,10 @@ def gpipe_pcg(
     )
     param_specs = jax.tree_util.tree_map(lambda _: P(), params)
     out_spec = P(*((data_axis,) + (None,) * (len(plan.out_shape) - 1)))
-    fn = shard_map(
+    fn = jax.shard_map(
         pipelined,
         mesh=mesh,
         in_specs=(param_specs,) + in_specs,
         out_specs=out_spec,
-        **_NONUNIFORM_SHARD_MAP_KW,
     )
     return fn(params, *input_arrays)

@@ -16,12 +16,10 @@ keys searched strategies by the three fingerprints that already exist —
 
 — and stores, per key: the winning strategy (strategy_io records + the
 mesh axes it lowers onto), provenance, and the StrategyTuner's quarantine
-fingerprints (previously in-memory only, lost on restart). Serialized XLA
-executables ride through JAX's own persistent compilation cache where the
-backend supports it (``enable_jax_compilation_cache``); on backends where
-deserialized executables are unsafe (CPU: donated-buffer aliasing breaks
-on jax 0.4.x) the store stays strategy-only — skipping the *search* is
-the long pole either way.
+fingerprints (previously in-memory only, lost on restart). The store
+keeps strategies; compiled executables are JAX's to keep, in the one
+persistent compilation cache the process places
+(``config.enable_compile_cache``) — a store never moves it.
 
 Robustness is the design center:
 
@@ -167,11 +165,10 @@ class ArtifactStore:
         <root>/entries/<key_id>.json    one integrity-enveloped entry
         <root>/quarantine/              corrupt/stale entries moved aside
         <root>/quarantine/<scope>.q.json  persisted tuner quarantines
-        <root>/xla_cache/               JAX compilation cache (optional)
     """
 
     def __init__(self, root: str, *, max_entries: int = 64,
-                 fault_injector=None, executable_cache: Optional[bool] = None):
+                 fault_injector=None):
         self.root = os.path.abspath(root)
         self.max_entries = max(1, int(max_entries))
         self.fault_injector = fault_injector
@@ -181,16 +178,6 @@ class ArtifactStore:
         os.makedirs(self.entries_dir, exist_ok=True)
         os.makedirs(self.quarantine_dir, exist_ok=True)
         self._clean_stale_tmp()
-        # serialized-executable leg: JAX's persistent compilation cache,
-        # gated per-backend (CPU deserialized executables mishandle
-        # donated buffers on jax 0.4.x — see docs/artifact_cache.md), so
-        # the default is auto-enable on TPU/GPU only
-        self.executable_cache_enabled = False
-        if executable_cache is None:
-            executable_cache = self._backend_supports_executables()
-        if executable_cache:
-            self.executable_cache_enabled = \
-                self.enable_jax_compilation_cache()
 
     # -- integrity envelope ---------------------------------------------
     def _entry_path(self, key: Dict[str, Any]) -> str:
@@ -463,38 +450,6 @@ class ArtifactStore:
             yield self
         finally:
             _ambient.store = prev
-
-    # -- serialized executables (per-backend) ----------------------------
-    @staticmethod
-    def _backend_supports_executables() -> bool:
-        """Deserialized XLA executables are only trusted off-CPU: on CPU
-        (jax 0.4.x) a compilation-cache-restored executable mishandles
-        donated-buffer aliasing (runtime/checkpoint.py records the same
-        hazard for zero-copy views), so CPU stays strategy-only."""
-        try:
-            import jax
-
-            return jax.default_backend() not in ("cpu",)
-        except Exception:
-            return False
-
-    def enable_jax_compilation_cache(self) -> bool:
-        """Point JAX's persistent compilation cache into this store so
-        recompiles of a cached strategy also skip XLA compilation where
-        the backend supports it. Returns whether it took effect."""
-        cache_dir = os.path.join(self.root, "xla_cache")
-        try:
-            os.makedirs(cache_dir, exist_ok=True)
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            return True
-        except Exception as e:  # older jax / unsupported backend
-            logger.info(
-                "artifact store: JAX compilation cache unavailable (%r); "
-                "staying strategy-only", e,
-            )
-            return False
 
 
 class _StaleEntry(ValueError):

@@ -778,6 +778,7 @@ class AdmissionQueue:
         self._q: deque = deque()
         self._lock = threading.Lock()
         self._nonempty = threading.Condition(self._lock)
+        self._closed: Optional[Callable] = None  # error factory, see close
 
     def __len__(self) -> int:
         with self._lock:
@@ -806,6 +807,12 @@ class AdmissionQueue:
             req._finish(error=err)
             raise err
         with self._lock:
+            if self._closed is not None:
+                err = self._closed(req)
+                _shed("aborted")
+                req.trace.shed("aborted", stage="enqueue")
+                req._finish(error=err)
+                raise err
             if len(self._q) >= self.max_depth:
                 full = QueueFullError(
                     f"admission queue at capacity ({self.max_depth})"
@@ -872,6 +879,16 @@ class AdmissionQueue:
                 n += 1
         self._export_depth()
         return n
+
+    def close(self, error_factory) -> int:
+        """The last consumer is gone (a lone batcher's serve thread
+        died): fail what is queued AND whatever is offered from now on
+        with `error_factory(req)`, so a caller blocked in `result()`
+        gets the cause at once instead of waiting out its deadline.
+        Returns the number drained."""
+        with self._lock:
+            self._closed = error_factory
+        return self.drain(error_factory)
 
 
 # ----------------------------------------------------------------------
@@ -1625,6 +1642,21 @@ class ContinuousBatcher:
             self._strand_slots()
             if self.on_dead is not None:
                 self.on_dead(self, e)
+            else:
+                # a lone batcher (no ReplicaSet): nobody is left to take
+                # the stranded or queued work, so it fails with the cause
+                # (bound to a local: `e` is unbound once this block ends,
+                # and the queue calls the factory for later offers too)
+                cause = e
+
+                def orphaned(req: GenerationRequest) -> ReplicaDeathError:
+                    err = ReplicaDeathError(
+                        f"request {req.id}: serving replica {self.name} "
+                        f"died and none is left to serve it: {cause!r}")
+                    err.__cause__ = cause
+                    return err
+
+                self.queue.close(orphaned)
         else:
             # marked dead externally (watchdog/heartbeat failover) while
             # we were mid-iteration: whatever we still hold goes back to

@@ -21,14 +21,42 @@ from typing import Dict, Optional
 
 @dataclasses.dataclass
 class TPUChipSpec:
-    """Per-chip peak numbers. Defaults are TPU v5e (public spec):
-    197 TFLOP/s bf16, 819 GB/s HBM BW, 16 GB HBM."""
+    """Per-chip peak numbers. Defaults are TPU v5e (Google Cloud
+    documentation, "TPU v5e"): 197 TFLOP/s bf16, 819 GB/s HBM BW, 16 GB
+    HBM."""
 
     peak_flops_bf16: float = 197e12
     peak_flops_f32: float = 49e12
     hbm_bandwidth: float = 819e9  # bytes/s
     hbm_capacity: int = 16 * 1024**3
     vmem_capacity: int = 128 * 1024**2
+    name: str = "TPU v5e"
+
+
+# The chips the cost model can price, keyed by what JAX reports as
+# `jax.Device.device_kind` (a v5e reports "TPU v5 lite").
+CHIP_SPECS: Dict[str, TPUChipSpec] = {
+    "TPU v5 lite": TPUChipSpec(),
+}
+
+
+def chip_spec_for(device) -> TPUChipSpec:
+    """The spec the search prices `device` with. On the TPU platform the
+    chip must be in CHIP_SPECS: pricing an unknown chip as a v5e would be
+    a wrong answer that looks like a right one, so it is an error. Off
+    the TPU (the CPU meshes of the tests) there is no chip to price; the
+    search then optimizes for a stated hypothetical v5e — a machine for
+    the search to reason about, never a rate of the host it runs on."""
+    if device.platform != "tpu":
+        return TPUChipSpec(name="TPU v5e (hypothetical: no TPU here)")
+    try:
+        return dataclasses.replace(CHIP_SPECS[device.device_kind])
+    except KeyError:
+        raise ValueError(
+            f"no chip spec for device_kind {device.device_kind!r}: add its "
+            f"published peaks to search/machine_model.py CHIP_SPECS "
+            f"(known: {sorted(CHIP_SPECS)})"
+        ) from None
 
 
 @dataclasses.dataclass

@@ -45,7 +45,9 @@ import tempfile
 import threading
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# a CPU-mesh harness by construction (counts and CPU wall clock, never a
+# device rate): the platform is pinned like tests/conftest.py pins it
+os.environ["JAX_PLATFORMS"] = "cpu"
 # honor JAX_NUM_CPU_DEVICES like tests/conftest.py: virtual CPU mesh size
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "")
@@ -59,11 +61,8 @@ import numpy as np  # noqa: E402
 
 import jax  # noqa: E402
 
-try:
-    jax.config.update("jax_num_cpu_devices",
-                      int(os.environ.get("JAX_NUM_CPU_DEVICES", "8")))
-except AttributeError:
-    pass  # older jax: the XLA_FLAGS export above does it
+jax.config.update("jax_num_cpu_devices",
+                  int(os.environ.get("JAX_NUM_CPU_DEVICES", "8")))
 
 
 def build_model_fn(args):

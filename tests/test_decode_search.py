@@ -175,9 +175,6 @@ def test_compile_decode_strategy_roundtrips_through_strategy_io(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_paged_flash_decode_matches_dense_reference():
-    from flexflow_tpu.kernels.attention import HAS_PALLAS
-    if not HAS_PALLAS:
-        pytest.skip("Pallas unavailable")
     from flexflow_tpu.kernels.decode import (
         paged_decode_reference,
         paged_flash_decode,
@@ -202,9 +199,6 @@ def test_paged_flash_decode_matches_dense_reference():
 def test_paged_view_of_cache_matches_dense_attention():
     """The serving adapter: dense per-slot caches viewed as a paged pool
     must reproduce plain masked attention over the dense caches."""
-    from flexflow_tpu.kernels.attention import HAS_PALLAS
-    if not HAS_PALLAS:
-        pytest.skip("Pallas unavailable")
     import jax.numpy as jnp
 
     from flexflow_tpu.kernels.decode import (

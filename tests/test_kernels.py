@@ -121,10 +121,7 @@ def test_flash_pallas_bwd_all_grads(causal):
 @pytest.mark.parametrize("causal", [False, True])
 def test_ring_attention_matches_naive(causal):
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5: not yet promoted out of experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devices = jax.devices()[:4]
     mesh = Mesh(np.array(devices), ("sp",))
@@ -147,10 +144,7 @@ def test_ulysses_attention_matches_naive(causal):
     """Ulysses all_to_all sequence parallelism (head scatter) must be
     exact, like ring — it's plain attention over re-sharded data."""
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5: not yet promoted out of experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from flexflow_tpu.kernels.attention import ulysses_attention
 
@@ -159,8 +153,7 @@ def test_ulysses_attention_matches_naive(causal):
     q, k, v = qkv(b=2, s=64, h=4, d=16)
 
     uly = shard_map(
-        functools.partial(ulysses_attention, axis_name="sp", causal=causal,
-                          interpret=True),
+        functools.partial(ulysses_attention, axis_name="sp", causal=causal),
         mesh=mesh,
         in_specs=(P(None, "sp"), P(None, "sp"), P(None, "sp")),
         out_specs=P(None, "sp"),
@@ -176,10 +169,7 @@ def test_ulysses_attention_matches_naive(causal):
 
 def test_ring_attention_grad():
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # jax < 0.5: not yet promoted out of experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     devices = jax.devices()[:4]
     mesh = Mesh(np.array(devices), ("sp",))

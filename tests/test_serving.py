@@ -410,6 +410,37 @@ def test_continuous_batcher_rejects_two_input_graphs():
         ContinuousBatcher(m, _serve_cfg(max_len=4), AdmissionQueue(4))
 
 
+def test_lone_batcher_death_reaches_the_caller(lm):
+    """A lone batcher (no ReplicaSet) whose serve thread dies fails its
+    in-flight, queued AND later-offered requests with the death cause at
+    once — not with a bare timeout after the deadline (what hid a kernel
+    the TPU compiler refused behind 600 s of silence)."""
+    fi = FaultInjector()
+    fi.inject("replica_death", at_step=1, replica="replica0")
+    q = AdmissionQueue(max_depth=8)
+    b = ContinuousBatcher(lm, _serve_cfg(slots=1), q,
+                          fault_injector=fi).start()
+    prompt = np.arange(3, dtype=np.int32)
+    try:
+        in_flight = GenerationRequest(prompt, 8, deadline_s=600.0)
+        queued = GenerationRequest(prompt, 8, deadline_s=600.0)
+        q.offer(in_flight)
+        q.offer(queued)  # one slot: waits behind the first
+        for req in (in_flight, queued):
+            with pytest.raises(ReplicaDeathError) as ei:
+                req.result(timeout=60.0)
+            assert ei.value.__cause__ is b.death_cause
+        assert b.dead and isinstance(b.death_cause, ReplicaDeathError)
+        late = GenerationRequest(prompt, 8, deadline_s=600.0)
+        with pytest.raises(ReplicaDeathError):
+            q.offer(late)
+        with pytest.raises(ReplicaDeathError):
+            late.result(timeout=1.0)
+    finally:
+        b.stop()
+    assert b.pool.pages_in_use == 0
+
+
 # ---------------------------------------------------------------------------
 # replica failover + rate limiting
 # ---------------------------------------------------------------------------
