@@ -836,7 +836,8 @@ class FFModel:
                                          devices=ndev,
                                          ops=len(self.graph.ops))
         elif search_enabled:
-            mesh = self._run_strategy_search(ndev)
+            with obs.mark("ff.compile.search", cat="compile", devices=ndev):
+                mesh = self._run_strategy_search(ndev)
             self.strategy_provenance = {"source": "search",
                                         "cause": _research_cause}
             self.search_trajectory.phase("strategy_search", _t_phase,
@@ -987,26 +988,28 @@ class FFModel:
             cur_inputs[i].guid: (cur_inputs[i], v)
             for i, v in self._constant_positions.items()
         }
-        _t_phase = time.perf_counter()
-        self.executor = PCGExecutor(
-            self.graph,
-            mesh,
-            self.optimizer,
-            self.loss_type,
-            self.metrics_obj,
-            compute_dtype=compute_dtype,
-            grad_dtype=grad_dtype,
-            seed=self.config.seed,
-            input_order=ordered_inputs,
-            remat=self.config.remat,
-            constants=constants,
-            plan_cost_model=plan_cost_model,
-            overlap_grad_sync=self.config.overlap_backward_update,
-        )
-        self.search_trajectory.phase("executor_build", _t_phase)
-        _t_phase = time.perf_counter()
-        self.state = self.executor.init_state()
-        self.search_trajectory.phase("init_state", _t_phase)
+        with obs.mark("ff.compile.lower", cat="compile",
+                      ops=len(self.graph.ops)):
+            _t_phase = time.perf_counter()
+            self.executor = PCGExecutor(
+                self.graph,
+                mesh,
+                self.optimizer,
+                self.loss_type,
+                self.metrics_obj,
+                compute_dtype=compute_dtype,
+                grad_dtype=grad_dtype,
+                seed=self.config.seed,
+                input_order=ordered_inputs,
+                remat=self.config.remat,
+                constants=constants,
+                plan_cost_model=plan_cost_model,
+                overlap_grad_sync=self.config.overlap_backward_update,
+            )
+            self.search_trajectory.phase("executor_build", _t_phase)
+            _t_phase = time.perf_counter()
+            self.state = self.executor.init_state()
+            self.search_trajectory.phase("init_state", _t_phase)
         self.perf_metrics = PerfMetrics()
 
     def compile_decode(self, *, strategy_path: Optional[str] = None,
@@ -1104,7 +1107,9 @@ class FFModel:
                 sh, xfers, alpha=cfg.search_alpha, budget=budget,
                 trajectory=self.decode_trajectory,
             )
-            graph, result = gsh.graph_optimize(graph, res)
+            with obs.mark("ff.compile.decode_search", cat="compile",
+                          devices=ndev, budget=budget):
+                graph, result = gsh.graph_optimize(graph, res)
             views = result.views
             cost = result.cost
             self.decode_trajectory.phase("decode_strategy_search", _t_phase,
@@ -1191,22 +1196,24 @@ class FFModel:
         axis_sizes = strategies.assign_mesh_axes(graph, ndev)
         mesh = build_mesh(axis_sizes)
         _t_phase = time.perf_counter()
-        self.decode_executor = PCGExecutor(
-            graph,
-            mesh,
-            self.optimizer,
-            self.loss_type,
-            self.metrics_obj,
-            compute_dtype=(
-                jnp.bfloat16 if cfg.allow_mixed_precision else None
-            ),
-            grad_dtype=None,  # decode never materializes gradients
-            seed=cfg.seed,
-            input_order=ordered_inputs,
-            remat=False,
-            constants=constants,
-            plan_cost_model=cost_model,
-        )
+        with obs.mark("ff.compile.lower", cat="compile", ops=len(graph.ops),
+                      objective="decode"):
+            self.decode_executor = PCGExecutor(
+                graph,
+                mesh,
+                self.optimizer,
+                self.loss_type,
+                self.metrics_obj,
+                compute_dtype=(
+                    jnp.bfloat16 if cfg.allow_mixed_precision else None
+                ),
+                grad_dtype=None,  # decode never materializes gradients
+                seed=cfg.seed,
+                input_order=ordered_inputs,
+                remat=False,
+                constants=constants,
+                plan_cost_model=cost_model,
+            )
         self.decode_trajectory.phase("decode_executor_build", _t_phase)
         return self.decode_executor
 
@@ -2070,34 +2077,35 @@ class FFModel:
                 # — the Legion trace-replay analog); partials come back
                 # stacked on a steps axis
                 nonlocal tstep
-                t0 = time.perf_counter() if tel is not None else 0.0
-                bxs = [
-                    self.executor.shard_batch_stack(
-                        pt,
-                        np.stack([np.asarray(b[i], pt.data_type.np_dtype)
-                                  for b in chunk]),
+                with obs.mark("ff.fit.feed", cat="train", step=tstep) as feed:
+                    bxs = [
+                        self.executor.shard_batch_stack(
+                            pt,
+                            np.stack([np.asarray(b[i], pt.data_type.np_dtype)
+                                      for b in chunk]),
+                        )
+                        for i, pt in enumerate(in_pts)
+                    ]
+                    bys = self.executor.put_replicated(
+                        np.stack([b[-1] for b in chunk]).astype(label_dt)
                     )
-                    for i, pt in enumerate(in_pts)
-                ]
-                bys = self.executor.put_replicated(
-                    np.stack([b[-1] for b in chunk]).astype(label_dt)
-                )
-                # one key per step, split exactly like the stepwise path so
-                # dropout masks are identical whatever the dispatch grouping
-                subs = []
-                for _ in chunk:
-                    self._rng, sub = jax.random.split(self._rng)
-                    subs.append(sub)
-                self.state, partials = scan_fn(
-                    self.state, bxs, bys,
-                    self.executor.put_replicated(jnp.stack(subs)),
-                )
+                    # one key per step, split exactly like the stepwise
+                    # path so dropout masks are identical whatever the
+                    # dispatch grouping
+                    subs = []
+                    for _ in chunk:
+                        self._rng, sub = jax.random.split(self._rng)
+                        subs.append(sub)
+                    keys = self.executor.put_replicated(jnp.stack(subs))
+                with obs.mark("ff.train.step", cat="train", step_num=tstep,
+                              steps=len(chunk)) as dispatch:
+                    self.state, partials = scan_fn(self.state, bxs, bys, keys)
                 device_partials.append(partials)
                 if tel is not None:
                     tel.record_chunk(
                         first_step=tstep, steps=len(chunk),
-                        dur_s=time.perf_counter() - t0, batch_size=bs,
-                        n_chips=n_chips, t0=t0,
+                        dur_s=feed.dur + dispatch.dur, batch_size=bs,
+                        n_chips=n_chips, t0=feed.t0,
                     )
                 tstep += len(chunk)
 
@@ -2108,41 +2116,54 @@ class FFModel:
                         flush(chunk)
                         chunk = []
                 else:
-                    t0 = time.perf_counter() if tel is not None else 0.0
-                    bx = [
-                        self.executor.shard_batch(pt, np.asarray(a, pt.data_type.np_dtype))
-                        for pt, a in zip(in_pts, batch[:-1])
-                    ]
-                    by = self.executor.put_replicated(
-                        np.asarray(batch[-1]).astype(label_dt)
-                    )
-                    self._rng, sub = jax.random.split(self._rng)
-                    self.state, partials = step_fn(
-                        self.state, bx, by, self.executor.put_replicated(sub)
-                    )
+                    with obs.mark("ff.fit.feed", cat="train",
+                                  step=tstep) as feed:
+                        bx = [
+                            self.executor.shard_batch(pt, np.asarray(a, pt.data_type.np_dtype))
+                            for pt, a in zip(in_pts, batch[:-1])
+                        ]
+                        by = self.executor.put_replicated(
+                            np.asarray(batch[-1]).astype(label_dt)
+                        )
+                        self._rng, sub = jax.random.split(self._rng)
+                        key = self.executor.put_replicated(sub)
+                    # the dispatch (an enqueue); the device runs the step
+                    # under the profiler's step marker of the same number
+                    with obs.mark("ff.train.step", cat="train",
+                                  step_num=tstep) as dispatch:
+                        self.state, partials = step_fn(self.state, bx, by,
+                                                       key)
                     device_partials.append(partials)
                     if tel is not None:
                         loss_val = None
+                        dur_s = feed.dur + dispatch.dur
                         if tel.config.sync_per_step:
+                            t_sync = time.perf_counter()
                             loss_val = float(
                                 _fetch_global(partials["loss"]).ravel()[-1]
                             )
+                            dur_s += time.perf_counter() - t_sync
                         tel.record_step(
-                            step=tstep, dur_s=time.perf_counter() - t0,
+                            step=tstep, dur_s=dur_s,
                             batch_size=bs, n_chips=n_chips, loss=loss_val,
-                            t0=t0,
+                            t0=feed.t0,
                         )
                     tstep += 1
                 num_samples += bs
             if chunk:  # tail chunk shorter than spd (own compiled shape)
                 flush(chunk)
-            folded = jax.tree_util.tree_map(
-                lambda *vs: sum(float(np.sum(_fetch_global(v))) for v in vs),
-                *device_partials,
-            )
-            last_loss = float(
-                _fetch_global(device_partials[-1]["loss"]).ravel()[-1]
-            )
+            # the epoch's one fetch of the partials: the host waits here
+            # for the device to finish the steps dispatched above
+            with obs.mark("ff.fit.fold", cat="train", epoch=epoch,
+                          steps=len(device_partials)):
+                folded = jax.tree_util.tree_map(
+                    lambda *vs: sum(float(np.sum(_fetch_global(v)))
+                                    for v in vs),
+                    *device_partials,
+                )
+                last_loss = float(
+                    _fetch_global(device_partials[-1]["loss"]).ravel()[-1]
+                )
             folded.pop("loss", None)
             gnorm_sum = folded.pop("grad_norm", None)
             self.perf_metrics.update(folded)
@@ -2155,7 +2176,8 @@ class FFModel:
                 + self.perf_metrics.report(),
                 verbose=verbose, name="epoch", epoch=epoch, loss=last_loss,
             )
-        jax.block_until_ready(self.state.params)
+        with obs.mark("ff.fit.sync", cat="train", step=tstep):
+            jax.block_until_ready(self.state.params)
         elapsed = time.time() - start
         # reference: transformer.cc:208-211 throughput print
         obs.progress(

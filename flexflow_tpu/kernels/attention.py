@@ -403,6 +403,7 @@ def _flash_fwd_folded(qf, kf, vf, *, causal: bool, interpret: bool,
             out_struct((bh, 1, sq), jnp.float32, qf),
         ],
         interpret=interpret,
+        name="ff_flash_fwd",
     )(*args)
     return out, lse
 
@@ -457,6 +458,7 @@ def _flash_bwd_folded(qf, kf, vf, of, lse, dof, *, causal: bool,
             out_struct((bh, sk, dv_d), vf.dtype, qf),
         ],
         interpret=interpret,
+        name="ff_flash_bwd",
     )(*args)
     return dq, dk, dv
 

@@ -137,6 +137,7 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
         grid_spec=grid_spec,
         out_shape=out_struct((b, h, 1, dv), q.dtype, q),
         interpret=interpret,
+        name="ff_paged_decode",
     )(jnp.asarray(page_table, jnp.int32), jnp.asarray(lengths, jnp.int32),
       q[:, :, None, :], k_pages, v_pages)
     return out[:, :, 0, :]

@@ -20,8 +20,10 @@ per-op event timing prints and the simulator's timeline export (SURVEY
 
 Wire-up: ``model.fit(..., telemetry=TelemetryConfig(dir=...))`` runs one
 session end to end; ``python -m flexflow_tpu.obs`` converts/summarizes
-the artifacts. With no session active every helper here is a cheap
-no-op — `tracer()` returns the shared NULL_TRACER (no per-call
+the artifacts. The hot path (serve loop, fit(), compile) marks its phases
+with `obs.mark("ff....")`, which needs no session: the spans are
+jax.profiler TraceAnnotations, visible to any profiler trace. With no
+session active every other helper here is a cheap no-op — `tracer()` returns the shared NULL_TRACER (no per-call
 allocation) and the counter/gauge helpers return after one global read.
 """
 from __future__ import annotations
@@ -49,6 +51,7 @@ from .request_trace import (  # noqa: F401
 from .telemetry import Telemetry, TelemetryConfig  # noqa: F401
 from .tracer import (  # noqa: F401
     NULL_TRACER,
+    Mark,
     Tracer,
     _NULL_SPAN,
     read_events_jsonl,
@@ -113,6 +116,23 @@ def span(name: str, cat: str = "runtime", **args):
     if t is None:
         return _NULL_SPAN
     return t.tracer.span(name, cat, **args)
+
+
+def mark(name: str, cat: str = "runtime", *, into=None, step_num=None,
+         session: bool = True, **args) -> Mark:
+    """THE way the program marks time on its hot path (names start
+    `ff.`): a context manager that always enters a jax.profiler
+    TraceAnnotation, so a profiler's trace shows the span on the calling
+    thread's host line, on the device's clock, and that also records the
+    tracer's "X" event while a session is open. `into=(counters, key)`
+    adds the span's seconds to an always-on counter; `step_num` makes the
+    annotation the profiler's step marker; `session=False` keeps a span
+    that repeats with no work done (the serve loop's idle sleep) out of
+    the session's event log. See tracer.Mark. `span()` stays the
+    session-only timer of everything off the hot path."""
+    t = _ACTIVE if session else None
+    return Mark(t.tracer if t is not None else None, name, cat, into,
+                step_num, args)
 
 
 def event(name: str, cat: str = "runtime", **args) -> None:

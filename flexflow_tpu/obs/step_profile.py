@@ -396,52 +396,82 @@ def _fused_step_args(model, cast, labels):
     return bx, by, rng
 
 
-def _xla_trace_events(model, step_args, logdir: str) -> List[dict]:
-    """TPU/GPU path: run one real fused step under jax.profiler and
-    map the XLA trace's op spans back to PCG op names (substring match
-    on the fusion names). Best-effort by construction — callers fall
-    back to the instrumented path when nothing maps."""
-    import gzip
+_HLO_INSTRUCTION = re.compile(
+    r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?\bop_name="([^"]*)"', re.M)
+_EVENT_INSTRUCTION = re.compile(r"^%?([\w.\-]+)")
 
+
+def _instruction_scopes(hlo_text: str) -> Dict[str, str]:
+    """{instruction name: op_name} of a compiled program's text: the
+    scope path the lowering gave each instruction (`jit(step)/
+    jvp(ff.fwd)/<PCG op>/dot_general`)."""
+    return dict(_HLO_INSTRUCTION.findall(hlo_text))
+
+
+def _xplane_op_events(path: str, scopes: Dict[str, str],
+                      pcg_names) -> List[dict]:
+    """The device operations of the profile at `path` (the "XLA Ops"
+    line of the first `/device:` plane), each under the PCG op whose
+    per-op scope (jax.named_scope(op.name), PCGExecutor.apply) is a
+    component of its instruction's op_name. Operations under no PCG
+    op's scope (the optimizer update, compiler-made copies) are left
+    out."""
+    from jax.profiler import ProfileData
+
+    pcg_names = set(pcg_names)
+    plane = next((p for p in sorted(ProfileData.from_file(path).planes,
+                                    key=lambda p: p.name)
+                  if p.name.startswith("/device:")), None)
+    if plane is None:
+        return []
+    raw = [e for line in plane.lines if line.name == "XLA Ops"
+           for e in line.events]
+    if not raw:
+        return []
+    min_ns = min(e.start_ns for e in raw)
+    out: List[dict] = []
+    for e in raw:
+        m = _EVENT_INSTRUCTION.match(e.name)
+        scope = scopes.get(m.group(1), "") if m else ""
+        op = next((c for c in scope.split("/") if c in pcg_names), None)
+        if op is None:
+            continue
+        out.append({
+            "ts": (e.start_ns - min_ns) * 1e-9,
+            "ph": "X", "name": op, "cat": MEASURED_CAT,
+            "dur": e.duration_ns * 1e-9, "tid": 0,
+            "args": {"source": "xla_trace", "xla_op": e.name[:200],
+                     "scope": scope},
+        })
+    return out
+
+
+def _xla_trace_events(model, step_args, logdir: str) -> List[dict]:
+    """TPU/GPU path: run one real fused step under jax.profiler, read
+    the newest `*.xplane.pb` with jax.profiler.ProfileData and map each
+    device operation to its PCG op by the per-op scope in its op_name
+    (taken from the text of the very executable that ran: the trace
+    names an operation by its instruction). Best-effort by construction
+    — callers fall back to the instrumented path when nothing maps."""
     import jax
 
     from ..runtime.profiler import trace
 
     step = model.executor.build_train_step(donate=False)
     bx, by, rng = step_args
-    _, parts = step(model.state, bx, by, rng)  # warm outside the trace
+    compiled = step.lower(model.state, bx, by, rng).compile()
+    _, parts = compiled(model.state, bx, by, rng)  # warm outside the trace
     jax.block_until_ready(parts["loss"])
     with trace(logdir):
-        _, parts = step(model.state, bx, by, rng)
+        _, parts = compiled(model.state, bx, by, rng)
         jax.block_until_ready(parts["loss"])
     paths = sorted(glob.glob(
-        os.path.join(logdir, "**", "*.trace.json.gz"), recursive=True))
+        os.path.join(logdir, "**", "*.xplane.pb"), recursive=True))
     if not paths:
         return []
-    with gzip.open(paths[-1], "rt") as f:
-        doc = json.load(f)
-    raw = [e for e in doc.get("traceEvents", [])
-           if e.get("ph") == "X" and e.get("name")]
-    if not raw:
-        return []
-    min_ts = min(float(e["ts"]) for e in raw)
-    names = sorted((op.name for op in model.graph.topo_order()),
-                   key=len, reverse=True)
-    pat = re.compile("|".join(re.escape(n) for n in names)) if names \
-        else None
-    out: List[dict] = []
-    for e in raw:
-        m = pat.search(str(e["name"])) if pat is not None else None
-        if m is None:
-            continue
-        out.append({
-            "ts": (float(e["ts"]) - min_ts) * 1e-6,
-            "ph": "X", "name": m.group(0), "cat": MEASURED_CAT,
-            "dur": float(e.get("dur", 0.0)) * 1e-6,
-            "tid": int(e.get("tid", 0)),
-            "args": {"source": "xla_trace", "xla_op": str(e["name"])},
-        })
-    return out
+    return _xplane_op_events(
+        paths[-1], _instruction_scopes(compiled.as_text()),
+        (op.name for op in model.graph.topo_order()))
 
 
 def capture_step_profile(model, x, y, *, batch_size: Optional[int] = None,

@@ -290,8 +290,11 @@ class Telemetry:
     def record_step(self, *, step: int, dur_s: float, batch_size: int,
                     n_chips: int, loss: Optional[float] = None,
                     t0: Optional[float] = None) -> None:
-        """One training step completed (or dispatched, when
-        sync_per_step is off)."""
+        """One training step completed (or, when sync_per_step is off,
+        merely dispatched: `dur_s` is then the host's feed + enqueue, and
+        `ff_step_wall_seconds` times the dispatch, not the step; the
+        device's time per step comes from a profiler trace, where
+        fit()'s `ff.train.step` marker and the step's scopes are)."""
         if self.config.step_events:
             args = {"step": step, "batch_size": batch_size}
             if loss is not None:
@@ -307,7 +310,9 @@ class Telemetry:
                              "training samples consumed").inc(batch_size)
         self.metrics.histogram(
             "ff_step_wall_seconds",
-            "per-step wall time (dispatch time unless sync_per_step)",
+            "per-step host time: WITHOUT sync_per_step this is the feed "
+            "and the dispatch (an enqueue), not the device's step; with "
+            "it, feed + dispatch + the wait for the step's loss",
         ).observe(dur_s)
         if dur_s > 0:
             self.metrics.gauge(
@@ -345,7 +350,9 @@ class Telemetry:
             .inc(batch_size * steps)
         self.metrics.histogram(
             "ff_step_wall_seconds",
-            "per-step wall time (dispatch time unless sync_per_step)",
+            "per-step host time: WITHOUT sync_per_step this is the feed "
+            "and the dispatch (an enqueue), not the device's step; with "
+            "it, feed + dispatch + the wait for the step's loss",
         ).observe(dur_s / max(1, steps))
         if dur_s > 0:
             self.metrics.gauge(

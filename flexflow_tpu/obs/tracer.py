@@ -167,6 +167,76 @@ class Span:
         return False
 
 
+_ANNOTATIONS = None  # jax.profiler's two TraceMe classes, imported at the first mark
+
+
+def _annotations():
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None:
+        from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+        _ANNOTATIONS = (TraceAnnotation, StepTraceAnnotation)
+    return _ANNOTATIONS
+
+
+class Mark:
+    """One span of the program's hot path, written from one call site to
+    both sinks: ALWAYS a jax.profiler TraceAnnotation (a TraceMe: a flag
+    check while no profiler runs; under one, an event on this thread's
+    line of the host plane, on the device's clock, `args` as its stats)
+    and, when `tracer` is a session's, the tracer's "X" event as
+    `Span` writes it. `step_num` makes it a StepTraceAnnotation (the
+    profiler's step marker). `into=(counters, key)` adds the span's
+    seconds to `counters[key]` at its end, session or none: the always-on
+    phase counters (ContinuousBatcher.stats). `t0` (perf_counter) and
+    `dur` stay readable after the block."""
+
+    __slots__ = ("name", "cat", "args", "t0", "dur", "_tracer", "_into",
+                 "_step_num", "_ann")
+
+    def __init__(self, tracer, name, cat, into, step_num, args):
+        self.name = name
+        self.cat = cat
+        self.args = args
+        self.t0 = self.dur = 0.0
+        self._tracer = tracer
+        self._into = into
+        self._step_num = step_num
+        self._ann = None
+
+    def set(self, **args):
+        """Attach args learnt mid-span (the slot an admission got)."""
+        self.args.update(args)
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+        return self
+
+    def __enter__(self):
+        plain, step = _annotations()
+        if self._step_num is None:
+            self._ann = plain(self.name, **self.args)
+        else:
+            self._ann = step(self.name, step_num=self._step_num, **self.args)
+        self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.dur = time.perf_counter() - self.t0
+        self._ann.__exit__(*exc)
+        if self._into is not None:
+            counters, key = self._into
+            counters[key] += self.dur
+        tr = self._tracer
+        if tr is not None:
+            args = self.args if self._step_num is None \
+                else dict(self.args, step=self._step_num)
+            tr.emit({"ts": self.t0 - tr.t0, "ph": "X", "name": self.name,
+                     "cat": self.cat, "dur": self.dur, "tid": 0,
+                     "args": args})
+        return False
+
+
 class Tracer:
     """Buffered JSONL event recorder.
 

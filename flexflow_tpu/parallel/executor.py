@@ -92,6 +92,18 @@ def global_grad_norm(grads) -> jax.Array:
     return jnp.sqrt(total)
 
 
+def _count_trace(program: str) -> None:
+    """Called from inside a traced body: a Python side effect, so it runs
+    once per TRACE of that program and never per execution. The count
+    says which program was built again (a new shape, a state whose
+    leaves changed type)."""
+    from .. import obs
+
+    obs.count("ff_program_traces_total",
+              help="times a jitted program's body was traced",
+              program=program)
+
+
 def _tree_select(pred, new, old):
     """Leafwise where(pred, new, old) tolerating None leaves (SGD without
     momentum keeps {"v": None}) — used to carry params/opt state through
@@ -385,9 +397,10 @@ class PCGExecutor:
                         n_devices=1, mesh=None,  # device-local inside shard_map
                         op_name=op.name,
                     )
-                    outs = d.forward(
-                        op.params, params.get(op.name, {}), ins, ctx
-                    )
+                    with jax.named_scope(op.name):
+                        outs = d.forward(
+                            op.params, params.get(op.name, {}), ins, ctx
+                        )
                     for t, v in zip(op.outputs, outs):
                         vals[t.guid] = v
                 return vals
@@ -533,59 +546,64 @@ class PCGExecutor:
                 )
         compute_idx = 0
         for op in self.topo:
-            ins = [vals[t.guid] for t in op.inputs]
-            if op.is_parallel_op:
-                outs = par_ops.execute(op, ins, self.mesh)
-            else:
-                opdef = get_op_def(op.op_type)
-                # fold in the op's index among COMPUTE ops, not its guid
-                # (process-global counter — a rebuilt model would draw
-                # different dropout masks for the same seed) and not its
-                # raw topo position (the search inserts partition/combine
-                # ops per mesh, which would make masks mesh-dependent)
-                op_rng = (
-                    jax.random.fold_in(rng, compute_idx)
-                    if rng is not None else None
-                )
-                compute_idx += 1
-                ctx = FwdCtx(
-                    training=training,
-                    rng=op_rng,
-                    seq_length=seq_length,
-                    compute_dtype=self.compute_dtype,
-                    aux_losses=aux_out,
-                    n_devices=self.mesh.size,
-                    mesh=self.mesh,
-                    op_name=op.name,
-                )
-                w = params.get(op.name, {})
-                if training and self.remat and op.op_type in _REMAT_OPS:
-                    # Rematerialize in the backward instead of saving the
-                    # op's internals — for attention that drops the stored
-                    # s_q x s_kv scores/probs (the dominant HBM residual;
-                    # measured 30x+ train-step speedup at seq 512 where the
-                    # saved probs otherwise thrash HBM). Exact: same math,
-                    # recomputed. RNG is closed over, so recompute is
-                    # deterministic.
-                    outs = jax.checkpoint(
-                        lambda w_, ins_, _od=opdef, _p=op.params, _c=ctx: (
-                            _od.forward(_p, w_, ins_, _c)
-                        )
-                    )(w, ins)
-                elif opdef.forward_stateful is not None:
-                    st = (net_state or {}).get(op.name, {})
-                    outs, new_st = opdef.forward_stateful(
-                        op.params, w, st, ins, ctx
-                    )
-                    if net_out is not None:
-                        # buffers are statistics, not a gradient path
-                        net_out[op.name] = jax.tree_util.tree_map(
-                            jax.lax.stop_gradient, new_st
-                        )
+            # one scope per PCG operator, parallel operators included:
+            # a device operation then names the graph node the search
+            # priced, a collective the Repartition / Combine / Reduction
+            # that caused it (trace time only)
+            with jax.named_scope(op.name):
+                ins = [vals[t.guid] for t in op.inputs]
+                if op.is_parallel_op:
+                    outs = par_ops.execute(op, ins, self.mesh)
                 else:
-                    outs = opdef.forward(op.params, w, ins, ctx)
-            for t, o in zip(op.outputs, outs):
-                vals[t.guid] = self._constrain(o, t)
+                    opdef = get_op_def(op.op_type)
+                    # fold in the op's index among COMPUTE ops, not its guid
+                    # (process-global counter — a rebuilt model would draw
+                    # different dropout masks for the same seed) and not its
+                    # raw topo position (the search inserts partition/combine
+                    # ops per mesh, which would make masks mesh-dependent)
+                    op_rng = (
+                        jax.random.fold_in(rng, compute_idx)
+                        if rng is not None else None
+                    )
+                    compute_idx += 1
+                    ctx = FwdCtx(
+                        training=training,
+                        rng=op_rng,
+                        seq_length=seq_length,
+                        compute_dtype=self.compute_dtype,
+                        aux_losses=aux_out,
+                        n_devices=self.mesh.size,
+                        mesh=self.mesh,
+                        op_name=op.name,
+                    )
+                    w = params.get(op.name, {})
+                    if training and self.remat and op.op_type in _REMAT_OPS:
+                        # Rematerialize in the backward instead of saving the
+                        # op's internals — for attention that drops the stored
+                        # s_q x s_kv scores/probs (the dominant HBM residual;
+                        # measured 30x+ train-step speedup at seq 512 where the
+                        # saved probs otherwise thrash HBM). Exact: same math,
+                        # recomputed. RNG is closed over, so recompute is
+                        # deterministic.
+                        outs = jax.checkpoint(
+                            lambda w_, ins_, _od=opdef, _p=op.params, _c=ctx: (
+                                _od.forward(_p, w_, ins_, _c)
+                            )
+                        )(w, ins)
+                    elif opdef.forward_stateful is not None:
+                        st = (net_state or {}).get(op.name, {})
+                        outs, new_st = opdef.forward_stateful(
+                            op.params, w, st, ins, ctx
+                        )
+                        if net_out is not None:
+                            # buffers are statistics, not a gradient path
+                            net_out[op.name] = jax.tree_util.tree_map(
+                                jax.lax.stop_gradient, new_st
+                            )
+                    else:
+                        outs = opdef.forward(op.params, w, ins, ctx)
+                for t, o in zip(op.outputs, outs):
+                    vals[t.guid] = self._constrain(o, t)
         return vals
 
     # -- step functions -----------------------------------------------------
@@ -879,25 +897,37 @@ class PCGExecutor:
             total_skips=jnp.asarray(0, jnp.int32),
         )
 
-    def _make_step(self):
+    def _make_step(self, program: Optional[str] = "train_step"):
+        """The train step's body. Its phases carry named scopes (trace
+        time only: they change the operations' metadata, not the code):
+        `ff.fwd` with one scope per PCG operator inside and `ff.loss`
+        (the backward of each then reads `transpose(jvp(ff.fwd))/...`),
+        `ff.grad_sync`, `ff.guard`, `ff.opt`, `ff.metrics`. `program`
+        labels the trace counter (None: the caller counts its own)."""
         guard = self.step_guard
         # overlap shardings are trace-time constants of the step program
         omap = self._overlap_specs()
 
         def step(state: TrainState, batch_inputs, labels, rng, *extra):
+            if program is not None:
+                _count_trace(program)
+
             def loss_of(params):
                 aux: list = []
                 net_out: dict = {}
-                vals = self.apply(
-                    params, self._input_vals(batch_inputs), training=True, rng=rng,
-                    aux_out=aux, net_state=state.net_state, net_out=net_out,
-                )
-                logits = vals[self.logits_pt.guid]
-                loss = self.loss_fn(logits, labels)
-                for a in aux:
-                    loss = loss + a
-                for r in self._reg_penalty(params):
-                    loss = loss + r
+                with jax.named_scope("ff.fwd"):
+                    vals = self.apply(
+                        params, self._input_vals(batch_inputs), training=True,
+                        rng=rng, aux_out=aux, net_state=state.net_state,
+                        net_out=net_out,
+                    )
+                    logits = vals[self.logits_pt.guid]
+                    with jax.named_scope("ff.loss"):
+                        loss = self.loss_fn(logits, labels)
+                        for a in aux:
+                            loss = loss + a
+                        for r in self._reg_penalty(params):
+                            loss = loss + r
                 if guard is not None:
                     # dynamic loss scaling: grads come out scaled and are
                     # unscaled below; the reported loss stays unscaled
@@ -919,8 +949,9 @@ class PCGExecutor:
                 # owned 1/d shards (partial norms psum to one scalar —
                 # no second full-tree traversal), and only the UPDATED
                 # params all-gather back (see _overlap_specs).
-                grads = self._constrain_weight_tree(grads, omap,
-                                                    sharded=True)
+                with jax.named_scope("ff.grad_sync"):
+                    grads = self._constrain_weight_tree(grads, omap,
+                                                        sharded=True)
             upd_src_params = (
                 self._constrain_weight_tree(state.params, omap,
                                             sharded=True)
@@ -929,16 +960,20 @@ class PCGExecutor:
             new_net = dict(state.net_state)
             new_net.update(net_out)
             if guard is None:
-                new_params, new_opt = self.optimizer.update(
-                    upd_src_params, grads, state.opt_state
-                )
-                if omap:
-                    new_params = self._constrain_weight_tree(
-                        new_params, omap, sharded=False
+                with jax.named_scope("ff.opt"):
+                    new_params, new_opt = self.optimizer.update(
+                        upd_src_params, grads, state.opt_state
                     )
-                    new_opt = self._constrain_opt_state(new_opt, omap)
+                if omap:
+                    # the all-gather of the updated parameters
+                    with jax.named_scope("ff.grad_sync"):
+                        new_params = self._constrain_weight_tree(
+                            new_params, omap, sharded=False
+                        )
+                        new_opt = self._constrain_opt_state(new_opt, omap)
                 new_guard = state.guard
-                partials = self.metrics.compute(logits, labels)
+                with jax.named_scope("ff.metrics"):
+                    partials = self.metrics.compute(logits, labels)
                 partials["loss"] = loss
                 if "grad_norm" in self.step_metrics:
                     # telemetry feed (set_step_metrics): the guard path
@@ -950,29 +985,34 @@ class PCGExecutor:
                 # multiplier (1.0 normally, NaN to simulate a bad batch)
                 poison = extra[0] if extra else jnp.asarray(1.0, jnp.float32)
                 inv = (poison / state.guard.loss_scale).astype(jnp.float32)
-                grads = jax.tree_util.tree_map(
-                    lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype),
-                    grads,
-                )
-                # under overlap the grads are data-sharded here, so this
-                # is a per-shard partial sum-of-squares + one scalar psum
-                # — the guard's old extra full-tree traversal is gone
-                gnorm = global_grad_norm(grads)
-                finite = jnp.isfinite(gnorm)
-                upd_params, upd_opt = self.optimizer.update(
-                    upd_src_params, grads, state.opt_state
-                )
+                with jax.named_scope("ff.guard"):
+                    grads = jax.tree_util.tree_map(
+                        lambda g: (g.astype(jnp.float32) * inv).astype(g.dtype),
+                        grads,
+                    )
+                    # under overlap the grads are data-sharded here, so
+                    # this is a per-shard partial sum-of-squares + one
+                    # scalar psum — the guard's old extra full-tree
+                    # traversal is gone
+                    gnorm = global_grad_norm(grads)
+                    finite = jnp.isfinite(gnorm)
+                with jax.named_scope("ff.opt"):
+                    upd_params, upd_opt = self.optimizer.update(
+                        upd_src_params, grads, state.opt_state
+                    )
                 # a skipped step carries params AND opt state through
                 # unchanged — momentum/bias-correction must not advance
                 # on a discarded gradient
-                new_params = _tree_select(finite, upd_params,
-                                          upd_src_params)
-                new_opt = _tree_select(finite, upd_opt, state.opt_state)
+                with jax.named_scope("ff.guard"):
+                    new_params = _tree_select(finite, upd_params,
+                                              upd_src_params)
+                    new_opt = _tree_select(finite, upd_opt, state.opt_state)
                 if omap:
-                    new_params = self._constrain_weight_tree(
-                        new_params, omap, sharded=False
-                    )
-                    new_opt = self._constrain_opt_state(new_opt, omap)
+                    with jax.named_scope("ff.grad_sync"):
+                        new_params = self._constrain_weight_tree(
+                            new_params, omap, sharded=False
+                        )
+                        new_opt = self._constrain_opt_state(new_opt, omap)
                 g = state.guard
                 cap = jnp.asarray(
                     guard.max_loss_scale
@@ -1006,7 +1046,8 @@ class PCGExecutor:
                 )
                 # skipped steps contribute nothing to epoch metrics (their
                 # logits/loss are NaN — summing would poison the epoch)
-                partials = self.metrics.compute(logits, labels)
+                with jax.named_scope("ff.metrics"):
+                    partials = self.metrics.compute(logits, labels)
                 partials["loss"] = loss
                 partials = jax.tree_util.tree_map(
                     lambda v: jnp.where(finite, v, jnp.zeros_like(v)), partials
@@ -1101,9 +1142,11 @@ class PCGExecutor:
             "guard's per-step poison/skip monitoring; resilient fit() "
             "dispatches stepwise (build_train_step)"
         )
-        step = self._make_step()
+        step = self._make_step(program=None)
 
         def multi(state, stacked_inputs, stacked_labels, rngs):
+            _count_trace("train_scan")
+
             def body(st, xs):
                 ins, lab, key = xs
                 st2, partials = step(st, ins, lab, key)
@@ -1406,6 +1449,8 @@ class PCGExecutor:
             (tok,) = batch_inputs
             tok = jnp.asarray(tok, plan.decode_pt.data_type.jnp_dtype)
             s0 = tok.shape[1]
+            # one token a row is a decode step, a block of them a prefill
+            _count_trace("decode_step" if s0 == 1 else "prefill")
             # t may be a scalar (all rows at the same position) or a (b,)
             # vector of per-row positions (continuous batching: each slot
             # of a running decode batch is mid-way through its own
@@ -1447,10 +1492,10 @@ class PCGExecutor:
                 return dec._slice_aligned(full, amap, t, s0, max_len,
                                           out_rank=out_rank, site=site)
 
-            for op in plan.live_ops:
+            def run_op(op):
                 if op.is_parallel_op:
                     vals[op.outputs[0].guid] = vals[op.inputs[0].guid]
-                    continue
+                    return
                 d = get_op_def(op.op_type)
                 w = params.get(op.name, {})
                 ot = op.op_type
@@ -1565,6 +1610,13 @@ class PCGExecutor:
                                     cache, v.astype(cache.dtype), t, axis=ax
                                 )
                             )
+
+            # the same scopes as the train step's forward: ff.decode, then
+            # one per PCG operator
+            with jax.named_scope("ff.decode"):
+                for op in plan.live_ops:
+                    with jax.named_scope(op.name):
+                        run_op(op)
             return vals[self.logits_pt.guid], new_caches
 
         built = (init_caches, jax.jit(step))

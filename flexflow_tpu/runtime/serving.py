@@ -667,6 +667,11 @@ class GenerationRequest:
         self.admitted_t: Optional[float] = None  # last slot admission
         self.first_token_t: Optional[float] = None
         self.finished_t: Optional[float] = None
+        # one time.monotonic() per generated token, stamped where the
+        # token is appended to its slot (the first IS first_token_t): the
+        # gaps are what a streaming user sees, an admission's stall of
+        # every slot included. Restarts with a failover re-admission.
+        self.token_t: List[float] = []
         self.generation = 0  # bumped on failover requeue
         # flight recorder (obs/request_trace.py): ReplicaSet.submit /
         # AdmissionQueue.offer mint a sampled context; the shared null
@@ -1036,7 +1041,16 @@ class ContinuousBatcher:
         self.stats = {"admitted": 0, "finished": 0, "iterations": 0,
                       "prefills": 0, "retired_eos": 0, "shed_decode": 0,
                       "stranded_requeued": 0, "decode_retunes": 0,
-                      "prefix_hits": 0, "prefill_skips": 0}
+                      "prefix_hits": 0, "prefill_skips": 0,
+                      # always-on phase counters: seconds inside each
+                      # ff.serve.* span (obs.mark), and the prompt tokens
+                      # prefilled against the bucket they were padded to
+                      "admit_s": 0.0, "prefill_s": 0.0, "insert_s": 0.0,
+                      "decode_s": 0.0, "decode_prepare_s": 0.0,
+                      "decode_dispatch_s": 0.0, "decode_wait_s": 0.0,
+                      "decode_fetch_s": 0.0, "decode_sample_s": 0.0,
+                      "idle_s": 0.0, "prefill_tokens": 0,
+                      "prefill_bucket_tokens": 0}
 
     def _decode_executor_mismatch(self, dex, initB_d) -> Optional[str]:
         """None if the decode-searched lowering can serve the batched
@@ -1131,6 +1145,15 @@ class ContinuousBatcher:
             return False
         from .. import obs
 
+        # from a successful poll to the slot filled or the request shed
+        with obs.mark("ff.serve.admit", cat="serving",
+                      into=(self.stats, "admit_s"), request=req.id,
+                      prompt_len=len(req.prompt)) as span:
+            return self._admit(req, span)
+
+    def _admit(self, req: GenerationRequest, span) -> bool:
+        from .. import obs
+
         now = time.monotonic()
         plen = len(req.prompt)
         total = plen + req.max_new_tokens
@@ -1212,6 +1235,7 @@ class ContinuousBatcher:
                      else None)
         cached = (self._prefix_cache.get(cache_key)
                   if cache_key is not None else None)
+        span.set(slot=slot_idx, bucket=bucket, skipped=cached is not None)
         prefill_span = req.trace.span("prefill", replica=self.name,
                                       bucket=bucket, prompt_len=plen,
                                       skipped=cached is not None)
@@ -1225,6 +1249,8 @@ class ContinuousBatcher:
                 self.stats["prefill_skips"] += 1
             else:
                 first, caches1 = self._prefill(req, plen)
+                self.stats["prefill_tokens"] += plen
+                self.stats["prefill_bucket_tokens"] += bucket
                 if cache_key is not None:
                     self._prefix_cache[cache_key] = (first, caches1)
                     while (len(self._prefix_cache)
@@ -1236,6 +1262,7 @@ class ContinuousBatcher:
         self._insert_slot(slot_idx, caches1)
         prefill_span.done()
         req.first_token_t = time.monotonic()
+        req.token_t = [req.first_token_t]
         obs.observe("ff_serving_ttft_seconds",
                     req.first_token_t - req.submitted_t,
                     help="time from submit to first generated token")
@@ -1260,10 +1287,15 @@ class ContinuousBatcher:
         The padded tail's garbage K/V sits at positions >= plen, which
         decode overwrites position-by-position before the causal mask
         ever exposes them."""
+        from .. import obs
+
         bucket = self._bucket(plen)
         padded = np.zeros((1, bucket), self._id_dt)
         padded[0, :plen] = req.prompt.astype(self._id_dt)
-        with self._device_lock:
+        with self._device_lock, obs.mark(
+                "ff.serve.prefill", cat="serving",
+                into=(self.stats, "prefill_s"), request=req.id,
+                bucket=bucket):
             caches1 = self._init1(self.model.state.params, ())
             logits, caches1 = self._step1(
                 self.model.state.params, caches1, jnp.int32(0),
@@ -1276,9 +1308,11 @@ class ContinuousBatcher:
         """Swap a prefilled batch-1 cache strip into the running batch:
         every per-slot cache leaf is written wholesale at `slot_idx`, so
         whatever a previous occupant left there is fully replaced."""
-        import jax
+        from .. import obs
 
-        with self._device_lock:
+        with self._device_lock, obs.mark(
+                "ff.serve.insert", cat="serving",
+                into=(self.stats, "insert_s"), slot=slot_idx):
             self._insert_slot_locked(jax, slot_idx, caches1)
 
     def _insert_slot_locked(self, jax, slot_idx: int, caches1) -> None:
@@ -1379,56 +1413,79 @@ class ContinuousBatcher:
 
     # -- the iteration loop ---------------------------------------------
     def _decode_iteration(self) -> None:
-        t_vec = np.zeros(self.config.slots, np.int32)
-        toks = np.zeros((self.config.slots, 1), self._id_dt)
-        active = []
-        sampled_any = False
-        for i, slot in enumerate(self.slots):
-            if slot is None:
-                continue
-            active.append(i)
-            sampled_any = sampled_any or slot.req.trace.sampled
-            t_vec[i] = slot.pos
-            toks[i, 0] = slot.tokens[slot.pos]
-            if self.config.share_prefixes:
-                # protocol guard: this step writes K/V at slot.pos. Only
-                # full PROMPT blocks are ever published, and decode
-                # positions sit strictly past them, so this is a no-op in
-                # steady state — but if a shared page were ever in the
-                # write path, the pool copies it private here (COW)
-                # instead of letting the write leak into siblings
-                self.pool.note_write(slot.seq_key, slot.pos)
-        span_t0 = time.perf_counter() if sampled_any else 0.0
-        with self._device_lock:
-            logits, self._caches = self._stepB(
-                self.model.state.params, self._caches, jnp.asarray(t_vec),
-                [jnp.asarray(toks)],
-            )
-            logits = np.asarray(logits)
-        span_dur = (time.perf_counter() - span_t0) if sampled_any else 0.0
-        occupancy = len(active)
-        for i in active:
-            slot = self.slots[i]
-            if slot is None:
-                continue  # taken by a concurrent teardown sweep mid-step
-            slot.tokens.append(int(logits[i, 0].argmax(-1)))
-            slot.pos += 1
-            new_pages = self.pool.touch(
-                slot.seq_key, max(self._bucket(slot.prompt_len), slot.pos))
-            if slot.req.trace.sampled:
-                # one completed span per sampled slot per iteration:
-                # slot occupancy + position make decode stalls and
-                # batch-sharing visible per request in the Perfetto lane
-                slot.req.trace.iteration(
-                    self.name, t0=span_t0, dur_s=span_dur,
-                    iteration=self._iteration, slot=i, pos=slot.pos,
-                    occupancy=occupancy,
-                )
-                if new_pages:
-                    slot.req.trace.event("kv_touch", replica=self.name,
-                                         pages=len(new_pages),
-                                         pos=slot.pos)
-            self._maybe_retire(i)
+        from .. import obs
+
+        stats = self.stats
+        with obs.mark("ff.serve.decode", cat="serving",
+                      into=(stats, "decode_s"), iteration=self._iteration,
+                      occupancy=self.active_slots) as span:
+            with obs.mark("ff.serve.decode.prepare", cat="serving",
+                          into=(stats, "decode_prepare_s")):
+                t_vec = np.zeros(self.config.slots, np.int32)
+                toks = np.zeros((self.config.slots, 1), self._id_dt)
+                active = []
+                for i, slot in enumerate(self.slots):
+                    if slot is None:
+                        continue
+                    active.append(i)
+                    t_vec[i] = slot.pos
+                    toks[i, 0] = slot.tokens[slot.pos]
+                    if self.config.share_prefixes:
+                        # protocol guard: this step writes K/V at
+                        # slot.pos. Only full PROMPT blocks are ever
+                        # published, and decode positions sit strictly
+                        # past them, so this is a no-op in steady state —
+                        # but if a shared page were ever in the write
+                        # path, the pool copies it private here (COW)
+                        # instead of letting the write leak into siblings
+                        self.pool.note_write(slot.seq_key, slot.pos)
+            with self._device_lock:
+                with obs.mark("ff.serve.decode.dispatch", cat="serving",
+                              into=(stats, "decode_dispatch_s")):
+                    logits, self._caches = self._stepB(
+                        self.model.state.params, self._caches,
+                        jnp.asarray(t_vec), [jnp.asarray(toks)],
+                    )
+                # ONE sync, split in two: the wait for the device, then
+                # the copy of the slots x vocabulary logits to the host
+                with obs.mark("ff.serve.decode.wait", cat="serving",
+                              into=(stats, "decode_wait_s")):
+                    jax.block_until_ready(logits)
+                with obs.mark("ff.serve.decode.fetch", cat="serving",
+                              into=(stats, "decode_fetch_s")) as fetch:
+                    logits = np.asarray(logits)
+            # a sampled request's share of the iteration: from the
+            # ff.serve.decode span's start to the logits on the host
+            span_dur = fetch.t0 + fetch.dur - span.t0
+            occupancy = len(active)
+            with obs.mark("ff.serve.decode.sample", cat="serving",
+                          into=(stats, "decode_sample_s")):
+                for i in active:
+                    slot = self.slots[i]
+                    if slot is None:
+                        continue  # taken by a concurrent teardown sweep mid-step
+                    slot.tokens.append(int(logits[i, 0].argmax(-1)))
+                    slot.req.token_t.append(time.monotonic())
+                    slot.pos += 1
+                    new_pages = self.pool.touch(
+                        slot.seq_key,
+                        max(self._bucket(slot.prompt_len), slot.pos))
+                    if slot.req.trace.sampled:
+                        # one completed span per sampled slot per
+                        # iteration: slot occupancy + position make decode
+                        # stalls and batch-sharing visible per request in
+                        # the Perfetto lane
+                        slot.req.trace.iteration(
+                            self.name, t0=span.t0, dur_s=span_dur,
+                            iteration=self._iteration, slot=i, pos=slot.pos,
+                            occupancy=occupancy,
+                        )
+                        if new_pages:
+                            slot.req.trace.event("kv_touch",
+                                                 replica=self.name,
+                                                 pages=len(new_pages),
+                                                 pos=slot.pos)
+                    self._maybe_retire(i)
 
     def _warmup_compiles(self) -> None:
         """Compile the batched decode step and every prefill bucket on
@@ -1604,7 +1661,10 @@ class ContinuousBatcher:
                     if self._retune_wanted():
                         self._retune_decode()
                         continue
-                    time.sleep(self.config.idle_wait_s)
+                    with obs.mark("ff.serve.idle", cat="serving",
+                                  into=(self.stats, "idle_s"),
+                                  session=False):
+                        time.sleep(self.config.idle_wait_s)
                     continue
                 it = self._iteration
                 if self.monitor is not None:
