@@ -175,8 +175,10 @@ def phase_kernels(ctx):
     slots = len(DECODE_LENGTHS)
     for dtype in (jnp.float32, jnp.bfloat16):
         q = jnp.asarray(rng.randn(slots, HEADS, HEAD_DIM), dtype)
+        # the pool in the cache's own layout: a page is PAGE positions of
+        # all heads
         kp, vp = (jnp.asarray(
-            rng.randn(HEADS, slots * pages_per_slot, PAGE, HEAD_DIM), dtype)
+            rng.randn(slots * pages_per_slot, PAGE, HEADS, HEAD_DIM), dtype)
             for _ in range(2))
         # scattered physical pages, ragged lengths (one token .. full)
         table = jnp.asarray(rng.permutation(slots * pages_per_slot)

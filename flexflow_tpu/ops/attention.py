@@ -73,6 +73,11 @@ def _dropout_fallback(impl: str, op_name: str, reason: str) -> None:
         "paged_block": "the paged flash-decode kernel attends ONE query "
                        "token per slot; multi-token blocks (prefill) "
                        "keep the dense masked path",
+        "paged_untileable": "Mosaic cannot tile the paged flash-decode "
+                            "kernel at this shape (heads*head_dim must "
+                            "fill 128-lane registers and a page whole "
+                            "sublane tiles: kernels/decode.py "
+                            "decode_block_pages)",
     }[reason]
     kind = "dropout" if reason in ("kernel", "mesh", "backend", "seq") \
         else "paged decode" if reason.startswith("paged_") \
@@ -437,11 +442,12 @@ def _forward_decode(params, weights, inputs, ctx, cache, t):
     executor.build_decode). Inputs are the NEW positions' slices
     (b, s0, e) starting at position t (s0 = 1 for token-by-token decode,
     s0 = prompt_len for one-shot prefill); cache holds (k, v) of shape
-    (b, max_len, h, d) with positions < t valid. Appends the block's K/V
-    and attends its queries against the prefix with intra-block causal
-    masking — cache-width attention rows per token instead of the full
-    O(L²) forward the reference's serving prototype would re-run (it has
-    no KV cache; triton/README.md calls it an incomplete prototype).
+    (b, max_len, h*d) with positions < t valid (init_decode_cache says
+    why the heads are folded). Appends the block's K/V and attends its
+    queries against the prefix with intra-block causal masking —
+    cache-width attention rows per token instead of the full O(L²)
+    forward the reference's serving prototype would re-run (it has no KV
+    cache; triton/README.md calls it an incomplete prototype).
 
     Requires self-attention (q_in is k_in is v_in upstream) — the decode
     builder rejects cross-attention graphs.
@@ -468,27 +474,31 @@ def _forward_decode(params, weights, inputs, ctx, cache, t):
     v_new = jnp.einsum("bse,ehd->bshd", v_in, wv,
                        preferred_element_type=jnp.float32).astype(q_in.dtype)
     k_cache, v_cache = cache
+    b, s0, h = q.shape[:3]
+    max_len = k_cache.shape[1]
+    # the cache keeps a position's heads folded into one row (b, max_len,
+    # h*d): the new rows fold the same way
+    k_new = k_new.reshape(b, s0, -1).astype(k_cache.dtype)
+    v_new = v_new.reshape(b, s0, -1).astype(v_cache.dtype)
     per_row_t = getattr(t, "ndim", 0) == 1
     if per_row_t:
         row_update = jax.vmap(
-            lambda c, n, tt: jax.lax.dynamic_update_slice(c, n, (tt, 0, 0))
+            lambda c, n, tt: jax.lax.dynamic_update_slice(c, n, (tt, 0))
         )
-        k_cache = row_update(k_cache, k_new.astype(k_cache.dtype), t)
-        v_cache = row_update(v_cache, v_new.astype(v_cache.dtype), t)
+        k_cache = row_update(k_cache, k_new, t)
+        v_cache = row_update(v_cache, v_new, t)
     else:
-        k_cache = jax.lax.dynamic_update_slice(
-            k_cache, k_new.astype(k_cache.dtype), (0, t, 0, 0)
-        )
-        v_cache = jax.lax.dynamic_update_slice(
-            v_cache, v_new.astype(v_cache.dtype), (0, t, 0, 0)
-        )
+        k_cache = jax.lax.dynamic_update_slice(k_cache, k_new, (0, t, 0))
+        v_cache = jax.lax.dynamic_update_slice(v_cache, v_new, (0, t, 0))
     # FF_DECODE_IMPL ∈ {auto, dense, paged}: "paged" routes single-token
     # steps through the Pallas paged flash-decode kernel
-    # (kernels/decode.py — the dense per-slot cache viewed as a paged
-    # pool, online softmax over pages, dead pages skipped); "auto"
-    # engages it only where the compiled kernel runs (TPU backend);
-    # "dense" pins the per-row masked reference path. Ineligible "paged"
-    # requests fall back dense with the shared
+    # (kernels/decode.py): the cache strips, as they lie, are the paged
+    # pool (a reshape, no copy), one grid step is one slot with all its
+    # heads, and only a slot's live pages are read. "auto" engages it
+    # only where the compiled kernel runs (TPU backend); "dense" pins the
+    # per-row masked reference path. A step the kernel cannot take (a
+    # multi-token block under "paged"; a shape Mosaic cannot tile under
+    # either) falls back dense with the shared
     # ff_attention_fallback_total{reason} counter + one warning.
     impl = os.environ.get("FF_DECODE_IMPL", "auto")
     if impl not in ("auto", "dense", "paged"):
@@ -498,53 +508,85 @@ def _forward_decode(params, weights, inputs, ctx, cache, t):
 
     use_paged = interpret = False
     if impl == "paged":
-        if q.shape[1] != 1:
+        if s0 != 1:
             _dropout_fallback(impl, ctx.op_name, "paged_block")
         else:
             # asked for by hand: off the TPU that means the interpreter
             use_paged, interpret = True, not pallas_compiled()
     elif impl == "auto":  # interpret mode on CPU would lose to XLA dense
-        use_paged = q.shape[1] == 1 and pallas_compiled()
+        use_paged = s0 == 1 and pallas_compiled()
     if use_paged:
         from ..kernels.decode import (
+            decode_block_pages,
             decode_page_size,
             paged_flash_decode,
             paged_view_of_cache,
         )
-        b = q.shape[0]
+        page_size = decode_page_size(max_len)
+        # the interpreter takes any shape; Mosaic only what it can tile
+        if not interpret and decode_block_pages(
+                k_cache.shape[-1], v_cache.shape[-1], page_size,
+                q.dtype) is None:
+            _dropout_fallback(impl, ctx.op_name, "paged_untileable")
+            use_paged = False
+    if use_paged:
         kp, vp, table = paged_view_of_cache(
-            k_cache.astype(q.dtype), v_cache.astype(q.dtype),
-            decode_page_size(k_cache.shape[1]),
-        )
+            k_cache.astype(q.dtype), v_cache.astype(q.dtype), page_size)
         lengths = (t.astype(jnp.int32) if per_row_t
                    else jnp.full((b,), t, jnp.int32)) + 1
         attn = paged_flash_decode(
             q[:, 0], kp, vp, table, lengths, interpret=interpret,
         )[:, None]                     # (b, 1, h, dv)
     else:
+        k_all, v_all = k_cache.astype(q.dtype), v_cache.astype(q.dtype)
         scale = 1.0 / jnp.sqrt(jnp.asarray(params.qk_head_dim, jnp.float32))
-        scores = jnp.einsum(
-            "bshd,bthd->bhst", q, k_cache.astype(q.dtype),
-            preferred_element_type=jnp.float32,
-        ) * scale                      # (b, h, s0, max_len)
-        pos = jnp.arange(k_cache.shape[1])      # cache positions
+        if s0 == 1:
+            # one token a slot: every head's scores in one product over
+            # the folded rows, as the kernel does it. Row h of `q_bd`
+            # holds head h's values on head h's lanes, so no 4-D view of
+            # the cache is made: on the TPU that view is a whole-cache
+            # relayout (init_decode_cache), while heads times the MXU work
+            # is nothing beside the cache's bytes.
+            def own_lanes(per_head):
+                return (jnp.arange(h * per_head)[None, :] // per_head
+                        == jnp.arange(h)[:, None])             # (h, h*d)
+            q_bd = jnp.where(own_lanes(params.qk_head_dim),
+                             q.reshape(b, 1, -1), 0)           # (b, h, h*d)
+            scores = jnp.einsum(
+                "bhk,btk->bht", q_bd, k_all,
+                preferred_element_type=jnp.float32,
+            )[:, :, None] * scale
+        else:
+            scores = jnp.einsum(
+                "bshd,bthd->bhst", q, k_all.reshape(b, max_len, h, -1),
+                preferred_element_type=jnp.float32,
+            ) * scale                  # (b, h, s0, max_len)
+        pos = jnp.arange(max_len)               # cache positions
         if per_row_t:
-            q_pos = t[:, None] + jnp.arange(q.shape[1])[None, :]  # (b, s0)
+            q_pos = t[:, None] + jnp.arange(s0)[None, :]          # (b, s0)
             scores = jnp.where(
                 pos[None, None, None, :] <= q_pos[:, None, :, None],
                 scores, jnp.finfo(jnp.float32).min,
             )
         else:
-            q_pos = t + jnp.arange(q.shape[1])  # this block's positions
+            q_pos = t + jnp.arange(s0)          # this block's positions
             scores = jnp.where(
                 pos[None, None, None, :] <= q_pos[None, None, :, None],
                 scores, jnp.finfo(jnp.float32).min,
             )
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        attn = jnp.einsum(
-            "bhst,bthd->bshd", probs, v_cache.astype(q.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(q.dtype)
+        if s0 == 1:
+            attn = jnp.einsum(
+                "bht,btk->bhk", probs[:, :, 0], v_all,
+                preferred_element_type=jnp.float32,
+            )                          # (b, h, h*dv): row h's own lanes
+            attn = jnp.where(own_lanes(params.v_head_dim), attn, 0).sum(1) \
+                .reshape(b, 1, h, -1).astype(q.dtype)
+        else:
+            attn = jnp.einsum(
+                "bhst,bthd->bshd", probs, v_all.reshape(b, max_len, h, -1),
+                preferred_element_type=jnp.float32,
+            ).astype(q.dtype)
     out = jnp.einsum("bshd,hde->bse", attn, wo,
                      preferred_element_type=jnp.float32)
     out = out.astype(q_in.dtype)  # post-cast dtype, same as _forward
@@ -607,11 +649,18 @@ def _forward_decode_cross(params, weights, q_in, ctx, kv):
 
 def init_decode_cache(params: MultiHeadAttentionParams, batch: int,
                       max_len: int, dtype):
-    """Fresh (k, v) cache for one MHA op."""
+    """Fresh (k, v) cache for one MHA op: (batch, max_len, heads*d), one
+    position's heads folded into one row. The fold decides how the cache
+    lies in the TPU's memory: XLA lays a 4-D (batch, max_len, heads, 64)
+    array out with max_len innermost (a 64-wide minor axis would be padded
+    to the 128 lanes), so every view of it by position, the paged kernel's
+    pages and the per-token append alike, was a whole-cache transpose;
+    with heads*d innermost a position is one contiguous row and a page a
+    contiguous run of them."""
     h, dqk, dv = params.num_heads, params.qk_head_dim, params.v_head_dim
     return (
-        jnp.zeros((batch, max_len, h, dqk), dtype),
-        jnp.zeros((batch, max_len, h, dv), dtype),
+        jnp.zeros((batch, max_len, h * dqk), dtype),
+        jnp.zeros((batch, max_len, h * dv), dtype),
     )
 
 

@@ -246,7 +246,7 @@ def op_decode_bytes(op: PCGOp) -> float:
     n = float(op_weight_bytes(op))
     if op.op_type == OperatorType.OP_MULTIHEAD_ATTENTION \
             and len(op.inputs) >= 3:
-        # the persistent (b, max_len, h, d) K/V pair the step attends
+        # the persistent (b, max_len, h*d) K/V pair the step attends
         # over — byte-equivalent to the full k/v inputs; the cache is
         # materialized at the compute width (bf16 under AMP)
         for x in op.inputs[1:3]:

@@ -18,6 +18,7 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -174,33 +175,75 @@ def test_compile_decode_strategy_roundtrips_through_strategy_io(tmp_path):
 # paged flash-decode kernel (kernels/decode.py) — interpret-mode parity
 # ---------------------------------------------------------------------------
 
-def test_paged_flash_decode_matches_dense_reference():
+@pytest.mark.parametrize("b,h,d,page,pp,lengths,dtype", [
+    # a long-running slot, a freshly admitted 1-token slot (mid-stream
+    # admission), and a mid-stream one; all inside one block of 128
+    (3, 2, 8, 4, 4, [10, 1, 7], np.float32),
+    # ragged 1 .. max_len over a scattered table: lengths that are no
+    # multiple of the page (37, 129), a slot that ends exactly with its
+    # first block, a full one (two blocks, the second 8 live pages of 32)
+    # and an empty one (reads nothing, gives zeros)
+    (6, 3, 8, 4, 40, [1, 160, 129, 37, 0, 128], np.float32),
+    # the serving cell's shape class at fewer slots: 32 heads of 64, page
+    # 16, bf16, what decode_block_pages tiles for the chip
+    (3, 32, 64, 16, 32, [512, 37, 300], jnp.bfloat16),
+], ids=["three-slots", "ragged-scattered", "cell-shape-bf16"])
+def test_paged_flash_decode_matches_dense_reference(b, h, d, page, pp,
+                                                    lengths, dtype):
     from flexflow_tpu.kernels.decode import (
+        decode_block_pages,
         paged_decode_reference,
         paged_flash_decode,
     )
 
-    b, h, d, page, pp = 3, 2, 8, 4, 4
     rng = np.random.RandomState(0)
-    q = rng.randn(b, h, d).astype(np.float32)
-    pool_k = rng.randn(h, b * pp, page, d).astype(np.float32)
-    pool_v = rng.randn(h, b * pp, page, d).astype(np.float32)
+    q = jnp.asarray(rng.randn(b, h, d), dtype)
+    # position-major pool, the way the cache lies: (pages, page, heads, d)
+    pool_k = jnp.asarray(rng.randn(b * pp, page, h, d), dtype)
+    pool_v = jnp.asarray(rng.randn(b * pp, page, h, d), dtype)
     # scattered, non-contiguous page assignment per slot
-    table = rng.permutation(b * pp)[: b * pp].reshape(b, pp).astype(np.int32)
-    # ragged positions: a long-running slot, a freshly admitted 1-token
-    # slot (mid-stream admission), and a mid-stream one
-    lengths = np.array([10, 1, 7], np.int32)
+    table = rng.permutation(b * pp).reshape(b, pp).astype(np.int32)
+    lengths = np.array(lengths, np.int32)
+    if dtype == jnp.bfloat16:
+        assert decode_block_pages(h * d, h * d, page, dtype) == 8
     out = paged_flash_decode(q, pool_k, pool_v, table, lengths,
                              interpret=True)
     ref = paged_decode_reference(q, pool_k, pool_v, table, lengths)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+    out, ref = (np.asarray(x, np.float32) for x in (out, ref))
+    live = lengths > 0
+    assert np.all(out[~live] == 0)
+    np.testing.assert_allclose(
+        out[live], ref[live], atol=1e-5 if dtype == np.float32 else 2e-2)
 
 
-def test_paged_view_of_cache_matches_dense_attention():
+def test_decode_block_pages_follows_the_shapes():
+    """Block sizes come from heads*d, the page, the dtype's tile and the
+    stated VMEM budget; a shape Mosaic cannot tile gives None."""
+    from flexflow_tpu.kernels.decode import (
+        KV_VMEM_BUDGET,
+        decode_block_pages,
+    )
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    assert decode_block_pages(2048, 2048, 16, bf16) == 8     # the cell
+    assert decode_block_pages(1024, 1024, 16, f32) == 8      # chip_smoke
+    assert decode_block_pages(2048, 2048, 8, f32) == 16      # page 8
+    assert decode_block_pages(1024, 1024, 48, f32) == 8      # 384 = 3 x 128
+    # a wide model: two K and two V blocks must fit the budget
+    assert 4 * 128 * 8192 * 2 <= KV_VMEM_BUDGET
+    assert decode_block_pages(8192, 8192, 16, bf16) == 8
+    assert decode_block_pages(16384, 16384, 16, bf16) is None
+    # lanes not filled; a bf16 page of 8 rows is half a sublane tile
+    assert decode_block_pages(16, 16, 16, f32) is None
+    assert decode_block_pages(2048, 2048, 8, bf16) is None
+
+
+@pytest.mark.parametrize("preferred", [4, 6], ids=["page-4", "page-6"])
+def test_paged_view_of_cache_matches_dense_attention(preferred):
     """The serving adapter: dense per-slot caches viewed as a paged pool
-    must reproduce plain masked attention over the dense caches."""
-    import jax.numpy as jnp
-
+    must reproduce plain masked attention over the dense caches, and the
+    view is a reshape of the strips as they lie: slot b's page i is rows
+    [i*page, (i+1)*page) of strip b."""
     from flexflow_tpu.kernels.decode import (
         decode_page_size,
         paged_flash_decode,
@@ -213,9 +256,14 @@ def test_paged_view_of_cache_matches_dense_attention():
     vc = rng.randn(b, max_len, h, d).astype(np.float32)
     q = rng.randn(b, h, d).astype(np.float32)
     lengths = np.array([5, 9], np.int32)
-    ps = decode_page_size(max_len, preferred=4)
-    assert max_len % ps == 0
-    kp, vp, table = paged_view_of_cache(jnp.asarray(kc), jnp.asarray(vc), ps)
+    ps = decode_page_size(max_len, preferred=preferred)
+    assert ps == preferred
+    folded = (b, max_len, h * d)       # the cache as init_decode_cache lays it
+    kp, vp, table = paged_view_of_cache(
+        jnp.asarray(kc.reshape(folded)), jnp.asarray(vc.reshape(folded)), ps)
+    assert kp.shape == (b * max_len // ps, ps, h * d)
+    np.testing.assert_array_equal(
+        np.asarray(kp)[np.asarray(table)].reshape(kc.shape), kc)
     out = np.asarray(paged_flash_decode(q, kp, vp, table, lengths,
                                         interpret=True))
     # dense oracle straight off the original caches
@@ -227,7 +275,36 @@ def test_paged_view_of_cache_matches_dense_attention():
     ref = np.einsum("bht,bthd->bhd", p, vc)
     np.testing.assert_allclose(out, ref, atol=1e-5)
     with pytest.raises(ValueError):
-        paged_view_of_cache(jnp.asarray(kc), jnp.asarray(vc), 5)
+        paged_view_of_cache(jnp.asarray(kc.reshape(folded)),
+                            jnp.asarray(vc.reshape(folded)), 5)
+
+
+def test_decode_attn_bench_rehearses_and_both_branches_agree(tmp_path):
+    """scripts/decode_attn_bench.py (PERF.md's kernel-against-dense table)
+    runs end to end off the chip at a toy shape, and the whole attention
+    op gives the same output under FF_DECODE_IMPL=paged (the kernel,
+    interpreted) and =dense (the block-diagonal product over the folded
+    rows) at every profile of slot lengths."""
+    out = tmp_path / "attn.json"
+    script = os.path.join(REPO, "scripts", "decode_attn_bench.py")
+    r = subprocess.run(
+        [sys.executable, script, "--cpu-rehearsal", "--slots", "4",
+         "--heads", "4", "--head-dim", "32", "--max-len", "64",
+         "--layers", "2", "--reps", "1", "--out", str(out)],
+        capture_output=True, text=True, env=os.environ.copy(), timeout=600,
+    )
+    assert r.returncode == 0, r.stdout + r.stderr
+    got = json.loads(out.read_text())
+    assert not got["on_chip"] and set(got["profiles"]) == {
+        "3-live", "third", "half", "full"}
+    for row in got["profiles"].values():
+        assert row["kernel_rel_err"] < 2e-2          # bf16
+        assert row["op_paged_out"] == pytest.approx(row["op_dense_out"],
+                                                    rel=1e-2)
+    # without a chip and without the flag it refuses to print a rate
+    r = subprocess.run([sys.executable, script], capture_output=True,
+                       text=True, env=os.environ.copy(), timeout=300)
+    assert r.returncode == 2 and "no rate" in r.stderr
 
 
 def test_decode_impl_env_gates_paged_path(monkeypatch):

@@ -70,16 +70,110 @@ def test_flash_forward_backward_lowers_for_tpu(bh, seq, dtype, dropout):
     assert _mosaic_calls(_fwd_bwd(attn), x, x, x) == 2
 
 
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_flash_decode_lowers_for_tpu(dtype):
-    slots, heads, d, max_len, page = 8, 16, 64, 1024, 16
+def _paged_decode_args(slots, heads, d, max_len, page, dtype):
+    """paged_flash_decode's operands for a pool of slots * max_len
+    positions in the cache's own layout, (pages, page, heads * d)."""
     pages = max_len // page
-    q = jax.ShapeDtypeStruct((slots, heads, d), dtype)
-    pool = jax.ShapeDtypeStruct((heads, slots * pages, page, d), dtype)
-    table = jax.ShapeDtypeStruct((slots, pages), jnp.int32)
-    lengths = jax.ShapeDtypeStruct((slots,), jnp.int32)
-    assert _mosaic_calls(paged_flash_decode, q, pool, pool, table,
-                         lengths) == 1
+    pool = jax.ShapeDtypeStruct((slots * pages, page, heads * d), dtype)
+    return (jax.ShapeDtypeStruct((slots, heads, d), dtype), pool, pool,
+            jax.ShapeDtypeStruct((slots, pages), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32))
+
+
+# chip_smoke.py's server (8 slots, 16 heads) and the serving cell
+# `serve-opt1.3b-saturated` (16 slots, 32 heads), both 64 wide, max_len
+# 1,024 in pages of 16
+PAGED_DECODE_SHAPES = [(8, 16, 64, 1024, 16), (16, 32, 64, 1024, 16)]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("shape", PAGED_DECODE_SHAPES,
+                         ids=["chip-smoke", "serving-cell"])
+def test_paged_flash_decode_lowers_for_tpu(shape, dtype):
+    assert _mosaic_calls(paged_flash_decode,
+                         *_paged_decode_args(*shape, dtype)) == 1
+
+
+def _decode_step_module(monkeypatch, hidden, heads, layers=2, slots=4,
+                        max_len=32):
+    """The batched decode step of a small causal LM, exported for the TPU
+    from here: `pallas_compiled` says yes, as it would on the chip, so
+    `auto` takes the kernel wherever the shape allows."""
+    from flexflow_tpu import (ActiMode, AggrMode, DataType, FFConfig,
+                              FFModel, LossType, MetricsType, SGDOptimizer)
+    from flexflow_tpu.kernels import attention as kattn
+
+    cfg = FFConfig()
+    cfg.batch_size = slots
+    cfg.search_budget = 1
+    cfg.workersPerNode = 1    # one chip: a Mosaic call is not partitioned
+    m = FFModel(cfg)
+    ids = m.create_tensor((slots, max_len), DataType.DT_INT32)
+    t = m.embedding(ids, 29, hidden, AggrMode.AGGR_MODE_NONE)
+    for _ in range(layers):
+        t = m.multihead_attention(t, t, t, hidden, heads, causal=True)
+        t = m.dense(t, hidden, ActiMode.AC_MODE_RELU)
+    m.softmax(m.dense(t, 29))
+    m.compile(SGDOptimizer(lr=0.01),
+              LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
+              [MetricsType.METRICS_ACCURACY])
+    monkeypatch.delenv("FF_DECODE_IMPL", raising=False)
+    monkeypatch.setattr(kattn, "pallas_compiled", lambda: True)
+    init, step = m.executor.build_decode(slots, max_len)
+    params = m.state.params
+    caches = jax.eval_shape(init, params, ())
+    exported = jax.export.export(step, platforms=["tpu"])(
+        params, caches, jax.ShapeDtypeStruct((slots,), jnp.int32),
+        [jax.ShapeDtypeStruct((slots, 1), jnp.int32)])
+    return exported.mlir_module(), caches
+
+
+def test_decode_step_holds_one_kernel_a_layer_and_no_cache_transpose(
+        monkeypatch):
+    """The kernel reads the cache where it lies: in the decode step's
+    module there is one Mosaic call a layer and no `transpose` whose
+    operand has as many elements as a cache (the pool is a reshape of the
+    strips; the per-head re-layout of PR 25's kernel is gone)."""
+    import re
+
+    layers, slots, max_len, hidden = 2, 4, 32, 128
+    text, caches = _decode_step_module(monkeypatch, hidden, heads=2,
+                                       layers=layers, slots=slots,
+                                       max_len=max_len)
+    assert text.count("tpu_custom_call") == layers
+    leaves = jax.tree_util.tree_leaves(caches["mha"])
+    assert {leaf.shape for leaf in leaves} == {(slots, max_len, hidden)}
+    cache_elems = slots * max_len * hidden
+    for line in text.splitlines():
+        if "stablehlo.transpose" not in line:
+            continue
+        dims = re.search(r"tensor<([0-9x]+)x[a-z]", line).group(1)
+        assert np.prod([int(n) for n in dims.split("x")]) < cache_elems, line
+
+
+def test_untileable_decode_shape_falls_back_dense_and_counts_once(
+        monkeypatch, tmp_path):
+    """heads * head_dim = 16 fills no 128-lane register: under `auto` on
+    the TPU the one layer's step takes the dense branch, counts the new
+    reason once and warns once."""
+    import warnings
+
+    import flexflow_tpu.obs as obs
+    from flexflow_tpu.obs import TelemetryConfig
+    from flexflow_tpu.ops import attention as mha
+
+    mha.reset_attention_fallback_warnings()
+    with obs.session(TelemetryConfig(dir=str(tmp_path / "tel"))):
+        with warnings.catch_warnings(record=True) as caught:
+            warnings.simplefilter("always")
+            text, _ = _decode_step_module(monkeypatch, hidden=16, heads=2,
+                                          layers=1)
+        count = obs.active().metrics.find("ff_attention_fallback_total",
+                                          reason="paged_untileable")
+        assert count is not None and count.value == 1.0
+    assert text.count("tpu_custom_call") == 0
+    told = [w for w in caught if "cannot tile" in str(w.message)]
+    assert len(told) == 1, [str(w.message) for w in caught]
 
 
 @pytest.mark.slow
@@ -91,15 +185,11 @@ def test_kernels_compile_for_v5e_without_a_chip():
     attn = functools.partial(flash_attention_folded, causal=True,
                              dropout=0.1, seeds=jnp.array([1, 2], jnp.uint32))
     _compile_for_v5e(_fwd_bwd(attn), x, x, x)
-    slots, heads, d, pages, page = 8, 16, 64, 64, 16
-    for dtype in (jnp.float32, jnp.bfloat16):
-        _compile_for_v5e(
-            paged_flash_decode,
-            jax.ShapeDtypeStruct((slots, heads, d), dtype),
-            jax.ShapeDtypeStruct((heads, slots * pages, page, d), dtype),
-            jax.ShapeDtypeStruct((heads, slots * pages, page, d), dtype),
-            jax.ShapeDtypeStruct((slots, pages), jnp.int32),
-            jax.ShapeDtypeStruct((slots,), jnp.int32))
+    # Mosaic's scoped-VMEM limit, met here before the chip is
+    for shape in PAGED_DECODE_SHAPES:
+        for dtype in (jnp.float32, jnp.bfloat16):
+            _compile_for_v5e(paged_flash_decode,
+                             *_paged_decode_args(*shape, dtype))
 
 
 def test_flash_lowers_for_tpu_under_shard_map():
