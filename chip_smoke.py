@@ -5,7 +5,9 @@ calls, at the full width of the model the repo's chip history belongs to
 (hidden 1024, 16 heads, 12 layers), with seeded random weights:
 
   kernels   every Pallas kernel compiled (interpret=False) at the shapes the
-            full-width model produces, against its jnp reference
+            full-width model produces, against its jnp reference; the gated
+            delta rule's chunked form against its recurrence at the hybrid
+            serving cell's head sizes, buckets of 256..4,096 with a masked tail
   trainer   FFModel -> build_transformer -> compile() -> fit(), seq 512,
             batch 8, mixed precision; one dispatch per step and several steps
             per dispatch; the loss must be finite and falling
@@ -58,6 +60,8 @@ if REHEARSAL:
     PROMPT_LENS = [2, 5, 9, 17, 40]
     FLASH_SHAPES = [(8, 32, jnp.float32, 0.0), (8, 32, jnp.float32, 0.1)]
     DECODE_LENGTHS = [1, 16, 17, 64]
+    # (heads, key size, value size), then (bucket, real tokens) per row
+    DELTA_HEADS, DELTA_BLOCKS = (2, 16, 8), [(64, 50), (128, 128), (128, 0)]
 else:
     HIDDEN, HEADS, LAYERS, SEQ, BATCH = 1024, 16, 12, 512, 8
     VOCAB, MAX_LEN, SLOTS = 32000, 1024, 8
@@ -71,6 +75,11 @@ else:
         (HEADS, 1024, jnp.bfloat16, 0.0),
     ]
     DECODE_LENGTHS = [1, 16, 17, 100, 333, 512, 1000, 1024]
+    # the gated delta rule at the hybrid serving cell's head sizes: every
+    # bucket a prompt of 256..3,584 tokens takes, each with a masked tail
+    DELTA_HEADS = (30, 96, 192)
+    DELTA_BLOCKS = [(256, 256), (512, 300), (1024, 1000), (2048, 1536),
+                    (4096, 3584)]
 HEAD_DIM = HIDDEN // HEADS
 PAGE = 16
 NEW_TOKENS = 8
@@ -194,6 +203,46 @@ def phase_kernels(ctx):
                 f"{jnp.dtype(dtype).name}")
         log(f"  {name}: rel err = {err:.1e}")
         assert np.isfinite(err) and err < KERNEL_TOL, name
+        ctx["kernels"].append(name)
+
+    # the gated delta rule (ops/linear_attention.py): the chunked form on a
+    # bucket whose tail is masked, against the recurrence over the real
+    # tokens alone, from a state that is not zero
+    from flexflow_tpu.ops.linear_attention import (delta_rule_chunked,
+                                                   delta_rule_step)
+
+    h, dk, dv = DELTA_HEADS
+
+    def recurrence(S, q, k, v, g, beta):
+        def token(S, c):
+            o, S = delta_rule_step(S, *c)
+            return S, o
+        S, o = jax.lax.scan(token, S, tuple(
+            jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta)))
+        return jnp.moveaxis(o, 0, 1), S
+
+    for bucket, real in DELTA_BLOCKS:
+        q, k = (rng.randn(1, bucket, h, dk).astype(np.float32)
+                for _ in range(2))
+        q /= np.linalg.norm(q, axis=-1, keepdims=True) * np.sqrt(dk)
+        k /= np.linalg.norm(k, axis=-1, keepdims=True)
+        v = rng.randn(1, bucket, h, dv).astype(np.float32)
+        live = (np.arange(bucket) < real)[None, :, None]
+        beta = np.where(live, 2.0 * rng.rand(1, bucket, h), 0.0) \
+            .astype(np.float32)
+        g = np.where(live, np.log(rng.uniform(0.9, 0.999, (1, bucket, h))),
+                     0.0).astype(np.float32)
+        S0 = jnp.asarray(0.1 * rng.randn(1, h, dv, dk), jnp.float32)
+        o, S = jax.jit(delta_rule_chunked)(S0, q, k, v, g, beta)
+        o_r, S_r = jax.jit(recurrence)(
+            S0, *(a[:, :real] for a in (q, k, v, g, beta)))
+        errs = [rel_err(S, S_r)] + (
+            [rel_err(o[:, :real], o_r)] if real else [])
+        name = (f"delta_rule_chunked heads={h} dk={dk} dv={dv} bucket="
+                f"{bucket} real={real}")
+        log(f"  {name}: rel err state/out = "
+            + "/".join(f"{e:.1e}" for e in errs))
+        assert all(np.isfinite(e) and e < KERNEL_TOL for e in errs), name
         ctx["kernels"].append(name)
 
 

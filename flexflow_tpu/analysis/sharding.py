@@ -32,7 +32,8 @@ from .diagnostics import AnalysisReport, Severity
 _DEGREE_PRESERVING = frozenset(
     t for t in (
         OperatorType.OP_RELU, OperatorType.OP_SIGMOID, OperatorType.OP_TANH,
-        OperatorType.OP_ELU, OperatorType.OP_GELU, OperatorType.OP_LEAKYRELU,
+        OperatorType.OP_ELU, OperatorType.OP_GELU, OperatorType.OP_SILU,
+        OperatorType.OP_LEAKYRELU,
         OperatorType.OP_DROPOUT, OperatorType.OP_SOFTMAX,
         OperatorType.OP_EW_ADD, OperatorType.OP_EW_MUL,
         OperatorType.OP_EW_SUB, OperatorType.OP_EW_DIV,

@@ -332,6 +332,14 @@ class FFModel:
         )
         return self._add_layer(OperatorType.OP_LAYERNORM, p, [input], name)
 
+    def rms_norm(self, input: Tensor, eps: float = 1e-6,
+                 name: str = "") -> Tensor:
+        """RMS norm over the last axis with a learned scale: the layer
+        norm op without the mean and the bias."""
+        p = LayerNormParams(axes=(-1,), elementwise_affine=True, eps=eps,
+                            rms=True)
+        return self._add_layer(OperatorType.OP_LAYERNORM, p, [input], name)
+
     def batch_matmul(
         self,
         A: Tensor,
@@ -358,6 +366,8 @@ class FFModel:
         add_zero_attn: bool = False,
         kernel_initializer=None,
         causal: bool = False,
+        qk_norm: bool = False,
+        qk_norm_eps: float = 1e-6,
         name: str = "",
     ) -> Tensor:
         p = MultiHeadAttentionParams(
@@ -370,6 +380,8 @@ class FFModel:
             add_bias_kv=add_bias_kv,
             add_zero_attn=add_zero_attn,
             causal=causal,
+            qk_norm=qk_norm,
+            qk_norm_eps=qk_norm_eps,
         )
         inits = (
             {k: kernel_initializer for k in ("wq", "wk", "wv", "wo")}
@@ -451,6 +463,9 @@ class FFModel:
 
     def gelu(self, x, name=""):
         return self._unary(OperatorType.OP_GELU, x, name)
+
+    def silu(self, x, name=""):
+        return self._unary(OperatorType.OP_SILU, x, name)
 
     def identity(self, x, name=""):
         return self._unary(OperatorType.OP_IDENTITY, x, name)
@@ -612,6 +627,28 @@ class FFModel:
             [input],
             name,
         )
+
+    def gated_delta_net(self, input: Tensor, num_heads: int, head_k_dim: int,
+                        head_v_dim: int, conv_kernel: int = 4,
+                        allow_neg_eigval: bool = True, norm_eps: float = 1e-6,
+                        kernel_initializer=None, name="") -> Tensor:
+        """Gated delta-rule linear attention over (batch, seq, embed): a
+        recurrent state per head in place of keys and values
+        (ops/linear_attention.py)."""
+        from ..ops.linear_attention import GatedDeltaNetParams
+
+        p = GatedDeltaNetParams(
+            embed_dim=input.dims[-1], num_heads=num_heads,
+            head_k_dim=head_k_dim, head_v_dim=head_v_dim,
+            conv_kernel=conv_kernel, allow_neg_eigval=allow_neg_eigval,
+            norm_eps=norm_eps)
+        inits = (
+            {k: kernel_initializer for k in
+             ("wq", "wk", "wv", "wz", "wo", "wb", "wa", "conv")}
+            if kernel_initializer else None
+        )
+        return self._add_layer(OperatorType.OP_GATED_DELTA_NET, p, [input],
+                               name, inits)
 
     # MoE family (reference: moe.cc:20-44 FFModel::moe composite)
     def group_by(self, input: Tensor, assign: Tensor, n: int, alpha: float, name=""):
@@ -1121,8 +1158,21 @@ class FFModel:
         # the static perf pass — run under the decode objective so FFA509
         # (over-sharded KV heads, latency-bound per-token collectives on
         # the critical path) fires here, at compile time
+        from ..analysis.precision import (
+            annotate_graph_precision,
+            precision_diagnostics,
+        )
         from ..search import run_strategy_validators
 
+        # the decode graph's precision flow first (decode serves under the
+        # same AMP dtype as training compute): the validators read the
+        # accumulators it stamps, and a graph declared in bfloat16 has
+        # none until then
+        annotate_graph_precision(
+            graph,
+            compute_dtype=(DataType.DT_BF16
+                           if cfg.allow_mixed_precision else None),
+        )
         problems = run_strategy_validators(graph, views, ndev)
         if problems:
             warnings.warn(
@@ -1148,19 +1198,8 @@ class FFModel:
             warnings=len(perf_rep.warnings),
             codes=sorted({d.code for d in perf_rep}),
         )
-        # FFA7xx precision audit of the decode strategy: annotate the
-        # decode graph's precision flow (decode serves under the same AMP
-        # dtype as training compute) and vet it like the train path does
-        from ..analysis.precision import (
-            annotate_graph_precision,
-            precision_diagnostics,
-        )
-
-        annotate_graph_precision(
-            graph,
-            compute_dtype=(DataType.DT_BF16
-                           if cfg.allow_mixed_precision else None),
-        )
+        # FFA7xx precision audit of the decode strategy, vetted like the
+        # train path's
         prec_rep = precision_diagnostics(
             graph, views=views, num_devices=ndev,
             drift_budget=cfg.precision_drift_budget,
@@ -3186,4 +3225,5 @@ def _to_acti(a) -> ActiMode:
         "sigmoid": ActiMode.AC_MODE_SIGMOID,
         "tanh": ActiMode.AC_MODE_TANH,
         "gelu": ActiMode.AC_MODE_GELU,
+        "silu": ActiMode.AC_MODE_SILU,
     }[a]

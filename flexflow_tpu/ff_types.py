@@ -82,6 +82,8 @@ class ActiMode(enum.IntEnum):
     AC_MODE_SIGMOID = 12
     AC_MODE_TANH = 13
     AC_MODE_GELU = 14
+    # TPU addition: x * sigmoid(x), the gate of a gated feed-forward
+    AC_MODE_SILU = 15
 
 
 class AggrMode(enum.IntEnum):
@@ -249,6 +251,10 @@ class OperatorType(enum.IntEnum):
     OP_WEIGHT_SHARD = 1121
     # recurrence (reference implements LSTM only in the standalone nmt/)
     OP_LSTM = 1130
+    # gated delta-rule linear attention: a recurrent state per head in
+    # place of keys and values (ops/linear_attention.py)
+    OP_GATED_DELTA_NET = 1131
+    OP_SILU = 1132
 
 
 PARALLEL_OP_TYPES = frozenset(

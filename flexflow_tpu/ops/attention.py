@@ -91,6 +91,11 @@ def _dropout_fallback(impl: str, op_name: str, reason: str) -> None:
     )
 
 
+# past this many bytes of float32 scores a block of decode queries takes the
+# chunked scan (the same budget _forward streams from)
+_DENSE_SCORE_BYTES = 256 * 1024 * 1024
+
+
 @dataclasses.dataclass(frozen=True)
 class MultiHeadAttentionParams:
     """reference: include/flexflow/ops/attention_params.h"""
@@ -104,6 +109,10 @@ class MultiHeadAttentionParams:
     add_bias_kv: bool = False
     add_zero_attn: bool = False
     causal: bool = False  # TPU addition: causal masking for decoder models
+    # RMS norms on the projected q and k, each over its whole heads x head
+    # size projection with a learned scale (the OLMo 2 block's QK-norm)
+    qk_norm: bool = False
+    qk_norm_eps: float = 1e-6
 
     # reference semantics (attention.cc:86): kdim/vdim are PER-HEAD
     # projection sizes (qProjSize = kdim); 0 means embed_dim/num_heads.
@@ -139,7 +148,30 @@ def _weights(params: MultiHeadAttentionParams, in_shapes, in_dtypes):
     ]
     if params.bias:
         ws.append(WeightSpec("bias_o", (params.embed_dim,), dt, "zero"))
+    if params.qk_norm:
+        ws.append(WeightSpec("q_norm", (h * dqk,), dt, "one"))
+        ws.append(WeightSpec("k_norm", (h * dqk,), dt, "one"))
     return ws
+
+
+def _qk_norm(params: MultiHeadAttentionParams, weights, q, k, layout="bshd"):
+    """The projected q and k through their RMS norms (float32 inside), or
+    as they came where the op has none. `layout` says where the heads and
+    the head size lie: "bshd" or "bhsd"."""
+    if not params.qk_norm:
+        return q, k
+    h_ax = layout.index("h")
+    shape = [1, 1, 1, q.shape[3]]
+    shape[h_ax] = q.shape[h_ax]
+
+    def norm(x, scale):
+        xf = x.astype(jnp.float32)
+        ms = jnp.mean(xf * xf, axis=(h_ax, 3), keepdims=True)
+        scale = scale.astype(jnp.float32).reshape(x.shape[h_ax], x.shape[3])
+        return (xf * jax.lax.rsqrt(ms + params.qk_norm_eps)
+                * scale.reshape(shape)).astype(x.dtype)
+
+    return norm(q, weights["q_norm"]), norm(k, weights["k_norm"])
 
 
 def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
@@ -203,7 +235,7 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
             _dropout_fallback(impl, ctx.op_name, "kernel")
         elif impl == "flash" or (
                 impl == "auto"
-                and (on_tpu or score_bytes > 256 * 1024 * 1024)):
+                and (on_tpu or score_bytes > _DENSE_SCORE_BYTES)):
             # without dropout this call would have streamed
             if not on_tpu:
                 _dropout_fallback(impl, ctx.op_name, "backend")
@@ -231,8 +263,10 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
                         preferred_element_type=jnp.float32)
         vf = jnp.einsum("bse,ehd->bhsd", v_in, wv,
                         preferred_element_type=jnp.float32)
-        qf = qf.astype(q_in.dtype).reshape(b * h, seq_len, dqk)
-        kf = kf.astype(q_in.dtype).reshape(b * h, kv_len, dqk)
+        qf, kf = _qk_norm(params, weights, qf.astype(q_in.dtype),
+                          kf.astype(q_in.dtype), "bhsd")
+        qf = qf.reshape(b * h, seq_len, dqk)
+        kf = kf.reshape(b * h, kv_len, dqk)
         vf = vf.astype(q_in.dtype).reshape(b * h, kv_len, dv)
         attn = flash_attention_folded(
             qf, kf, vf, params.causal,
@@ -255,8 +289,8 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     q = jnp.einsum("bse,ehd->bshd", q_in, wq, preferred_element_type=jnp.float32)
     k = jnp.einsum("bse,ehd->bshd", k_in, wk, preferred_element_type=jnp.float32)
     v = jnp.einsum("bse,ehd->bshd", v_in, wv, preferred_element_type=jnp.float32)
-    q = q.astype(q_in.dtype)
-    k = k.astype(q_in.dtype)
+    q, k = _qk_norm(params, weights, q.astype(q_in.dtype),
+                    k.astype(q_in.dtype))
     v = v.astype(q_in.dtype)
 
     # Dispatch: on TPU the fused Pallas kernel (fwd + bwd in VMEM,
@@ -294,7 +328,7 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     use_streaming = (
         impl in ("flash", "chunked", "ring", "ulysses")
         or (impl == "auto"
-            and (prefer_flash or score_bytes > 256 * 1024 * 1024))
+            and (prefer_flash or score_bytes > _DENSE_SCORE_BYTES))
     ) and not use_dropout
     # Sequence/context parallelism: with the seq axis sharded, the dense
     # and flash paths would make XLA all-gather the full K/V on every chip;
@@ -473,6 +507,7 @@ def _forward_decode(params, weights, inputs, ctx, cache, t):
                        preferred_element_type=jnp.float32).astype(q_in.dtype)
     v_new = jnp.einsum("bse,ehd->bshd", v_in, wv,
                        preferred_element_type=jnp.float32).astype(q_in.dtype)
+    q, k_new = _qk_norm(params, weights, q, k_new)
     k_cache, v_cache = cache
     b, s0, h = q.shape[:3]
     max_len = k_cache.shape[1]
@@ -537,6 +572,19 @@ def _forward_decode(params, weights, inputs, ctx, cache, t):
         attn = paged_flash_decode(
             q[:, 0], kp, vp, table, lengths, interpret=interpret,
         )[:, None]                     # (b, 1, h, dv)
+    elif (s0 > 1 and not per_row_t
+          and 4 * b * h * s0 * max_len > _DENSE_SCORE_BYTES):
+        # a long prompt's block (serving's prefill from position t): the
+        # dense branch below would hold (b, h, s0, max_len) float32 scores,
+        # 2 GB at 30 heads and 4,096 x 4,096; the chunked scan of
+        # kernels/attention.py walks the cache 256 positions at a time
+        # under the same mask (cache position <= t + row)
+        from ..kernels.attention import _chunk_scan
+
+        attn, _, _ = _chunk_scan(
+            q, k_cache.astype(q.dtype).reshape(b, max_len, h, -1),
+            v_cache.astype(q.dtype).reshape(b, max_len, h, -1),
+            causal=True, chunk_size=min(256, max_len), q_offset=t)
     else:
         k_all, v_all = k_cache.astype(q.dtype), v_cache.astype(q.dtype)
         scale = 1.0 / jnp.sqrt(jnp.asarray(params.qk_head_dim, jnp.float32))

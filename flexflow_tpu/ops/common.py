@@ -21,4 +21,6 @@ def apply_activation(mode: ActiMode, x):
         return jnp.tanh(x)
     if mode == ActiMode.AC_MODE_GELU:
         return jax.nn.gelu(x)
+    if mode == ActiMode.AC_MODE_SILU:
+        return jax.nn.silu(x)
     raise ValueError(f"unknown activation {mode}")

@@ -30,6 +30,7 @@ _UNARY_FNS = {
     OperatorType.OP_TANH: jnp.tanh,
     OperatorType.OP_ELU: jax.nn.elu,
     OperatorType.OP_GELU: jax.nn.gelu,
+    OperatorType.OP_SILU: jax.nn.silu,
     OperatorType.OP_RSQRT: lambda x: jax.lax.rsqrt(x),
     OperatorType.OP_SQRT: jnp.sqrt,
     OperatorType.OP_SIN: jnp.sin,

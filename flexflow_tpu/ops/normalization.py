@@ -14,6 +14,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 
 from ..ff_types import DataType, OperatorType
@@ -114,6 +115,9 @@ class LayerNormParams:
     axes: Tuple[int, ...] = (-1,)
     elementwise_affine: bool = True
     eps: float = 1e-5
+    # RMS norm (Zhang & Sennrich 2019): no mean is taken off and there is
+    # no bias, y = x / sqrt(mean(x^2) + eps) * scale
+    rms: bool = False
 
 
 def _ln_infer(params, in_shapes, in_dtypes):
@@ -125,23 +129,36 @@ def _ln_weights(params: LayerNormParams, in_shapes, in_dtypes):
         return []
     s = in_shapes[0]
     norm_shape = tuple(s[a % len(s)] for a in params.axes)
-    return [
-        WeightSpec("scale", norm_shape, in_dtypes[0], "one"),
-        WeightSpec("bias", norm_shape, in_dtypes[0], "zero"),
-    ]
+    scale = WeightSpec("scale", norm_shape, in_dtypes[0], "one")
+    if params.rms:
+        return [scale]
+    return [scale, WeightSpec("bias", norm_shape, in_dtypes[0], "zero")]
+
+
+def rms_normalize(x, scale, eps):
+    """x / sqrt(mean(x^2) + eps) * scale over the last axis, in float32;
+    the attention ops' q/k norms and the gated output norm share it."""
+    xf = x.astype(jnp.float32)
+    y = xf * jax.lax.rsqrt(jnp.mean(xf * xf, axis=-1, keepdims=True) + eps)
+    return y * scale.astype(jnp.float32)
 
 
 def _ln_forward(params: LayerNormParams, weights, inputs, ctx):
     (x,) = inputs
     axes = tuple(a % x.ndim for a in params.axes)
     xf = x.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=axes, keepdims=True)
-    var = jnp.var(xf, axis=axes, keepdims=True)
-    y = (xf - mean) / jnp.sqrt(var + params.eps)
+    if params.rms:
+        y = xf * jax.lax.rsqrt(
+            jnp.mean(xf * xf, axis=axes, keepdims=True) + params.eps)
+    else:
+        mean = jnp.mean(xf, axis=axes, keepdims=True)
+        var = jnp.var(xf, axis=axes, keepdims=True)
+        y = (xf - mean) / jnp.sqrt(var + params.eps)
     if params.elementwise_affine:
         bshape = [x.shape[a] if a in axes else 1 for a in range(x.ndim)]
         y = y * weights["scale"].astype(jnp.float32).reshape(bshape)
-        y = y + weights["bias"].astype(jnp.float32).reshape(bshape)
+        if not params.rms:
+            y = y + weights["bias"].astype(jnp.float32).reshape(bshape)
     return [y.astype(x.dtype)]
 
 

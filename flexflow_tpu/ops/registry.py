@@ -159,6 +159,7 @@ def ensure_ops_loaded():
         embedding,
         fused,
         linear,
+        linear_attention,
         lstm,
         moe,
         normalization,

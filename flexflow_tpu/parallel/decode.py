@@ -149,9 +149,9 @@ def _is_unary_pointwise(op) -> bool:
           ) or op.op_type in (
         OperatorType.OP_EXP, OperatorType.OP_LOG, OperatorType.OP_RELU,
         OperatorType.OP_SIGMOID, OperatorType.OP_TANH, OperatorType.OP_ELU,
-        OperatorType.OP_GELU, OperatorType.OP_RSQRT, OperatorType.OP_SQRT,
-        OperatorType.OP_SIN, OperatorType.OP_COS, OperatorType.OP_POW,
-        OperatorType.OP_PRELU,
+        OperatorType.OP_GELU, OperatorType.OP_SILU, OperatorType.OP_RSQRT,
+        OperatorType.OP_SQRT, OperatorType.OP_SIN, OperatorType.OP_COS,
+        OperatorType.OP_POW, OperatorType.OP_PRELU,
     )
 
 
@@ -209,6 +209,16 @@ class _Propagator:
                 fail("causal cross-attention has no decode rule")
             # cross-attention: k/v static (encoder side) — full-length
             # K/V computed once, no causal mask (matches the full forward)
+            set_out(0, AxisInfo(live=1))
+            return
+
+        if t == OperatorType.OP_GATED_DELTA_NET:
+            (a,) = ins
+            if a.live != 1 or a.prefix is not None:
+                fail("the input must be (batch, seq, embed) with the live "
+                     "axis at 1")
+            # the op carries its own per-slot state from block to block
+            # (executor.build_decode's "recurrent" cache section)
             set_out(0, AxisInfo(live=1))
             return
 

@@ -1260,6 +1260,20 @@ class PCGExecutor:
         attends the precomputed encoder K/V, and static/constant operands
         (positional tables, masks) are sliced per step.
 
+        An op that keeps a state of fixed size in place of keys and values
+        (the gated delta-rule mixer, ops/linear_attention.py) has a cache
+        section of its own, caches["recurrent"][op.name] = (S, conv_tail),
+        zero at init_caches. Such a state is never overwritten position by
+        position, so a block that is padded (serving's prefill buckets)
+        says how many of its tokens are real: step(..., valid), a scalar or
+        a (batch,) count; positions at or beyond it leave the state as it
+        was. None means the whole block. A caller that wants one row of a
+        block's output (a prefill wants the last real token's logits, not
+        bucket x vocabulary of them) says which: step(..., row), a scalar
+        or a (batch,) index into the block; what follows the last op that
+        mixes positions then runs on that row alone and the output has one
+        position.
+
         step's `t` may be a scalar (the generate APIs: every row at the
         same position) or a (batch,) int vector of per-row positions —
         the continuous-batching contract (runtime/serving.py): each slot
@@ -1271,6 +1285,7 @@ class PCGExecutor:
         self-attention, softmax over the live axis."""
         from . import decode as dec
         from ..ops.attention import cross_decode_kv, init_decode_cache
+        from ..ops.linear_attention import init_state as init_recurrent_state
 
         key = (batch, max_len, cache_dtype, decode_input, assume_causal)
         cached = self._decode_builds.get(key)
@@ -1318,10 +1333,12 @@ class PCGExecutor:
 
         # MHA classification: self-attention (live k/v -> per-op KV cache)
         # vs cross-attention (static k/v -> precomputed encoder K/V)
-        mha_self, mha_cross = [], []
+        mha_self, mha_cross, recurrent = [], [], []
         for op in plan.live_ops:
             if op.is_parallel_op:
                 continue
+            if op.op_type == OperatorType.OP_GATED_DELTA_NET:
+                recurrent.append(op)
             if op.op_type == OperatorType.OP_MULTIHEAD_ATTENTION:
                 if plan.info.get(op.inputs[1].guid, dec.AxisInfo()).is_live:
                     mha_self.append(op)
@@ -1417,7 +1434,12 @@ class PCGExecutor:
                 # K/V): separate key so serving's beam reorder can skip
                 # gathering them
                 "mha_static": {},
+                # state of fixed size per slot, beside the keys and values
+                "recurrent": {},
             }
+            for op in recurrent:
+                caches["recurrent"][op.name] = init_recurrent_state(
+                    op.params, batch, cdt)
             for g in plan.cached_guids:
                 pt = next(x for op in plan.live_ops for x in op.outputs
                           if x.guid == g)
@@ -1444,8 +1466,20 @@ class PCGExecutor:
         cached_set = set(plan.cached_guids)
         mha_cross_set = {id(op) for op in mha_cross}
         mha_self_set = {id(op) for op in mha_self}
+        recurrent_set = {id(op) for op in recurrent}
+        # the last live op that mixes positions: attention, a recurrent
+        # op, or a primitive-op attention's products and prefix softmax.
+        # Every op after it treats the positions of a block alike.
+        last_mixing = max(
+            (i for i, op in enumerate(plan.live_ops)
+             if not op.is_parallel_op and (
+                 id(op) in mha_self_set | mha_cross_set | recurrent_set
+                 or op.op_type in (OperatorType.OP_BATCHMATMUL,
+                                   OperatorType.OP_SOFTMAX)
+                 or any(x.guid in cached_set for x in op.outputs))),
+            default=-1)
 
-        def step(params, caches, t, batch_inputs):
+        def step(params, caches, t, batch_inputs, valid=None, row=None):
             (tok,) = batch_inputs
             tok = jnp.asarray(tok, plan.decode_pt.data_type.jnp_dtype)
             s0 = tok.shape[1]
@@ -1461,6 +1495,9 @@ class PCGExecutor:
                     f"per-row positions: {t.shape[0]} positions for "
                     f"{tok.shape[0]} rows"
                 )
+            if valid is not None:
+                valid = jnp.broadcast_to(
+                    jnp.asarray(valid, jnp.int32), (tok.shape[0],))
             consts = _materialize_constants()
             statics = dict(caches["static"])
             vals = {plan.decode_pt.guid: tok}
@@ -1469,6 +1506,7 @@ class PCGExecutor:
                 "prefix": dict(caches["prefix"]),
                 "mha": dict(caches["mha"]),
                 "mha_static": caches["mha_static"],
+                "recurrent": dict(caches["recurrent"]),
             }
 
             def get_static(g):
@@ -1506,6 +1544,12 @@ class PCGExecutor:
                     outs, new_caches["mha"][op.name] = d.forward_decode(
                         op.params, w, ins, ctx, caches["mha"][op.name], t
                     )
+                elif id(op) in recurrent_set:
+                    ins = [vals[x.guid] for x in op.inputs]
+                    outs, new_caches["recurrent"][op.name] = \
+                        d.forward_decode(
+                            op.params, w, ins, ctx,
+                            caches["recurrent"][op.name], t, valid=valid)
                 elif id(op) in mha_cross_set:
                     from ..ops.attention import _forward_decode_cross
 
@@ -1614,9 +1658,30 @@ class PCGExecutor:
             # the same scopes as the train step's forward: ff.decode, then
             # one per PCG operator
             with jax.named_scope("ff.decode"):
-                for op in plan.live_ops:
+                for i, op in enumerate(plan.live_ops):
                     with jax.named_scope(op.name):
                         run_op(op)
+                    if i == last_mixing and row is not None and s0 > 1:
+                        # from here on one position a row: every live
+                        # value is cut to it, and static operands are
+                        # sliced at that position (aligned_input reads
+                        # t and s0)
+                        at = jnp.broadcast_to(
+                            jnp.asarray(row, jnp.int32), (tok.shape[0],))
+                        for g, v in list(vals.items()):
+                            ax = info.get(g, dec.AxisInfo()).live
+                            if ax is None:
+                                continue
+                            if ax == 0:
+                                raise NotImplementedError(
+                                    "one row of a block: a live tensor has "
+                                    "no batch axis before its live axis")
+                            vals[g] = jax.vmap(
+                                lambda r, n, _ax=ax:
+                                jax.lax.dynamic_slice_in_dim(
+                                    r, n, 1, axis=_ax - 1)
+                            )(v, at)
+                        t, s0 = t + jnp.asarray(row, jnp.int32), 1
             return vals[self.logits_pt.guid], new_caches
 
         built = (init_caches, jax.jit(step))

@@ -29,6 +29,7 @@ _FUSABLE = {
     OperatorType.OP_SIGMOID,
     OperatorType.OP_TANH,
     OperatorType.OP_GELU,
+    OperatorType.OP_SILU,
     OperatorType.OP_ELU,
     OperatorType.OP_EXP,
     OperatorType.OP_SCALAR_MULTIPLY,

@@ -889,6 +889,36 @@ def kv_page_bytes(model, page_size: int,
     return total or None
 
 
+def recurrent_slot_bytes(model) -> int:
+    """Bytes ONE slot holds of recurrent state across the model's gated
+    delta-rule layers (ops/linear_attention.py state_bytes): the second
+    kind of per-slot state. It does not grow with the sequence, so it is
+    no page's cost: a slot pays it once, whatever it reserves in pages.
+    0 for a graph with no such layer."""
+    import numpy as np
+
+    from ..ff_types import OperatorType
+    from ..ops.linear_attention import state_bytes
+
+    ex = getattr(model, "executor", None)
+    if ex is None:
+        return 0
+    cdt = getattr(ex, "compute_dtype", None)
+    itemsize = np.dtype(cdt if cdt is not None else np.float32).itemsize
+    return sum(state_bytes(op.params, itemsize) for op in ex.topo
+               if getattr(op, "op_type", None)
+               == OperatorType.OP_GATED_DELTA_NET)
+
+
+def slot_reservation_bytes(model, config: KVCacheConfig,
+                           tokens: int) -> int:
+    """What a slot that reserves `tokens` positions takes of the device's
+    memory, both kinds of state counted: its pages of keys and values
+    and its recurrent state."""
+    page = kv_page_bytes(model, config.page_size, config.kv_dtype) or 0
+    return config.pages_for(tokens) * page + recurrent_slot_bytes(model)
+
+
 # ----------------------------------------------------------------------
 # auditor CLI: python -m flexflow_tpu.runtime.kvcache {audit,selftest}
 # ----------------------------------------------------------------------

@@ -64,7 +64,8 @@ from ..obs.request_trace import (
     mint_request_trace,
     record_request_stages,
 )
-from .kvcache import KVCacheConfig, KVCacheExhaustedError, PagePool
+from .kvcache import (KVCacheConfig, KVCacheExhaustedError, PagePool,
+                      slot_reservation_bytes)
 from .resilience import ResilienceError
 from .verify import NotCompiledError, ServingConfigError
 
@@ -369,7 +370,8 @@ def incremental_beam_generate(
             idx = jnp.asarray(src_beams.astype(np.int32))
             gathered = jax.tree_util.tree_map(
                 lambda c: jnp.take(c, idx, axis=0),
-                {"prefix": caches["prefix"], "mha": caches["mha"]},
+                {"prefix": caches["prefix"], "mha": caches["mha"],
+                 "recurrent": caches["recurrent"]},
             )
             caches = {"static": caches["static"],
                       "mha_static": caches["mha_static"], **gathered}
@@ -899,6 +901,13 @@ class AdmissionQueue:
 # ----------------------------------------------------------------------
 # continuous (in-flight) batching
 # ----------------------------------------------------------------------
+@jax.jit
+def _best_id(logits):
+    """The best id of a batch-1, one-position output (1, 1, vocab), picked
+    on the device: the host fetches a scalar."""
+    return jnp.argmax(logits[0, 0])
+
+
 @dataclasses.dataclass
 class _Slot:
     req: GenerationRequest
@@ -1050,7 +1059,13 @@ class ContinuousBatcher:
                       "decode_dispatch_s": 0.0, "decode_wait_s": 0.0,
                       "decode_fetch_s": 0.0, "decode_sample_s": 0.0,
                       "idle_s": 0.0, "prefill_tokens": 0,
-                      "prefill_bucket_tokens": 0}
+                      "prefill_bucket_tokens": 0,
+                      # bucket - prompt, summed: positions a prefill ran
+                      # and every op with a recurrent state masked
+                      "prefill_masked_tokens": 0,
+                      # gauges: bytes the slots hold of each kind of
+                      # per-slot state, set when the caches are made
+                      "kv_cache_bytes": 0, "recurrent_state_bytes": 0}
 
     def _decode_executor_mismatch(self, dex, initB_d) -> Optional[str]:
         """None if the decode-searched lowering can serve the batched
@@ -1062,7 +1077,9 @@ class ContinuousBatcher:
         sections must agree (guids differ across lowerings, so in
         practice both must be empty — true for decoder-only fused-MHA
         graphs), 'mha' sections must cover the same op names with the
-        same per-slot leaf shapes. Probed with jax.eval_shape — no cache
+        same per-slot leaf shapes, and so must 'recurrent' (the state of
+        fixed size some ops keep beside keys and values). Probed with
+        jax.eval_shape — no cache
         allocation happens here."""
         params = (self.model.state.params
                   if getattr(self.model, "state", None) is not None else None)
@@ -1085,18 +1102,20 @@ class ContinuousBatcher:
                 return (f"{section!r} cache keys differ between the decode- "
                         f"and train-searched lowerings "
                         f"({len(d_keys)} vs {len(p_keys)} entries)")
-        if set(dec["mha"]) != set(pre["mha"]):
-            return ("attention cache op names differ between the decode- "
-                    "and train-searched lowerings")
-        for name, dleaves in dec["mha"].items():
-            dflat, dtree = jax.tree_util.tree_flatten(dleaves)
-            pflat, ptree = jax.tree_util.tree_flatten(pre["mha"][name])
-            if dtree != ptree:
-                return f"attention cache structure differs for {name!r}"
-            for a, b in zip(dflat, pflat):
-                if a.shape[1:] != b.shape[1:] or a.dtype != b.dtype:
-                    return (f"attention cache leaf mismatch for {name!r}: "
-                            f"{a.shape}/{a.dtype} vs {b.shape}/{b.dtype}")
+        for section, what in (("mha", "attention cache"),
+                              ("recurrent", "recurrent state")):
+            if set(dec[section]) != set(pre[section]):
+                return (f"{what} op names differ between the decode- "
+                        "and train-searched lowerings")
+            for name, dleaves in dec[section].items():
+                dflat, dtree = jax.tree_util.tree_flatten(dleaves)
+                pflat, ptree = jax.tree_util.tree_flatten(pre[section][name])
+                if dtree != ptree:
+                    return f"{what} structure differs for {name!r}"
+                for a, b in zip(dflat, pflat):
+                    if a.shape[1:] != b.shape[1:] or a.dtype != b.dtype:
+                        return (f"{what} leaf mismatch for {name!r}: "
+                                f"{a.shape}/{a.dtype} vs {b.shape}/{b.dtype}")
         return None
 
     # -- lifecycle -------------------------------------------------------
@@ -1190,9 +1209,8 @@ class ContinuousBatcher:
             # with the prompt given, reserve() attaches published prefix
             # pages refcounted and only charges the unshared remainder —
             # the dedup that lets N same-prefix sessions share one pool
-            rr = self.pool.reserve(
-                seq_key, self._reserve_tokens(plen, req.max_new_tokens),
-                tokens=prompt_tokens)
+            reserved = self._reserve_tokens(plen, req.max_new_tokens)
+            rr = self.pool.reserve(seq_key, reserved, tokens=prompt_tokens)
         except KVCacheExhaustedError as e:
             if e.never_fits:
                 _shed("kv_exhausted")
@@ -1229,6 +1247,8 @@ class ContinuousBatcher:
         if req.trace.sampled:
             req.trace.event("kv_reserve", replica=self.name,
                             pages=rr.pages, shared=rr.shared_pages,
+                            bytes=slot_reservation_bytes(
+                                self.model, self.pool.config, reserved),
                             **self.pool.snapshot())
         cache_key = ((bucket, req.prompt.astype(self._id_dt).tobytes())
                      if share and self.config.prefix_cache_entries > 0
@@ -1251,6 +1271,7 @@ class ContinuousBatcher:
                 first, caches1 = self._prefill(req, plen)
                 self.stats["prefill_tokens"] += plen
                 self.stats["prefill_bucket_tokens"] += bucket
+                self.stats["prefill_masked_tokens"] += bucket - plen
                 if cache_key is not None:
                     self._prefix_cache[cache_key] = (first, caches1)
                     while (len(self._prefix_cache)
@@ -1286,7 +1307,10 @@ class ContinuousBatcher:
         power-of-two bucket (bounds distinct jit shapes to log2(max_len)).
         The padded tail's garbage K/V sits at positions >= plen, which
         decode overwrites position-by-position before the causal mask
-        ever exposes them."""
+        ever exposes them; a recurrent state is never overwritten, so the
+        step is told the prompt's length and the tail leaves it alone. It
+        is told the one row wanted too, the last real token's: what
+        follows the last attention runs on that row alone."""
         from .. import obs
 
         bucket = self._bucket(plen)
@@ -1299,9 +1323,11 @@ class ContinuousBatcher:
             caches1 = self._init1(self.model.state.params, ())
             logits, caches1 = self._step1(
                 self.model.state.params, caches1, jnp.int32(0),
-                [jnp.asarray(padded)],
+                [jnp.asarray(padded)], jnp.int32(plen), jnp.int32(plen - 1),
             )
-            first = int(np.asarray(logits)[0, plen - 1].argmax(-1))
+            # the step put out the one row needed, the last real token's,
+            # not bucket x vocabulary of them; its best id is a scalar
+            first = int(_best_id(logits))
         return first, caches1
 
     def _insert_slot(self, slot_idx: int, caches1) -> None:
@@ -1318,9 +1344,10 @@ class ContinuousBatcher:
     def _insert_slot_locked(self, jax, slot_idx: int, caches1) -> None:
         if self._caches is None:
             self._caches = self._initB(self.model.state.params, ())
+            self._note_state_bytes()
         caches = self._caches
         out = {"static": caches["static"], "mha_static": caches["mha_static"],
-               "prefix": {}, "mha": {}}
+               "prefix": {}, "mha": {}, "recurrent": {}}
         for g, c in caches["prefix"].items():
             row = caches1["prefix"][g]
             if tuple(c.shape) != (self.config.slots,) + tuple(row.shape[1:]):
@@ -1342,7 +1369,30 @@ class ContinuousBatcher:
                 jax.lax.dynamic_update_slice_in_dim(
                     vB, v1.astype(vB.dtype), slot_idx, axis=0),
             )
+        for opname, state in caches["recurrent"].items():
+            out["recurrent"][opname] = tuple(
+                jax.lax.dynamic_update_slice_in_dim(
+                    sB, s1.astype(sB.dtype), slot_idx, axis=0)
+                for sB, s1 in zip(state, caches1["recurrent"][opname]))
         self._caches = out
+
+    def _note_state_bytes(self) -> None:
+        """What the slots hold of each kind of per-slot state, as gauges
+        (`stats` and the telemetry session): keys and values, which grow
+        with a sequence's length up to what is held here, and recurrent
+        state, which does not."""
+        from .. import obs
+
+        def nbytes(*sections):
+            return int(sum(leaf.nbytes for sec in sections for leaf in
+                           jax.tree_util.tree_leaves(self._caches[sec])))
+
+        self.stats["kv_cache_bytes"] = nbytes("mha", "prefix")
+        self.stats["recurrent_state_bytes"] = nbytes("recurrent")
+        for kind in ("kv_cache_bytes", "recurrent_state_bytes"):
+            obs.gauge_set("ff_serving_" + kind, self.stats[kind],
+                          help="bytes the decode slots hold of this kind "
+                               "of per-slot state", replica=self.name)
 
     # -- retirement ------------------------------------------------------
     def _release(self, slot_idx: int) -> None:
@@ -1501,8 +1551,11 @@ class ContinuousBatcher:
             b = 1
             while True:
                 caches1 = self._init1(params, ())
-                self._step1(params, caches1,
-                            jnp.int32(0), [jnp.zeros((1, b), self._id_dt)])
+                logits, _ = self._step1(
+                    params, caches1, jnp.int32(0),
+                    [jnp.zeros((1, b), self._id_dt)], jnp.int32(b),
+                    jnp.int32(b - 1))
+                _best_id(logits)
                 if b >= self.config.max_len:
                     break
                 b = min(2 * b, self.config.max_len)

@@ -112,6 +112,23 @@ def op_flops(op: PCGOp) -> float:
         av = 2.0 * bq * h * sq * sk * p.v_head_dim
         out = 2.0 * bq * sq * h * p.v_head_dim * p.embed_dim
         return proj + scores + av + out
+    if t == OperatorType.OP_GATED_DELTA_NET:
+        (x,) = in_shapes
+        p = op.params
+        tokens, e = x[0] * x[1], x[2]
+        h, dk, dv = p.num_heads, p.head_k_dim, p.head_v_dim
+        # q, k, v, gate and output projections, the two per-head gates;
+        # per token and head the state's decay, its rank-one update and
+        # the read (6 dv dk), and the short convolution
+        proj = 2.0 * tokens * e * (h * (2 * dk + 3 * dv) + 2 * h)
+        state = 6.0 * tokens * h * dv * dk
+        conv = 2.0 * tokens * p.conv_kernel * p.conv_channels
+        return proj + state + conv
+    if t == OperatorType.OP_LAYERNORM and op.params.rms:
+        # square, mean, normalise, scale: four operations an element
+        return 4.0 * _vol(out_shapes[0])
+    if t == OperatorType.OP_SILU:
+        return 4.0 * _vol(out_shapes[0])  # exp, add, divide, multiply
     if t in (OperatorType.OP_GROUP_BY, OperatorType.OP_AGGREGATE,
              OperatorType.OP_AGG_SPEC):
         # dispatch/combine einsum ~ tokens × experts × capacity × dim
@@ -251,10 +268,24 @@ def op_decode_bytes(op: PCGOp) -> float:
         # materialized at the compute width (bf16 under AMP)
         for x in op.inputs[1:3]:
             n += _vol(x.material_shape()) * x.effective_itemsize()
+    if op.op_type == OperatorType.OP_GATED_DELTA_NET:
+        n += _recurrent_state_traffic(op)
     for x in list(op.inputs) + list(op.outputs):
         n += _vol(x.material_shape()) * x.effective_itemsize() \
             / max(1, _seq_extent(x))
     return n
+
+
+def _recurrent_state_traffic(op: PCGOp) -> float:
+    """Bytes of per-slot recurrent state one decode step of a gated
+    delta-rule op reads AND writes, for the whole batch: the float32 state
+    matrices and the convolution's tail (its length-independent stand-in
+    for the keys and values an attention op re-reads)."""
+    from ..ops.linear_attention import state_bytes
+
+    batch = op.inputs[0].material_shape()[0]
+    return 2.0 * batch * state_bytes(
+        op.params, op.inputs[0].effective_itemsize())
 
 
 _DEFAULT_CALIBRATION: Optional[dict] = None
@@ -529,17 +560,22 @@ class CostModel:
         for w in op.weights:
             membytes += _vol(w.material_shape()) * w.data_type.size \
                 / max(1, w.get_total_degree())
+        # the per-slot state (keys and values, recurrent state) tiles over
+        # the batch
+        batch_deg = 1
+        if op.outputs and op.outputs[0].dims:
+            batch_deg = max(1, op.outputs[0].dims[0].degree)
         if op.op_type == OperatorType.OP_MULTIHEAD_ATTENTION \
                 and len(op.inputs) >= 3:
-            batch_deg = 1
-            if op.outputs and op.outputs[0].dims:
-                batch_deg = max(1, op.outputs[0].dims[0].degree)
             head_deg = max(
                 [max(1, w.get_total_degree()) for w in op.weights] or [1]
             )
             kv = sum(_vol(x.material_shape()) * x.effective_itemsize()
                      for x in op.inputs[1:3])
             membytes += kv / max(1, batch_deg * head_deg)
+        if op.op_type == OperatorType.OP_GATED_DELTA_NET:
+            # over the batch alone: the op's weights are not head-sharded
+            membytes += _recurrent_state_traffic(op) / batch_deg
         for x in list(op.inputs) + list(op.outputs):
             membytes += _vol(x.material_shape()) * x.effective_itemsize() \
                 / max(1, _seq_extent(x)) / parts
