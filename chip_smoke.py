@@ -426,6 +426,9 @@ def phase_server(ctx):
         "the decode-searched strategy did not fit; serving fell back to "
         "the training lowering")
     assert batcher.stats["finished"] == len(prompts), batcher.stats
+    # on the chip the decode step owns its caches and appends in place
+    assert batcher.stats["decode_caches_donated"] == int(not REHEARSAL), \
+        batcher.stats
 
     # shape, range, prompt kept
     for p, o in zip(prompts, outs):

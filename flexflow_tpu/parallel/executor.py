@@ -1079,10 +1079,12 @@ class PCGExecutor:
 
         return step
 
-    def _donate_state(self) -> tuple:
-        """donate_argnums for the train state: donate on accelerators,
-        where in-place buffer reuse halves peak weight/opt-state HBM —
-        but NOT on CPU. On the CPU backend, an executable deserialized
+    def donates_buffers(self) -> bool:
+        """One rule for every buffer a jitted step owns, the train step's
+        state and the decode step's caches alike: donate on accelerators,
+        where in-place buffer reuse halves peak weight/opt-state HBM and
+        spares a decode step the copy of its whole KV cache — but NOT on
+        CPU. On the CPU backend, an executable deserialized
         from the persistent compilation cache has been seen to lose the
         input/output aliasing metadata for donated buffers (the final
         state's buffers get reclaimed while still referenced, and live
@@ -1090,7 +1092,11 @@ class PCGExecutor:
         reuses the memory); not re-checked since, because CPU donation
         buys nothing — host RAM is not the scarce resource — so the safe
         choice costs nothing where it applies."""
-        return (0,) if jax.default_backend() != "cpu" else ()
+        return jax.default_backend() != "cpu"
+
+    def _donate_state(self) -> tuple:
+        """donate_argnums for the train state (argument 0)."""
+        return (0,) if self.donates_buffers() else ()
 
     def build_train_step(self, donate: bool = True) -> Callable:
         """donate=False builds a variant that never donates the input
@@ -1280,6 +1286,15 @@ class PCGExecutor:
         of a running decode batch advances through its own sequence, so
         K/V appends and causality masks are applied per row.
 
+        step CONSUMES `caches`: on an accelerator the argument is donated
+        (donates_buffers, the rule of the train step's state), so XLA
+        appends to the cache leaves in place instead of copying every one
+        of them first, and the arrays handed in are deleted. The caller rebinds
+        from the return value, `logits, caches = step(params, caches, ...)`,
+        and reads nothing of the old tree afterwards (tools/fflint.py
+        FFL102). init_caches hands out buffers that belong to the caches
+        alone, so a step consumes nothing of the caller's.
+
         Build-time validation rejects graphs the scheme can't prove exact:
         ops mixing sequence positions without a decode rule, non-causal
         self-attention, softmax over the live axis."""
@@ -1287,7 +1302,9 @@ class PCGExecutor:
         from ..ops.attention import cross_decode_kv, init_decode_cache
         from ..ops.linear_attention import init_state as init_recurrent_state
 
-        key = (batch, max_len, cache_dtype, decode_input, assume_causal)
+        donate = self.donates_buffers()
+        key = (batch, max_len, cache_dtype, decode_input, assume_causal,
+               donate)
         cached = self._decode_builds.get(key)
         if cached is not None:
             return cached
@@ -1426,8 +1443,19 @@ class PCGExecutor:
                 "init_caches(params, static_inputs)"
             )
             svals = _compute_statics(params, static_inputs)
+            # the step consumes the caches (donation): a static value that
+            # IS the caller's array (an input or a weight a live op reads
+            # as it came) or that lies under two guids is copied, so every
+            # leaf is a buffer the caches alone own
+            taken = {id(x) for x in jax.tree_util.tree_leaves(
+                (params, list(static_inputs)))} if static_kept else set()
+            static = {}
+            for g in static_kept:
+                v = svals[g]
+                static[g] = jnp.copy(v) if id(v) in taken else v
+                taken.add(id(static[g]))
             caches = {
-                "static": {g: svals[g] for g in static_kept},
+                "static": static,
                 "prefix": {},
                 "mha": {},
                 # beam-invariant per-op statics (cross-attention encoder
@@ -1682,9 +1710,24 @@ class PCGExecutor:
                                     r, n, 1, axis=_ax - 1)
                             )(v, at)
                         t, s0 = t + jnp.asarray(row, jnp.int32), 1
+            if donate:
+                # XLA aliases a donated leaf to the output of its own
+                # shape and type; one that comes back as another is
+                # copied every step after all, and says so
+                for sec in ("prefix", "mha", "recurrent"):
+                    for (path, old), new in zip(
+                            jax.tree_util.tree_leaves_with_path(caches[sec]),
+                            jax.tree_util.tree_leaves(new_caches[sec])):
+                        if (old.shape, old.dtype) != (new.shape, new.dtype):
+                            dec.decode_fallback(
+                                sec + jax.tree_util.keystr(path),
+                                "cache_not_donated",
+                                f"{old.dtype}{list(old.shape)} comes back "
+                                f"as {new.dtype}{list(new.shape)}")
             return vals[self.logits_pt.guid], new_caches
 
-        built = (init_caches, jax.jit(step))
+        built = (init_caches,
+                 jax.jit(step, donate_argnums=(1,) if donate else ()))
         self._decode_builds[key] = built
         return built
 

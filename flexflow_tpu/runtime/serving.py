@@ -1065,7 +1065,12 @@ class ContinuousBatcher:
                       "prefill_masked_tokens": 0,
                       # gauges: bytes the slots hold of each kind of
                       # per-slot state, set when the caches are made
-                      "kv_cache_bytes": 0, "recurrent_state_bytes": 0}
+                      "kv_cache_bytes": 0, "recurrent_state_bytes": 0,
+                      # 1 where the decode steps consume their caches and
+                      # append in place (executor.build_decode donates them
+                      # on an accelerator: one rule for every executor of
+                      # this process), set when the steps are built
+                      "decode_caches_donated": int(ex.donates_buffers())}
 
     def _decode_executor_mismatch(self, dex, initB_d) -> Optional[str]:
         """None if the decode-searched lowering can serve the batched
@@ -1345,35 +1350,34 @@ class ContinuousBatcher:
         if self._caches is None:
             self._caches = self._initB(self.model.state.params, ())
             self._note_state_bytes()
-        caches = self._caches
+        # each old leaf is let go as its successor is made: the insert
+        # holds one spare leaf, never a second generation of the caches
+        # beside the first (the decode step no longer does either)
+        caches, self._caches = self._caches, None
+
+        def put(old, row):
+            return jax.lax.dynamic_update_slice_in_dim(
+                old, row.astype(old.dtype), slot_idx, axis=0)
+
         out = {"static": caches["static"], "mha_static": caches["mha_static"],
                "prefix": {}, "mha": {}, "recurrent": {}}
-        for g, c in caches["prefix"].items():
+        for g in list(caches["prefix"]):
             row = caches1["prefix"][g]
-            if tuple(c.shape) != (self.config.slots,) + tuple(row.shape[1:]):
+            shape = tuple(caches["prefix"][g].shape)
+            if shape != (self.config.slots,) + tuple(row.shape[1:]):
                 raise ServingConfigError(
                     f"prefix cache guid {g} has no per-slot leading axis "
-                    f"(batch shape {tuple(c.shape)} vs row "
+                    f"(batch shape {shape} vs row "
                     f"{tuple(row.shape)}) — this graph folds batch with "
                     "another axis and cannot be continuously batched"
                 )
-            out["prefix"][g] = jax.lax.dynamic_update_slice_in_dim(
-                c, row.astype(c.dtype), slot_idx, axis=0
-            )
-        for opname, kv in caches["mha"].items():
-            k1, v1 = caches1["mha"][opname]
-            kB, vB = kv
-            out["mha"][opname] = (
-                jax.lax.dynamic_update_slice_in_dim(
-                    kB, k1.astype(kB.dtype), slot_idx, axis=0),
-                jax.lax.dynamic_update_slice_in_dim(
-                    vB, v1.astype(vB.dtype), slot_idx, axis=0),
-            )
-        for opname, state in caches["recurrent"].items():
-            out["recurrent"][opname] = tuple(
-                jax.lax.dynamic_update_slice_in_dim(
-                    sB, s1.astype(sB.dtype), slot_idx, axis=0)
-                for sB, s1 in zip(state, caches1["recurrent"][opname]))
+            out["prefix"][g] = put(caches["prefix"].pop(g), row)
+        # (k, v) of an attention op; (S, conv_tail) of a recurrent one
+        for sec in ("mha", "recurrent"):
+            for opname in list(caches[sec]):
+                leaves = list(caches[sec].pop(opname))
+                out[sec][opname] = tuple(
+                    put(leaves.pop(0), row) for row in caches1[sec][opname])
         self._caches = out
 
     def _note_state_bytes(self) -> None:
@@ -1393,6 +1397,11 @@ class ContinuousBatcher:
             obs.gauge_set("ff_serving_" + kind, self.stats[kind],
                           help="bytes the decode slots hold of this kind "
                                "of per-slot state", replica=self.name)
+        obs.gauge_set("ff_serving_decode_caches_donated",
+                      self.stats["decode_caches_donated"],
+                      help="1 where the decode step owns these caches and "
+                           "appends in place, 0 where it copies them first",
+                      replica=self.name)
 
     # -- retirement ------------------------------------------------------
     def _release(self, slot_idx: int) -> None:

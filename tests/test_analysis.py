@@ -619,6 +619,57 @@ def test_fflint_donated_reuse():
     assert _codes(nodonate) == []
 
 
+@pytest.mark.parametrize("source, codes", [
+    # the decode step consumes what it is handed as `caches`
+    ("def run(ex, params, toks):\n"
+     "    init, step = ex.build_decode(2, 16)\n"
+     "    caches = init(params, ())\n"
+     "    logits, new = step(params, caches, 0, [toks])\n"
+     "    return caches['mha']\n", ["FFL102"]),
+    # handed over by keyword, over several lines
+    ("def run(ex, params, toks):\n"
+     "    init, step = ex.build_decode(2, 16)\n"
+     "    caches = init(params, ())\n"
+     "    logits, new = step(\n"
+     "        params, t=0, batch_inputs=[toks],\n"
+     "        caches=caches)\n"
+     "    return caches\n", ["FFL102"]),
+    # rebinding from the return value is the contract
+    ("def run(ex, params, toks):\n"
+     "    init, step = ex.build_decode(2, 16)\n"
+     "    caches = init(params, ())\n"
+     "    for t in range(4):\n"
+     "        logits, caches = step(\n"
+     "            params, caches, t, [toks])\n"
+     "    return caches\n", []),
+    # the weights are lent, not given: reading them again is fine
+    ("def run(ex, params, toks):\n"
+     "    init, step = ex.build_decode(2, 16)\n"
+     "    logits, caches = step(params, init(params, ()), 0, [toks])\n"
+     "    return params\n", []),
+    # a step kept on self, built in one method and called in another
+    ("class B:\n"
+     "    def __init__(self, ex):\n"
+     "        initB, stepB = ex.build_decode(2, 16)\n"
+     "        self._initB, self._stepB = initB, stepB\n"
+     "    def bad(self, params, toks):\n"
+     "        logits, new = self._stepB(params, self._caches, 0, [toks])\n"
+     "        return self._caches\n"
+     "    def good(self, params, toks):\n"
+     "        logits, self._caches = self._stepB(\n"
+     "            params, self._caches, 0, [toks])\n"
+     "        return self._caches\n", ["FFL102"]),
+    # init_caches is no step: what it is handed stays the caller's
+    ("def run(ex, params, xb):\n"
+     "    init, step = ex.build_decode(2, 16)\n"
+     "    caches = init(params, [xb])\n"
+     "    return xb\n", []),
+], ids=["read_again", "keyword_multiline", "rebound", "params_lent",
+        "kept_on_self", "init_is_no_step"])
+def test_fflint_donated_decode_caches(source, codes):
+    assert _codes(source) == codes
+
+
 def test_fflint_clean_on_final_tree_and_cli():
     """Acceptance: `python tools/fflint.py flexflow_tpu/` exits 0."""
     proc = subprocess.run(

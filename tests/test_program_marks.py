@@ -227,12 +227,12 @@ def test_program_traces_are_counted_once_per_build(tmp_path):
         assert traces(tel, "train_step") == 1
         step(*train_args(m, batch=4))  # another shape: built again
         assert traces(tel, "train_step") == 2
-        _, dstep = m.executor.build_decode(2, SEQ)
-        caches = m.executor.build_decode(2, SEQ)[0](m.state.params, ())
+        init, dstep = m.executor.build_decode(2, SEQ)
         t = jnp.zeros((2,), jnp.int32)
-        for _ in range(2):
-            dstep(m.state.params, caches, t, [jnp.zeros((2, 1), jnp.int32)])
-        dstep(m.state.params, caches, jnp.int32(0),
+        for _ in range(2):  # the step consumes its caches: fresh ones each
+            dstep(m.state.params, init(m.state.params, ()), t,
+                  [jnp.zeros((2, 1), jnp.int32)])
+        dstep(m.state.params, init(m.state.params, ()), jnp.int32(0),
               [jnp.zeros((2, 4), jnp.int32)])
         assert traces(tel, "decode_step") == 1
         assert traces(tel, "prefill") == 1

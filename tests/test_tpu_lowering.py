@@ -94,14 +94,9 @@ def test_paged_flash_decode_lowers_for_tpu(shape, dtype):
                          *_paged_decode_args(*shape, dtype)) == 1
 
 
-def _decode_step_module(monkeypatch, hidden, heads, layers=2, slots=4,
-                        max_len=32):
-    """The batched decode step of a small causal LM, exported for the TPU
-    from here: `pallas_compiled` says yes, as it would on the chip, so
-    `auto` takes the kernel wherever the shape allows."""
+def _causal_lm(hidden, heads, layers, slots, max_len):
     from flexflow_tpu import (ActiMode, AggrMode, DataType, FFConfig,
                               FFModel, LossType, MetricsType, SGDOptimizer)
-    from flexflow_tpu.kernels import attention as kattn
 
     cfg = FFConfig()
     cfg.batch_size = slots
@@ -117,15 +112,37 @@ def _decode_step_module(monkeypatch, hidden, heads, layers=2, slots=4,
     m.compile(SGDOptimizer(lr=0.01),
               LossType.LOSS_SPARSE_CATEGORICAL_CROSSENTROPY,
               [MetricsType.METRICS_ACCURACY])
+    return m
+
+
+def _decode_step_module(monkeypatch, hidden, heads, layers=2, slots=4,
+                        max_len=32):
+    """The batched decode step of a small causal LM, exported for the TPU
+    from here."""
+    return _export_decode_step(
+        monkeypatch, _causal_lm(hidden, heads, layers, slots, max_len),
+        slots, max_len)
+
+
+def _export_decode_step(monkeypatch, m, slots, max_len, module=True):
+    """(module text, cache shapes) of `m`'s batched decode step, exported
+    for the TPU from the CPU: `pallas_compiled` says yes, as it would on
+    the chip, so `auto` takes the kernel wherever the shape allows. With
+    module=False, (the Exported, the step's argument shapes)."""
+    from flexflow_tpu.kernels import attention as kattn
+
     monkeypatch.delenv("FF_DECODE_IMPL", raising=False)
     monkeypatch.setattr(kattn, "pallas_compiled", lambda: True)
     init, step = m.executor.build_decode(slots, max_len)
     params = m.state.params
-    caches = jax.eval_shape(init, params, ())
-    exported = jax.export.export(step, platforms=["tpu"])(
-        params, caches, jax.ShapeDtypeStruct((slots,), jnp.int32),
-        [jax.ShapeDtypeStruct((slots, 1), jnp.int32)])
-    return exported.mlir_module(), caches
+    shapes = (jax.eval_shape(lambda p: p, params),
+              jax.eval_shape(init, params, ()),
+              jax.ShapeDtypeStruct((slots,), jnp.int32),
+              [jax.ShapeDtypeStruct((slots, 1), jnp.int32)])
+    exported = jax.export.export(step, platforms=["tpu"])(*shapes)
+    if module:
+        return exported.mlir_module(), shapes[1]
+    return exported, shapes
 
 
 def test_decode_step_holds_one_kernel_a_layer_and_no_cache_transpose(
@@ -149,6 +166,97 @@ def test_decode_step_holds_one_kernel_a_layer_and_no_cache_transpose(
             continue
         dims = re.search(r"tensor<([0-9x]+)x[a-z]", line).group(1)
         assert np.prod([int(n) for n in dims.split("x")]) < cache_elems, line
+
+
+@pytest.mark.parametrize("donates", [True, False],
+                         ids=["as_on_the_chip", "as_on_the_cpu"])
+@pytest.mark.parametrize("kind", ["attention", "hybrid"])
+def test_decode_step_donates_every_cache_leaf_it_rewrites(
+        kind, donates, monkeypatch):
+    """The step built as on the chip hands XLA every key, value and
+    recurrent-state leaf to write in place: in the module exported for the
+    TPU each such argument is aliased to an output (or left to XLA as a
+    donor), and nothing else is given away, the weights least of all. The
+    build the CPU gets, the suite's own, donates nothing."""
+    import re
+    from collections import Counter
+
+    from flexflow_tpu.parallel.executor import PCGExecutor
+
+    if kind == "attention":
+        build = functools.partial(_decode_step_module, monkeypatch,
+                                  hidden=128, heads=2)
+    else:
+        import sys
+
+        from tests.test_linear_attention import hybrid
+
+        monkeypatch.setattr(sys, "argv", sys.argv[:1])
+        build = functools.partial(_export_decode_step, monkeypatch,
+                                  hybrid(batch=4, seq=32), 4, 32)
+    assert not PCGExecutor.donates_buffers(None)  # JAX_PLATFORMS=cpu
+    if donates:
+        monkeypatch.setattr(PCGExecutor, "donates_buffers", lambda self: True)
+    text, caches = build()
+    main = next(line for line in text.splitlines()
+                if "func.func public @main" in line)
+    args = main[:main.index(") -> (")]
+    given = Counter(
+        shape for shape, attrs in re.findall(
+            r"%arg\d+: tensor<([^>]+)>(?: (\{[^}]*\}))?", args)
+        if "tf.aliasing_output" in attrs or "jax.buffer_donor" in attrs)
+    mlir = {"float32": "f32", "bfloat16": "bf16"}
+    rewritten = Counter(
+        "x".join(map(str, leaf.shape)) + "x" + mlir[leaf.dtype.name]
+        for sec in ("mha", "prefix", "recurrent")
+        for leaf in jax.tree_util.tree_leaves(caches[sec]))
+    assert len(caches["recurrent"]) == (kind == "hybrid")
+    assert sum(rewritten.values()) >= 4
+    assert given == (rewritten if donates else Counter())
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("donates", [True, False],
+                         ids=["as_on_the_chip", "as_on_the_cpu"])
+def test_donated_decode_step_compiles_for_v5e_without_a_cache_copy(
+        donates, monkeypatch):
+    """One step further than the lowering, as for the kernels: the TPU's
+    compiler, given the donated step, aliases every cache leaf to its
+    output and leaves no `copy` of a cache's shape; given the undonated
+    one it aliases nothing (there every leaf is copied before the append)."""
+    import re
+
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    from flexflow_tpu.parallel.executor import PCGExecutor
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no libtpu / no compile-only support here
+        pytest.skip(f"no TPU topology description available: {e!r}")
+    monkeypatch.setattr(PCGExecutor, "donates_buffers",
+                        lambda self: donates)
+    slots, max_len, hidden = 8, 256, 256
+    exported, shapes = _export_decode_step(
+        monkeypatch, _causal_lm(hidden, 2, 2, slots, max_len), slots,
+        max_len, module=False)
+    on_chip = SingleDeviceSharding(topo.devices[0])
+    compiled = jax.jit(
+        exported.call, donate_argnums=(1,) if donates else ()).lower(
+        *jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=on_chip), shapes)
+    ).compile()
+    leaves = jax.tree_util.tree_leaves(shapes[1]["mha"])
+    cache_bytes = sum(a.size * a.dtype.itemsize for a in leaves)
+    aliased = compiled.memory_analysis().alias_size_in_bytes
+    assert aliased == (cache_bytes if donates else 0)
+    dims = ",".join(map(str, leaves[0].shape))
+    copies = re.findall(r"= \w+\[" + dims + r"\]\S* copy\(",
+                        compiled.as_text())
+    assert not (donates and copies), copies
 
 
 def test_untileable_decode_shape_falls_back_dense_and_counts_once(

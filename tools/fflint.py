@@ -25,7 +25,12 @@ FFL102  reuse of a donated state after a donated step call
         Historical: the same PR 2 class — a variable passed into a
         `build_train_step()` callable (donating by default) is dead
         after the call; reading it again observes reused buffers.
-        Rebind it from the step's return value first.
+        Rebind it from the step's return value first. The decode step
+        is the same: `init, step = ....build_decode(...)` consumes what
+        it is handed as `caches` (its second argument), on the chip
+        where nobody is watching; `logits, caches = step(params,
+        caches, ...)` rebinds. A step kept on `self` is followed
+        through the module.
 FFL103  host-sync call inside a step-path function of parallel/ or
         kernels/ modules
         The per-step dispatch path (the traced `step`/`loss_of`/...
@@ -81,7 +86,8 @@ RULES = {
               "pass/continue)",
     "FFL101": "np.asarray/np.array without copy=True on "
               "jax.device_get(...) output",
-    "FFL102": "donated train-step input read again after the step call",
+    "FFL102": "donated input of a train step (its state) or a decode step "
+              "(its caches) read again after the step call",
     "FFL103": "host-sync call (block_until_ready / jax.device_get / "
               "np.asarray) inside a step-path function of parallel/ or "
               "kernels/",
@@ -192,43 +198,84 @@ def _check_asarray(tree: ast.AST, path: str, findings: List[Finding]) -> None:
 # ----------------------------------------------------------------------
 # FFL102 — donated buffer reused after the step
 # ----------------------------------------------------------------------
+# which argument each builder's callable consumes: (position, keyword)
+_DONATED_ARG = {"build_train_step": (0, "state"),
+                "build_decode": (1, "caches")}
+
+
+def _assigned_pairs(node: ast.Assign):
+    """(target, value) of an assignment, a tuple assigned to a tuple
+    element by element."""
+    for tgt in node.targets:
+        if (isinstance(tgt, ast.Tuple) and isinstance(node.value, ast.Tuple)
+                and len(tgt.elts) == len(node.value.elts)):
+            yield from zip(tgt.elts, node.value.elts)
+        else:
+            yield tgt, node.value
+
+
+def _built_step(t: ast.AST, v: ast.Call):
+    """(target, builder) where `t = v` binds a donating step, else
+    (t, None): `x = <...>.build_train_step(...)` without donate=False,
+    the second of `init, step = <...>.build_decode(...)`."""
+    callee = _dotted(v.func).rsplit(".", 1)[-1]
+    if callee == "build_train_step":
+        # donate=(expr) that may be False at runtime: trust it only when
+        # literally False
+        donate_off = any(
+            k.arg == "donate" and getattr(k.value, "value", None) is False
+            for k in v.keywords)
+        return t, None if donate_off else callee
+    if (callee == "build_decode" and isinstance(t, ast.Tuple)
+            and len(t.elts) == 2):
+        return t.elts[1], callee
+    return t, None
+
+
+def _donating_steps(scope: ast.AST, known: Dict[str, str]) -> Dict[str, str]:
+    """{dotted name: builder} of the donating step callables assigned in
+    `scope`, and of any name such a callable is assigned on to
+    (`self._init, self._step = init, step`). `known` holds the names that
+    are steps already (the module's `self.` attributes)."""
+    steps = dict(known)
+    pairs = [pair for n in ast.walk(scope) if isinstance(n, ast.Assign)
+             for pair in _assigned_pairs(n)]
+    for _ in range(3):  # a chain of hand-ons is short
+        for t, v in pairs:
+            if isinstance(v, ast.Call):
+                t, builder = _built_step(t, v)
+            else:
+                builder = steps.get(_dotted(v))
+            if builder and isinstance(t, (ast.Name, ast.Attribute)):
+                steps[_dotted(t)] = builder
+    return steps
+
+
 def _check_donated_reuse(tree: ast.AST, path: str,
                          findings: List[Finding]) -> None:
-    for fn in ast.walk(tree):
-        if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
-            continue
-        # step-fn variables: x = <...>.build_train_step(...) without
-        # donate=False
-        step_fns: Set[str] = set()
-        for node in ast.walk(fn):
-            if not (isinstance(node, ast.Assign)
-                    and isinstance(node.value, ast.Call)):
-                continue
-            callee = _dotted(node.value.func)
-            if not callee.endswith("build_train_step"):
-                continue
-            donate_off = any(
-                k.arg == "donate"
-                and getattr(k.value, "value", None) is False
-                for k in node.value.keywords
-            )
-            # donate=(expr) that may be False at runtime: trust it only
-            # when literally False
-            if donate_off:
-                continue
-            for tgt in node.targets:
-                if isinstance(tgt, ast.Name):
-                    step_fns.add(tgt.id)
+    fns = [fn for fn in ast.walk(tree)
+           if isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef))]
+    # a step kept on `self` is called from other methods than the one
+    # that built it; a plain name means its own function's step only
+    kept: Dict[str, str] = {}
+    for fn in fns:
+        kept.update((name, b) for name, b in _donating_steps(fn, {}).items()
+                    if name.startswith("self."))
+    seen = set()  # a nested function is walked with its outer one too
+    for fn in fns:
+        step_fns = _donating_steps(fn, kept)
         if not step_fns:
             continue
-        # calls step(arg0, ...): arg0 is donated; flag loads of arg0's
+        # calls step(..., arg, ...): arg is donated; flag loads of arg's
         # expression after the call line and before a re-store of it
         for node in ast.walk(fn):
             if not (isinstance(node, ast.Call)
-                    and isinstance(node.func, ast.Name)
-                    and node.func.id in step_fns and node.args):
+                    and _dotted(node.func) in step_fns):
                 continue
-            target = _dotted(node.args[0])
+            pos, kw = _DONATED_ARG[step_fns[_dotted(node.func)]]
+            donated = (node.args[pos] if len(node.args) > pos else next(
+                (k.value for k in node.keywords if k.arg == kw), None))
+            target = _dotted(donated) if donated is not None else ""
             if not target:
                 continue
             stores = [
@@ -243,17 +290,20 @@ def _check_donated_reuse(tree: ast.AST, path: str,
                     continue
                 if not isinstance(getattr(n, "ctx", None), ast.Load):
                     continue
-                if _dotted(n) != target or n.lineno <= node.lineno:
+                # past the call's last line: its own arguments are no
+                # second read
+                if _dotted(n) != target or \
+                        n.lineno <= (node.end_lineno or node.lineno):
                     continue
                 if rebound is not None and n.lineno >= rebound:
                     continue
-                if n.end_col_offset is not None and \
-                        n.lineno == node.lineno:
-                    continue
+                if (n.lineno, n.col_offset) in seen:
+                    break
+                seen.add((n.lineno, n.col_offset))
                 findings.append(Finding(
                     path, n.lineno, n.col_offset, "FFL102",
-                    f"`{target}` was donated to `{node.func.id}(...)` on "
-                    f"line {node.lineno} and is read again before being "
+                    f"`{target}` was donated to `{_dotted(node.func)}(...)` "
+                    f"on line {node.lineno} and is read again before being "
                     "rebound — donated buffers are reused by the next "
                     "dispatch (historical: stale-state reads after "
                     "donation)",
