@@ -416,11 +416,10 @@ def _flash_bwd_folded(qf, kf, vf, of, lse, dof, *, causal: bool,
     dv_d = vf.shape[-1]               # v_head_dim may differ from qk's d
     # Default: FULL kv tile at g=2. The kv-blocked variant (bk < sk, which
     # halves live VMEM and admits g=4) was the round-2 verdict's suggested
-    # retry; measured on v5e at the bench shape (benchmarks/
-    # flash_kernel_sweep.py, harness floor subtracted): g2/full 248 us,
-    # g4/bk256 284 us, g4/full 446 us, g8/bk128 297 us — the full-tile g=2
-    # schedule stays the fastest, so blocking ships as an env-tunable
-    # (FF_FLASH_BWD_BK / FF_FLASH_BWD_G, 0 = auto) rather than the default.
+    # retry; a round-3 sweep on a v5e found the full-tile g=2 schedule
+    # the fastest, so blocking ships as an env-tunable (FF_FLASH_BWD_BK /
+    # FF_FLASH_BWD_G, 0 = auto) rather than the default (ROADMAP C4: no
+    # cell has measured it since).
     bk = int(os.environ.get("FF_FLASH_BWD_BK", "0")) or sk
     if bk <= 0 or bk > sk:
         bk = sk

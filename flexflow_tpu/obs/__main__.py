@@ -6,7 +6,6 @@ Usage:
     python -m flexflow_tpu.obs prom     <metrics.jsonl> [-o metrics.prom]
     python -m flexflow_tpu.obs requests <events.jsonl> [--slowest K]
     python -m flexflow_tpu.obs explain  [--top N] [--in-situ] [shape flags]
-    python -m flexflow_tpu.obs bench    [--src DIR] [--tolerance F]
     python -m flexflow_tpu.obs calibrate inspect <store.json>
     python -m flexflow_tpu.obs calibrate prune   <store.json> --max-age-h H
     python -m flexflow_tpu.obs calibrate diff    <a.json> <b.json>
@@ -17,8 +16,6 @@ prints per-category/event counts plus step/search aggregates — and,
 when the log carries a step-observatory capture, the overlap-
 realization/HBM numbers, per-collective hidden/exposed attribution and
 the measured-vs-simulated per-op drift from the overlay file.
-``bench`` prints the BENCH_r*.json round trajectory with the newest
-round's regression attributed per phase (fwd/bwd/opt/sync).
 ``prom`` re-renders the last metrics.jsonl snapshot as Prometheus text.
 ``requests`` reconstructs per-request lifecycles from the serving
 flight recorder's events (cat "requests"): stage breakdown, top-K
@@ -369,53 +366,6 @@ def _cmd_explain(args) -> int:
     return 0
 
 
-def _cmd_bench(args) -> int:
-    from .step_profile import bench_regression_attribution, load_bench_history
-
-    history = load_bench_history(args.src)
-    if not history:
-        print(f"bench: no BENCH_r*.json artifacts under {args.src}")
-        return 1
-    print(f"{len(history)} bench round(s) under {args.src}:")
-    print(f"  {'round':>5} {'value':>10} {'unit':<15} {'chips':>5} "
-          f"{'backend':<8} {'fwd ms':>8} {'bwd ms':>8} {'opt ms':>8} "
-          f"{'sync ms':>8}")
-    for r in history:
-        ph = r.get("phases") or {}
-
-        def ms(k, _ph=ph):
-            v = _ph.get(k)
-            return f"{v * 1e3:>8.3f}" if isinstance(v, (int, float)) \
-                else f"{'-':>8}"
-
-        print(f"  {r['round'] if r['round'] is not None else '?':>5} "
-              f"{r['value'] if r['value'] is not None else '-':>10} "
-              f"{(r['unit'] or '-')[:15]:<15} "
-              f"{r['n_chips'] if r['n_chips'] is not None else '-':>5} "
-              f"{(r['backend'] or '-')[:8]:<8} "
-              f"{ms('fwd')} {ms('bwd')} {ms('opt')} {ms('sync')}")
-    att = bench_regression_attribution(history, tolerance=args.tolerance)
-    if att.get("status") != "ok":
-        print(f"attribution: {att.get('status')} "
-              f"({att.get('rounds', 0)} usable round(s))")
-        return 0
-    print(f"newest r{att['cur_round']:02d} vs r{att['prev_round']:02d}: "
-          f"{att['cur_value']:.3f} vs {att['prev_value']:.3f} "
-          f"(ratio {att['throughput_ratio']:.3f}"
-          + (", REGRESSED" if att["regressed"] else "") + ")")
-    if att.get("phases"):
-        for ph, d in att["phases"].items():
-            share = d.get("share_of_regression", 0.0)
-            print(f"  {ph:<5} {d['prev_s'] * 1e3:>8.3f} -> "
-                  f"{d['cur_s'] * 1e3:>8.3f} ms "
-                  f"({d['delta_s'] * 1e3:+.3f}; "
-                  f"{share:.0%} of the regression)")
-        if att.get("dominant_phase"):
-            print(f"  dominant phase: {att['dominant_phase']} "
-                  f"(step {att['step_delta_s'] * 1e3:+.3f} ms)")
-    return 1 if att["regressed"] and args.strict else 0
-
-
 def _fleet_domains(args):
     if not getattr(args, "domains", None):
         return None
@@ -569,18 +519,6 @@ def main(argv=None) -> int:
     e.add_argument("--in-situ", action="store_true",
                    help="also capture a step profile of the fused jitted "
                         "step and join its per-op seconds into the rows")
-    b = sub.add_parser(
-        "bench",
-        help="BENCH_r*.json round trajectory + newest-round regression "
-             "attribution per phase (fwd/bwd/opt/sync)",
-    )
-    b.add_argument("--src", default=".",
-                   help="directory holding BENCH_r*.json (default: .)")
-    b.add_argument("--tolerance", type=float, default=0.05,
-                   help="fractional throughput drop that counts as a "
-                        "regression (default 0.05)")
-    b.add_argument("--strict", action="store_true",
-                   help="exit 1 when the newest round regressed")
     fl = sub.add_parser(
         "fleet",
         help="aggregate a fleet spool directory (obs/fleet.py): live "
@@ -622,7 +560,7 @@ def main(argv=None) -> int:
     return {"trace": _cmd_trace, "summary": _cmd_summary,
             "prom": _cmd_prom, "requests": _cmd_requests,
             "calibrate": _cmd_calibrate, "explain": _cmd_explain,
-            "bench": _cmd_bench, "fleet": _cmd_fleet,
+            "fleet": _cmd_fleet,
             "forensics": _cmd_forensics}[args.cmd](args)
 
 

@@ -38,11 +38,6 @@ in-situ instrument:
     with ``runtime/profiler.py::simulated_timeline_events`` into ONE
     Perfetto file: "simulated" and "measured" process groups on a
     shared rebased timebase.
-  * **regression observatory** — ``load_bench_history`` /
-    ``bench_regression_attribution`` turn the repo's ``BENCH_r*.json``
-    artifacts (bench.py's ``phases_s_per_step``) into a per-phase
-    regression trajectory, surfaced via ``python -m flexflow_tpu.obs
-    bench``.
 
 Wire-up: ``fit(telemetry=TelemetryConfig(dir=..., step_profile=True))``
 captures after the training loop (the step is warm) and writes
@@ -65,7 +60,6 @@ logger = logging.getLogger(__name__)
 MEASURED_CAT = "measured"
 OVERLAY_FILE = "step_timeline.json"
 OOM_FORENSICS_FILE = "oom_forensics.json"
-BENCH_PHASES = ("fwd", "bwd", "opt", "sync")
 # floor written to the calibration store: validate_calibration rejects
 # efficiencies outside (0, 1], and a literal 0.0 would price overlap as
 # impossible forever on the strength of one noisy capture
@@ -753,96 +747,3 @@ def dump_oom_forensics(model, out_dir: str, *, error: str = "",
     with open(path, "w") as f:
         json.dump(doc, f, indent=1, sort_keys=True)
     return path
-
-
-# ----------------------------------------------------------------------
-# BENCH-history regression observatory
-# ----------------------------------------------------------------------
-def load_bench_history(src: str = ".") -> List[dict]:
-    """The repo's BENCH_r*.json artifacts as a round-ordered
-    trajectory: [{round, value, phases, n_chips, backend, ...}]. Rounds
-    that predate a field carry None for it (old artifacts had no
-    phases_s_per_step)."""
-    paths = sorted(glob.glob(os.path.join(src, "BENCH_r*.json")))
-    out: List[dict] = []
-    for p in paths:
-        try:
-            with open(p) as f:
-                doc = json.load(f)
-        except (OSError, ValueError) as e:
-            logger.warning("bench history: skipping %s (%s)", p, e)
-            continue
-        parsed = doc.get("parsed") or {}
-        m = re.search(r"BENCH_r(\d+)\.json$", p)
-        out.append({
-            "round": int(m.group(1)) if m else doc.get("n"),
-            "path": p,
-            "metric": parsed.get("metric"),
-            "value": parsed.get("value"),
-            "unit": parsed.get("unit"),
-            "phases": parsed.get("phases_s_per_step"),
-            "n_chips": parsed.get("n_chips"),
-            "backend": parsed.get("backend"),
-            "smoke": parsed.get("smoke"),
-            "jax_version": parsed.get("jax_version"),
-        })
-    out.sort(key=lambda r: (r["round"] is None, r["round"]))
-    return out
-
-
-def bench_regression_attribution(history: List[dict],
-                                 *, tolerance: float = 0.05) -> dict:
-    """Newest round vs the previous one OF THE SAME SERIES (metric +
-    backend — rounds predating the metric field count as the transformer
-    series, and rounds without a backend field only match each other),
-    with the regression attributed
-    per phase: each phase's seconds delta and its share of the total
-    step-time change. Phases are only attributable when both rounds
-    carry phases_s_per_step."""
-    rounds = [r for r in history if r.get("value") is not None]
-    if rounds:
-        newest = rounds[-1]
-        rounds = [
-            r for r in rounds
-            if (r.get("metric") or "transformer_train_throughput")
-            == (newest.get("metric") or "transformer_train_throughput")
-            and r.get("backend") == newest.get("backend")
-        ]
-    if len(rounds) < 2:
-        return {"status": "insufficient_history", "rounds": len(rounds)}
-    prev, cur = rounds[-2], rounds[-1]
-    out: dict = {
-        "status": "ok",
-        "prev_round": prev["round"], "cur_round": cur["round"],
-        "prev_value": prev["value"], "cur_value": cur["value"],
-        "throughput_ratio": (cur["value"] / prev["value"])
-        if prev["value"] else None,
-        "regressed": bool(prev["value"]
-                          and cur["value"] < prev["value"] * (1 - tolerance)),
-        "tolerance": tolerance,
-        "phases": None,
-    }
-    pp, cp = prev.get("phases"), cur.get("phases")
-    if isinstance(pp, dict) and isinstance(cp, dict):
-        deltas = {}
-        total_delta = 0.0
-        for ph in BENCH_PHASES:
-            a, b = pp.get(ph), cp.get(ph)
-            if a is None or b is None:
-                continue
-            deltas[ph] = {"prev_s": a, "cur_s": b, "delta_s": b - a,
-                          "ratio": (b / a) if a else None}
-            total_delta += b - a
-        grew = {ph: d["delta_s"] for ph, d in deltas.items()
-                if d["delta_s"] > 0}
-        grew_total = sum(grew.values())
-        for ph, d in deltas.items():
-            d["share_of_regression"] = (
-                (d["delta_s"] / grew_total) if grew_total > 0
-                and d["delta_s"] > 0 else 0.0
-            )
-        out["phases"] = deltas
-        out["step_delta_s"] = total_delta
-        if grew:
-            out["dominant_phase"] = max(grew, key=grew.get)
-    return out

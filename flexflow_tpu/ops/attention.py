@@ -471,9 +471,9 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     return [out]
 
 
-def _forward_decode(params, weights, inputs, ctx, cache, t):
+def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
     """Incremental decode step with a KV cache (serving path,
-    executor.build_decode). Inputs are the NEW positions' slices
+    parallel/decode.py). Inputs are the NEW positions' slices
     (b, s0, e) starting at position t (s0 = 1 for token-by-token decode,
     s0 = prompt_len for one-shot prefill); cache holds (k, v) of shape
     (b, max_len, h*d) with positions < t valid (init_decode_cache says
@@ -491,7 +491,9 @@ def _forward_decode(params, weights, inputs, ctx, cache, t):
     runtime/serving.py: each slot of a running decode batch is mid-way
     through its own sequence). The vector path appends each row's K/V at
     its own offset (a vmapped per-row update) and masks each row's
-    attention against its own position."""
+    attention against its own position. `valid` (a padded block's count
+    of real tokens) is not used: what a block writes beyond it lies
+    behind the causal mask until a later token overwrites it."""
     q_in, k_in, v_in = inputs
     cdt = ctx.compute_dtype
     if cdt is not None:
@@ -643,13 +645,14 @@ def _forward_decode(params, weights, inputs, ctx, cache, t):
     return [out], (k_cache, v_cache)
 
 
-def cross_decode_kv(params: MultiHeadAttentionParams, weights, k_in, v_in,
-                    ctx):
+def cross_decode_kv(params: MultiHeadAttentionParams, weights,
+                    static_inputs, ctx):
     """Precompute the FULL encoder-side K/V for cross-attention decode
-    (executor.build_decode init): k_in/v_in are the static encoder
-    outputs (b, s_enc, e). Computed once per sequence — each decode step
-    then attends its query slice against these without re-projecting
-    (the O(1)/token contract for enc-dec serving)."""
+    (the op's init_decode_static): static_inputs = (k_in, v_in), the
+    static encoder outputs (b, s_enc, e). Computed once per sequence —
+    each decode step then attends its query slice against these without
+    re-projecting (the O(1)/token contract for enc-dec serving)."""
+    k_in, v_in = static_inputs
     cdt = ctx.compute_dtype
     if cdt is not None:
         k_in, v_in = k_in.astype(cdt), v_in.astype(cdt)
@@ -663,11 +666,13 @@ def cross_decode_kv(params: MultiHeadAttentionParams, weights, k_in, v_in,
     return (k, v)
 
 
-def _forward_decode_cross(params, weights, q_in, ctx, kv):
-    """Cross-attention decode step: project this block's queries and
-    attend over the precomputed full encoder K/V (cross_decode_kv). No
-    causal mask — every decoder position sees the whole encoder sequence,
-    exactly like the training forward."""
+def _forward_decode_cross(params, weights, inputs, ctx, kv):
+    """Cross-attention decode step (the op's forward_decode_static):
+    project this block's queries, the one live input, and attend over the
+    precomputed full encoder K/V (cross_decode_kv). No causal mask —
+    every decoder position sees the whole encoder sequence, exactly like
+    the training forward."""
+    (q_in,) = inputs
     cdt = ctx.compute_dtype
     if cdt is not None:
         q_in = q_in.astype(cdt)
@@ -720,4 +725,8 @@ register_op(
     forward=_forward,
     num_inputs=3,
     forward_decode=_forward_decode,
+    init_decode_state=init_decode_cache,
+    decode_section="mha",
+    init_decode_static=cross_decode_kv,
+    forward_decode_static=_forward_decode_cross,
 )

@@ -261,8 +261,14 @@ def _forward(params: GatedDeltaNetParams, weights, inputs, ctx):
     return [y.astype(x.dtype)]
 
 
+def _init_decode_state(params, batch, max_len, dtype):
+    """The op's state as the decode caches hold it (section "recurrent"):
+    of fixed size, so `max_len` is not used."""
+    return init_state(params, batch, dtype)
+
+
 def _forward_decode(params, weights, inputs, ctx, state, t, valid=None):
-    """Incremental step (executor.build_decode): the block's tokens from
+    """Incremental step (parallel/decode.py): the block's tokens from
     the slot's state. `t` is not used: the state carries the position."""
     (x,) = inputs
     y, state = _mix(params, weights, x, ctx, state, valid)
@@ -277,4 +283,6 @@ register_op(
     forward=_forward,
     num_inputs=1,
     forward_decode=_forward_decode,
+    init_decode_state=_init_decode_state,
+    decode_section="recurrent",
 )

@@ -55,9 +55,22 @@ class OpDef:
     # (params, op) -> bool for ops whose params decide it (softmax over
     # the seq axis is NOT pointwise; over features it is). Ops that MIX
     # positions instead provide forward_decode(params, weights, inputs,
-    # ctx, cache, t) -> (outs, cache') — attention appends K/V there.
+    # ctx, state, t, valid=None) -> (outs, state') — attention appends
+    # K/V there — and say what state that is: init_decode_state(params,
+    # batch, max_len, dtype) makes it empty, slot axis leading, and
+    # decode_section names the section of the decode caches it lives in
+    # (parallel/decode.py SLOT_SECTIONS), under the op's name.
     seq_pointwise: object = False
     forward_decode: Optional[Callable] = None
+    init_decode_state: Optional[Callable] = None
+    decode_section: Optional[str] = None
+    # The same op where every input but the first is static (cross-
+    # attention over an encoder's output): init_decode_static(params,
+    # weights, static_inputs, ctx) -> state, computed once a sequence;
+    # forward_decode_static(params, weights, live_input, ctx, state) ->
+    # outs reads it and appends nothing.
+    init_decode_static: Optional[Callable] = None
+    forward_decode_static: Optional[Callable] = None
     # Cross-batch mutable buffers (reference: cuDNN BN running stats,
     # Cache op's CACHE_UPDATE_TASK). state_spec declares them like
     # weights; forward_stateful(params, weights, state, inputs, ctx) ->
@@ -86,6 +99,10 @@ def register_op(
     num_inputs: int = 1,
     seq_pointwise: object = False,
     forward_decode: Optional[Callable] = None,
+    init_decode_state: Optional[Callable] = None,
+    decode_section: Optional[str] = None,
+    init_decode_static: Optional[Callable] = None,
+    forward_decode_static: Optional[Callable] = None,
     state_spec: Optional[Callable] = None,
     forward_stateful: Optional[Callable] = None,
 ) -> OpDef:
@@ -98,6 +115,10 @@ def register_op(
         num_inputs=num_inputs,
         seq_pointwise=seq_pointwise,
         forward_decode=forward_decode,
+        init_decode_state=init_decode_state,
+        decode_section=decode_section,
+        init_decode_static=init_decode_static,
+        forward_decode_static=forward_decode_static,
         state_spec=state_spec,
         forward_stateful=forward_stateful,
     )

@@ -1,4 +1,10 @@
-"""Incremental (KV-cache) decoding for arbitrary PCGs.
+"""Incremental (KV-cache) decoding for arbitrary PCGs: the plan, the step
+built from it (build_step, behind PCGExecutor.build_decode) and the tree
+of caches the step steps, which this module alone takes apart (SLOT_SECTIONS
+and the functions beside it: insert_row, take_rows, state_bytes,
+declared_state_bytes). Which ops keep state between steps, and what, is
+each op definition's own answer (ops/registry.py init_decode_state,
+decode_section).
 
 The reference's serving story is a Triton prototype that replays a full
 forward per request (triton/README.md: "incomplete prototype"); it has no
@@ -46,6 +52,7 @@ op params.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import warnings
 from typing import Dict, List, Optional, Tuple
 
@@ -54,7 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ff_types import AggrMode, OperatorType
-from ..ops.registry import FwdCtx, get_op_def
+from ..ops.registry import FwdCtx, get_op_def, has_op_def
 
 NEG_INF = -1e30
 
@@ -131,10 +138,16 @@ class DecodePlan:
     static_ops: List  # topo-ordered ops computable from static inputs
     info: Dict[int, AxisInfo]  # guid -> axis info (live tensors only)
     cached_guids: List[int]  # tensors consumed at full prefix length
+    static_keyed: List  # live ops whose keys and values are static
     static_needed: List[int]  # static guids consumed by live ops
     live_len: int  # compiled decoder length L
     decode_pt: object  # the decode-driving input ParallelTensor
     requires_cap_le_live_len: bool  # static slicing present
+
+    def cached_pt(self, guid):
+        """The tensor behind one of cached_guids."""
+        return next(x for op in self.live_ops for x in op.outputs
+                    if x.guid == guid)
 
 
 def _is_unary_pointwise(op) -> bool:
@@ -171,6 +184,9 @@ class _Propagator:
         # softmax ops over a prefix axis (primitive-op attention rows):
         # each needs a causality proof or an assume_causal opt-in
         self.prefix_softmaxes: List = []
+        # ops whose keys and values are static (cross-attention): their
+        # state is computed once, not appended to
+        self.static_keyed: List = []
 
     def get(self, guid) -> AxisInfo:
         return self.info.get(guid, AxisInfo())
@@ -203,12 +219,15 @@ class _Propagator:
                 if not op.params.causal:
                     fail("needs causal=True (otherwise each position sees "
                          "the future and the cached prefix is stale)")
-            elif op.params.causal:
-                # the full forward would tril-mask cross scores; the
-                # decode kernel attends the full encoder unmasked
-                fail("causal cross-attention has no decode rule")
-            # cross-attention: k/v static (encoder side) — full-length
-            # K/V computed once, no causal mask (matches the full forward)
+            else:
+                if op.params.causal:
+                    # the full forward would tril-mask cross scores; the
+                    # decode kernel attends the full encoder unmasked
+                    fail("causal cross-attention has no decode rule")
+                # cross-attention: k/v static (encoder side) — full-length
+                # K/V computed once, no causal mask (matches the full
+                # forward)
+                self.static_keyed.append(op)
             set_out(0, AxisInfo(live=1))
             return
 
@@ -218,7 +237,7 @@ class _Propagator:
                 fail("the input must be (batch, seq, embed) with the live "
                      "axis at 1")
             # the op carries its own per-slot state from block to block
-            # (executor.build_decode's "recurrent" cache section)
+            # (the section of the caches its definition names)
             set_out(0, AxisInfo(live=1))
             return
 
@@ -694,6 +713,7 @@ def build_plan(topo, input_pts, constants, decode_input: Optional[int] = None,
         static_ops=static_ops,
         info=prop.info,
         cached_guids=sorted(prop.cached),
+        static_keyed=prop.static_keyed,
         static_needed=needed,
         live_len=live_len,
         decode_pt=decode_pt,
@@ -820,3 +840,503 @@ def _static_alignment(shape, out_rank, out_info: AxisInfo, live_len):
             elif pos == out_info.prefix:
                 plan.append((ax, "prefix"))
     return plan
+
+
+# -- the caches: the tree a step steps ------------------------------------------
+# One tree, keyed by section, and this module alone knows its shape: the
+# batcher and beam search (runtime/serving.py) and the sizing
+# (runtime/kvcache.py) go through the functions below.
+#
+#   per-slot sections: every leaf has a leading slot (batch) axis, one row
+#   a sequence in flight, and a step hands back its successor
+#     "prefix"            guid -> a primitive-op attention's operand at
+#                         full length (DecodePlan.cached_guids)
+#     "mha", "recurrent"  op name -> what that op's definition keeps
+#                         (ops/registry.py init_decode_state, decode_section)
+#   shared sections: made once by init_caches and read by every step, never
+#   stepped; a constant-derived leaf may have a leading axis of 1
+#     "static"            guid -> an encoder-side value a live op reads
+#     "mha_static"        op name -> a static-keyed attention's (k, v)
+SLOT_SECTIONS = ("prefix", "mha", "recurrent")
+SHARED_SECTIONS = ("static", "mha_static")
+# what a slot pays for, by kind: keys and values, which grow with a
+# sequence up to max_len (pages), and state of fixed size, which does not
+STATE_KINDS = {"kv": ("prefix", "mha"), "fixed": ("recurrent",)}
+
+
+def _stepped(caches):
+    """The tree a step puts its successors into: the shared sections as
+    they are, the per-slot ones as shallow copies."""
+    new = {sec: caches[sec] for sec in SHARED_SECTIONS}
+    new.update((sec, dict(caches[sec])) for sec in SLOT_SECTIONS)
+    return new
+
+
+def insert_row(caches, row_caches, slot: int):
+    """A running batch's caches with slot `slot` replaced, in every
+    per-slot leaf, by the one row of `row_caches` (a prefilled batch-1
+    tree): whatever a previous occupant left there is gone. CONSUMES
+    `caches`: each old leaf is let go as its successor is made, so the
+    insert holds one spare leaf, never a second generation of the caches
+    beside the first."""
+    from ..runtime.verify import ServingConfigError
+
+    def put(old, row):
+        return jax.lax.dynamic_update_slice_in_dim(
+            old, row.astype(old.dtype), slot, axis=0)
+
+    out = {sec: caches[sec] for sec in SHARED_SECTIONS}
+    for sec in SLOT_SECTIONS:
+        out[sec] = {}
+        for key in list(caches[sec]):
+            olds, treedef = jax.tree_util.tree_flatten(caches[sec].pop(key))
+            rows = jax.tree_util.tree_leaves(row_caches[sec][key])
+            shapes = next(
+                ((tuple(o.shape), tuple(r.shape)) for o, r in zip(olds, rows)
+                 if r.shape[0] != 1 or o.shape[1:] != r.shape[1:]), None)
+            if shapes is not None:
+                raise ServingConfigError(
+                    f"{sec} cache {key} has no per-slot leading axis "
+                    f"(batch shape {shapes[0]} vs row {shapes[1]}) — this "
+                    "graph folds batch with another axis and cannot be "
+                    "continuously batched"
+                )
+            out[sec][key] = treedef.unflatten(
+                [put(olds.pop(0), row) for row in rows])
+    return out
+
+
+def take_rows(caches, idx):
+    """The caches with every per-slot leaf's rows taken by `idx` (beam
+    search: each beam's caches follow the beam it grew from). The shared
+    sections stay as they are: they are the same for every row, and a
+    constant-derived entry has a leading axis of 1, where a gather would
+    fill out-of-bounds rows with NaN."""
+    out = jax.tree_util.tree_map(
+        lambda c: jnp.take(c, idx, axis=0),
+        {sec: caches[sec] for sec in SLOT_SECTIONS})
+    out.update((sec, caches[sec]) for sec in SHARED_SECTIONS)
+    return out
+
+
+def state_bytes(caches) -> Dict[str, int]:
+    """Bytes the caches hold of each kind of per-slot state
+    (STATE_KINDS)."""
+    return {kind: int(sum(leaf.nbytes for sec in sections for leaf in
+                          jax.tree_util.tree_leaves(caches[sec])))
+            for kind, sections in STATE_KINDS.items()}
+
+
+def declared_state_bytes(topo, kind: str, max_len: int, dtype) -> int:
+    """Bytes ONE slot of `max_len` positions holds of `kind` across the
+    ops of `topo`, by what each op's definition says it keeps
+    (init_decode_state, its shapes alone: nothing is allocated). Counts
+    every op of the graph, as the sizing formula does (docs/serving.md),
+    not only those a decode plan finds live."""
+    total = 0
+    for op in topo:
+        if not has_op_def(op.op_type):
+            continue
+        d = get_op_def(op.op_type)
+        if d.decode_section not in STATE_KINDS[kind]:
+            continue
+        leaves = jax.tree_util.tree_leaves(jax.eval_shape(functools.partial(
+            d.init_decode_state, op.params, 1, max_len, dtype)))
+        total += sum(int(np.prod(leaf.shape, dtype=np.int64))
+                     * leaf.dtype.itemsize for leaf in leaves)
+    return total
+
+
+# -- the step -------------------------------------------------------------------
+def _check_plan(plan: DecodePlan, logits_pt, max_len: int) -> None:
+    """Build-time refusals the plan alone cannot make: they need the
+    decode batch, the cap or the graph's output."""
+    # prefix caches patch ONLY axis 0 to the decode batch; a graph that
+    # folds batch with heads on axis 0 (B*H, ...) would get a wrong-sized
+    # cache when decoding at a different batch than compile (beam search
+    # at num_beams) — reject at build like the other exactness checks
+    compile_batch = plan.decode_pt.material_shape()[0]
+    for g in plan.cached_guids:
+        pt = plan.cached_pt(g)
+        if plan.info[g].live != 0 and pt.material_shape()[0] != compile_batch:
+            raise NotImplementedError(
+                f"cached tensor guid {g} has axis-0 size "
+                f"{pt.material_shape()[0]} != compiled batch "
+                f"{compile_batch}: its batch dim is folded with "
+                "another axis, so decoding at a different batch "
+                "would mis-size the cache"
+            )
+    if plan.requires_cap_le_live_len and max_len > plan.live_len:
+        raise NotImplementedError(
+            f"max_len {max_len} > compiled decoder length "
+            f"{plan.live_len}: the graph bakes full-length constants "
+            "(masks/position tables) that can't be extended"
+        )
+    if not plan.info.get(logits_pt.guid, AxisInfo()).is_live:
+        raise NotImplementedError(
+            "the graph output does not depend on the decode input"
+        )
+
+
+def _constants_at(constants, batch: int):
+    """Baked constants, with batch-uniform leading axes collapsed to 1:
+    decode may run at a different batch than compile (beam search runs at
+    num_beams), and constants like HF's extended attention masks carry
+    the compiled batch size — when every row is identical (no per-sample
+    padding was traced) a broadcastable row-1 constant is exact."""
+    vals = {}
+    for guid, (pt, value) in constants.items():
+        shape = tuple(pt.material_shape())
+        if isinstance(value, np.ndarray):
+            arr = value
+            if (arr.ndim >= 1 and arr.shape[0] not in (1, batch)
+                    and np.array_equal(arr, np.broadcast_to(
+                        arr[:1], arr.shape), equal_nan=True)):
+                arr = arr[:1]
+            vals[guid] = jnp.asarray(arr, pt.data_type.jnp_dtype)
+        else:
+            if len(shape) >= 1 and shape[0] not in (1, batch):
+                shape = (1,) + shape[1:]
+            vals[guid] = jnp.full(shape, value, pt.data_type.jnp_dtype)
+    return vals
+
+
+def _statics(plan: DecodePlan, vals, params, ctx):
+    """The static side, once a sequence: every static op's outputs added
+    to `vals` (the constants and the static graph inputs, by guid)."""
+    for op in plan.static_ops:
+        if op.is_parallel_op:
+            vals[op.outputs[0].guid] = vals[op.inputs[0].guid]
+            continue
+        d = get_op_def(op.op_type)
+        ins = [vals[x.guid] for x in op.inputs]
+        w = (params or {}).get(op.name, {})
+        if (op.op_type == OperatorType.OP_RESHAPE
+                and tuple(ins[0].shape)
+                != tuple(op.inputs[0].material_shape())):
+            # traced reshape params bake the compiled batch size; decode
+            # may run at a different batch (beam search) — recompute the
+            # batch axis
+            target = list(op.outputs[0].material_shape())
+            target[0] = -1
+            outs = [jnp.reshape(ins[0], target)]
+        else:
+            outs = d.forward(op.params, w, ins, ctx)
+        for x, v in zip(op.outputs, outs):
+            vals[x.guid] = v
+    return vals
+
+
+def _kept_statics(plan: DecodePlan, static_keyed):
+    """The static values the step itself reads. Those whose ONLY live
+    consumers are a static-keyed op's key/value slots are folded into
+    that op's precomputed state — keeping the raw encoder hidden states
+    in the caches as well would waste HBM per layer."""
+    folded = {x.guid for op in static_keyed for x in op.inputs[1:]}
+    keyed = {id(op) for op in static_keyed}
+    other_uses = set()
+    for op in plan.live_ops:
+        if op.is_parallel_op or id(op) in keyed:
+            continue
+        for x in op.inputs:
+            other_uses.add(x.guid)
+    for op in static_keyed:
+        other_uses.add(op.inputs[0].guid)
+    return [g for g in plan.static_needed
+            if g not in folded or g in other_uses]
+
+
+def _prefix_softmax(x, dim: int, live_ax: int, t):
+    """An attention row softmax over the prefix axis: inject the
+    causality/validity mask (hides the cache's unwritten tail; for causal
+    models this matches the graph's own mask)."""
+    kv = jax.lax.broadcasted_iota(jnp.int32, x.shape, dim)
+    if getattr(t, "ndim", 0) == 1:
+        if x.shape[0] != t.shape[0]:
+            raise NotImplementedError(
+                f"per-row positions: attention scores fold batch with "
+                f"another axis (axis 0 is {x.shape[0]}, batch "
+                f"{t.shape[0]})"
+            )
+        t = t.reshape((t.shape[0],) + (1,) * (x.ndim - 1))
+    qp = t + jax.lax.broadcasted_iota(jnp.int32, x.shape, live_ax)
+    x = jnp.where(kv <= qp, x, NEG_INF)
+    return jax.nn.softmax(x, axis=dim)
+
+
+def _append(cache, v, t, ax: int, guid):
+    """A prefix cache with the block `v` written at position `t` of its
+    live axis `ax` (per row where `t` is a vector)."""
+    if getattr(t, "ndim", 0) != 1:
+        return jax.lax.dynamic_update_slice_in_dim(
+            cache, v.astype(cache.dtype), t, axis=ax)
+    if ax == 0 or cache.shape[0] != t.shape[0]:
+        raise NotImplementedError(
+            f"per-row positions: prefix cache guid {guid} has no "
+            f"batch-leading axis (live axis {ax}, axis 0 {cache.shape[0]})"
+        )
+    return jax.vmap(
+        lambda c, vv, tt: jax.lax.dynamic_update_slice_in_dim(
+            c, vv, tt, axis=ax - 1)
+    )(cache, v.astype(cache.dtype), t)
+
+
+def _check_donated(caches, new_caches) -> None:
+    """XLA aliases a donated leaf to the output of its own shape and
+    type; one that comes back as another is copied every step after all,
+    and says so."""
+    for sec in SLOT_SECTIONS:
+        for (path, old), new in zip(
+                jax.tree_util.tree_leaves_with_path(caches[sec]),
+                jax.tree_util.tree_leaves(new_caches[sec])):
+            if (old.shape, old.dtype) != (new.shape, new.dtype):
+                decode_fallback(
+                    sec + jax.tree_util.keystr(path), "cache_not_donated",
+                    f"{old.dtype}{list(old.shape)} comes back as "
+                    f"{new.dtype}{list(new.shape)}")
+
+
+def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
+               batch: int, max_len: int, cache_dtype=None,
+               decode_input: Optional[int] = None,
+               assume_causal: bool = False, donate: bool = False):
+    """(init_caches, step) over the graph `topo`: the contract is
+    PCGExecutor.build_decode's, which memoises this by its arguments.
+    Decode is device-local: parallel ops are the identity and the weights
+    are read where the training executor placed them."""
+    plan = build_plan(topo, input_pts, constants, decode_input,
+                      assume_causal=assume_causal)
+    _check_plan(plan, logits_pt, max_len)
+    cdt = cache_dtype or compute_dtype or jnp.float32
+    static_pts = [pt for pt in input_pts if pt.guid != plan.decode_pt.guid]
+    ctx = FwdCtx(
+        training=False, rng=None, seq_length=-1,
+        compute_dtype=compute_dtype, aux_losses=None,
+        n_devices=1, mesh=None,
+    )
+    info = plan.info
+    cached_set = set(plan.cached_guids)
+
+    # which live ops keep state is their definitions' answer; which of
+    # them has static keys and values is the plan's
+    static_keyed = plan.static_keyed
+    static_keyed_set = {id(op) for op in static_keyed}
+    stateful = [op for op in plan.live_ops
+                if not op.is_parallel_op
+                and get_op_def(op.op_type).decode_section is not None
+                and id(op) not in static_keyed_set]
+    stateful_set = {id(op) for op in stateful}
+    needs_params = bool(static_keyed) or any(
+        op.weights for op in plan.static_ops if not op.is_parallel_op
+    )
+    static_kept = _kept_statics(plan, static_keyed)
+
+    def init_caches(params=None, static_inputs=()):
+        assert len(static_inputs) == len(static_pts), (
+            f"need {len(static_pts)} static (non-decode) input arrays, "
+            f"got {len(static_inputs)}"
+        )
+        assert params is not None or not needs_params, (
+            "this graph has encoder-side ops: call "
+            "init_caches(params, static_inputs)"
+        )
+        svals = _constants_at(constants, batch)
+        for pt, arr in zip(static_pts, static_inputs):
+            svals[pt.guid] = jnp.asarray(arr, pt.data_type.jnp_dtype)
+        svals = _statics(plan, svals, params, ctx)
+        # the step consumes the caches (donation): a static value that
+        # IS the caller's array (an input or a weight a live op reads
+        # as it came) or that lies under two guids is copied, so every
+        # leaf is a buffer the caches alone own
+        taken = {id(x) for x in jax.tree_util.tree_leaves(
+            (params, list(static_inputs)))} if static_kept else set()
+        caches = {sec: {} for sec in SHARED_SECTIONS + SLOT_SECTIONS}
+        for g in static_kept:
+            v = svals[g]
+            caches["static"][g] = jnp.copy(v) if id(v) in taken else v
+            taken.add(id(caches["static"][g]))
+        for g in plan.cached_guids:
+            pt = plan.cached_pt(g)
+            shape = list(pt.material_shape())
+            shape[info[g].live] = max_len
+            if info[g].live != 0:
+                shape[0] = batch  # decode batch, not compile batch
+            caches["prefix"][g] = jnp.zeros(shape, pt.data_type.jnp_dtype)
+        for op in stateful:
+            d = get_op_def(op.op_type)
+            caches[d.decode_section][op.name] = d.init_decode_state(
+                op.params, batch, max_len, cdt)
+        for op in static_keyed:
+            caches["mha_static"][op.name] = get_op_def(
+                op.op_type).init_decode_static(
+                    op.params, params.get(op.name, {}),
+                    [svals[x.guid] for x in op.inputs[1:]], ctx)
+        return caches
+
+    # the last live op that mixes positions: an op with a decode rule of
+    # its own, or a primitive-op attention's products and prefix softmax.
+    # Every op after it treats the positions of a block alike.
+    last_mixing = max(
+        (i for i, op in enumerate(plan.live_ops)
+         if not op.is_parallel_op and (
+             id(op) in stateful_set | static_keyed_set
+             or op.op_type in (OperatorType.OP_BATCHMATMUL,
+                               OperatorType.OP_SOFTMAX)
+             or any(x.guid in cached_set for x in op.outputs))),
+        default=-1)
+
+    def step(params, caches, t, batch_inputs, valid=None, row=None):
+        from .executor import _count_trace
+
+        (tok,) = batch_inputs
+        tok = jnp.asarray(tok, plan.decode_pt.data_type.jnp_dtype)
+        s0 = tok.shape[1]
+        # one token a row is a decode step, a block of them a prefill
+        _count_trace("decode_step" if s0 == 1 else "prefill")
+        # t may be a scalar (all rows at the same position) or a (b,)
+        # vector of per-row positions (continuous batching: each slot
+        # of a running decode batch is mid-way through its own
+        # sequence — runtime/serving.ContinuousBatcher)
+        per_row_t = getattr(t, "ndim", 0) == 1
+        if per_row_t and tok.shape[0] != t.shape[0]:
+            raise NotImplementedError(
+                f"per-row positions: {t.shape[0]} positions for "
+                f"{tok.shape[0]} rows"
+            )
+        if valid is not None:
+            valid = jnp.broadcast_to(
+                jnp.asarray(valid, jnp.int32), (tok.shape[0],))
+        consts = _constants_at(constants, batch)
+        statics = dict(caches["static"])
+        vals = {plan.decode_pt.guid: tok}
+        new_caches = _stepped(caches)
+
+        def get_static(g):
+            if g in statics:
+                return statics[g]
+            return consts[g]
+
+        def aligned_input(x, out_rank, out_info, site=""):
+            """A live op's input value: live tensors yield their
+            current slice; static/constant operands are sliced where
+            their full-length axes align with the live/prefix axes."""
+            g = x.guid
+            if g in vals:
+                return vals[g]
+            full = get_static(g)
+            # runtime shape, not the compiled ParallelTensor's — a
+            # batch-collapsed constant differs on axis 0
+            amap = _static_alignment(
+                tuple(full.shape), out_rank, out_info, plan.live_len,
+            )
+            return _slice_aligned(full, amap, t, s0, max_len,
+                                  out_rank=out_rank, site=site)
+
+        def run_op(op):
+            if op.is_parallel_op:
+                vals[op.outputs[0].guid] = vals[op.inputs[0].guid]
+                return
+            d = get_op_def(op.op_type)
+            w = params.get(op.name, {})
+            ot = op.op_type
+            out_info = info.get(op.outputs[0].guid, AxisInfo())
+
+            if id(op) in stateful_set:
+                ins = [vals[x.guid] for x in op.inputs]
+                outs, new_caches[d.decode_section][op.name] = \
+                    d.forward_decode(
+                        op.params, w, ins, ctx,
+                        caches[d.decode_section][op.name], t, valid=valid)
+            elif id(op) in static_keyed_set:
+                outs = d.forward_decode_static(
+                    op.params, w, [vals[op.inputs[0].guid]], ctx,
+                    caches["mha_static"][op.name],
+                )
+            elif ot == OperatorType.OP_BATCHMATMUL:
+                a_pt, b_pt = op.inputs
+                # lhs may itself be static (live operand on the rhs)
+                a = (vals[a_pt.guid] if a_pt.guid in vals
+                     else get_static(a_pt.guid))
+                b_info = info.get(b_pt.guid, AxisInfo())
+                if b_pt.guid in cached_set:
+                    b = new_caches["prefix"][b_pt.guid]
+                elif b_info.is_live:
+                    b = vals[b_pt.guid]
+                else:
+                    b_full = get_static(b_pt.guid)
+                    a_info = info.get(a_pt.guid, AxisInfo())
+                    rb = b_full.ndim
+                    if a_info.prefix == len(a_pt.material_shape()) - 1:
+                        # probs @ static V of compiled length: keep
+                        # only the cap positions the cache covers
+                        b_full = jax.lax.slice_in_dim(
+                            b_full, 0, max_len, axis=rb - 2
+                        )
+                    b = b_full
+                outs = [jnp.matmul(
+                    a, b, preferred_element_type=jnp.float32
+                ).astype(a.dtype)]
+            elif ot == OperatorType.OP_SOFTMAX:
+                x = vals[op.inputs[0].guid]
+                dim = op.params.dim % x.ndim
+                a_info = info[op.inputs[0].guid]
+                if a_info.prefix is not None and dim == a_info.prefix:
+                    assert a_info.live is not None, (
+                        "prefix softmax without a live query axis"
+                    )
+                    outs = [_prefix_softmax(x, dim, a_info.live, t)]
+                else:
+                    outs = [jax.nn.softmax(x, axis=dim)]
+            elif ot in (OperatorType.OP_RESHAPE, OperatorType.OP_FLAT):
+                x = vals[op.inputs[0].guid]
+                target = list(op.outputs[0].material_shape())
+                if out_info.live is not None:
+                    target[out_info.live] = s0
+                if out_info.live != 0:
+                    target[0] = -1  # batch may differ from compile
+                outs = [jnp.reshape(x, target)]
+            else:
+                out_rank = len(op.outputs[0].material_shape())
+                ins = [aligned_input(x, out_rank, out_info, op.name)
+                       for x in op.inputs]
+                outs = d.forward(op.params, w, ins, ctx)
+
+            for x, v in zip(op.outputs, outs):
+                vals[x.guid] = v
+                if x.guid in cached_set:
+                    new_caches["prefix"][x.guid] = _append(
+                        caches["prefix"][x.guid], v, t, info[x.guid].live,
+                        x.guid)
+
+        # the same scopes as the train step's forward: ff.decode, then
+        # one per PCG operator
+        with jax.named_scope("ff.decode"):
+            for i, op in enumerate(plan.live_ops):
+                with jax.named_scope(op.name):
+                    run_op(op)
+                if i == last_mixing and row is not None and s0 > 1:
+                    # from here on one position a row: every live
+                    # value is cut to it, and static operands are
+                    # sliced at that position (aligned_input reads
+                    # t and s0)
+                    at = jnp.broadcast_to(
+                        jnp.asarray(row, jnp.int32), (tok.shape[0],))
+                    for g, v in list(vals.items()):
+                        ax = info.get(g, AxisInfo()).live
+                        if ax is None:
+                            continue
+                        if ax == 0:
+                            raise NotImplementedError(
+                                "one row of a block: a live tensor has "
+                                "no batch axis before its live axis")
+                        vals[g] = jax.vmap(
+                            lambda r, n, _ax=ax:
+                            jax.lax.dynamic_slice_in_dim(
+                                r, n, 1, axis=_ax - 1)
+                        )(v, at)
+                    t, s0 = t + jnp.asarray(row, jnp.int32), 1
+        if donate:
+            _check_donated(caches, new_caches)
+        return vals[logits_pt.guid], new_caches
+
+    return init_caches, jax.jit(step, donate_argnums=(1,) if donate else ())

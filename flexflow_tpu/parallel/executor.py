@@ -1252,10 +1252,11 @@ class PCGExecutor:
                      decode_input: Optional[int] = None,
                      assume_causal: bool = False):
         """(init_caches, step) for KV-cache autoregressive decoding over an
-        arbitrary causal decoder or encoder-decoder PCG (the liveness/
-        prefix analysis in parallel/decode.py — graphs imported from HF
-        build attention from primitive batch_matmul/softmax/mask ops and
-        still decode O(1)/token).
+        arbitrary causal decoder or encoder-decoder PCG, built by
+        parallel/decode.py (its liveness/prefix analysis and build_step —
+        graphs imported from HF build attention from primitive
+        batch_matmul/softmax/mask ops and still decode O(1)/token) and
+        memoised here by its arguments.
 
         init_caches(params=None, static_inputs=()) computes the static
         (encoder-side) subgraph once and zero-fills the prefix/KV caches;
@@ -1299,436 +1300,17 @@ class PCGExecutor:
         ops mixing sequence positions without a decode rule, non-causal
         self-attention, softmax over the live axis."""
         from . import decode as dec
-        from ..ops.attention import cross_decode_kv, init_decode_cache
-        from ..ops.linear_attention import init_state as init_recurrent_state
 
         donate = self.donates_buffers()
         key = (batch, max_len, cache_dtype, decode_input, assume_causal,
                donate)
-        cached = self._decode_builds.get(key)
-        if cached is not None:
-            return cached
-
-        plan = dec.build_plan(self.topo, self.input_pts, self.constants,
-                              decode_input, assume_causal=assume_causal)
-        # prefix caches patch ONLY axis 0 to the decode batch; a graph that
-        # folds batch with heads on axis 0 (B*H, ...) would get a
-        # wrong-sized cache when decoding at a different batch than
-        # compile (beam search at num_beams) — reject at build like the
-        # other exactness checks
-        compile_batch = plan.decode_pt.material_shape()[0]
-        for g in plan.cached_guids:
-            pt = next(x for op in plan.live_ops for x in op.outputs
-                      if x.guid == g)
-            if plan.info[g].live != 0 and \
-                    pt.material_shape()[0] != compile_batch:
-                raise NotImplementedError(
-                    f"cached tensor guid {g} has axis-0 size "
-                    f"{pt.material_shape()[0]} != compiled batch "
-                    f"{compile_batch}: its batch dim is folded with "
-                    "another axis, so decoding at a different batch "
-                    "would mis-size the cache"
-                )
-        if plan.requires_cap_le_live_len and max_len > plan.live_len:
-            raise NotImplementedError(
-                f"max_len {max_len} > compiled decoder length "
-                f"{plan.live_len}: the graph bakes full-length constants "
-                "(masks/position tables) that can't be extended"
-            )
-        if not plan.info.get(self.logits_pt.guid, dec.AxisInfo()).is_live:
-            raise NotImplementedError(
-                "the graph output does not depend on the decode input"
-            )
-        cdt = cache_dtype or self.compute_dtype or jnp.float32
-        static_pts = [pt for pt in self.input_pts
-                      if pt.guid != plan.decode_pt.guid]
-        ctx = FwdCtx(
-            training=False, rng=None, seq_length=-1,
-            compute_dtype=self.compute_dtype, aux_losses=None,
-            n_devices=1, mesh=None,  # decode is device-local
-        )
-
-        # MHA classification: self-attention (live k/v -> per-op KV cache)
-        # vs cross-attention (static k/v -> precomputed encoder K/V)
-        mha_self, mha_cross, recurrent = [], [], []
-        for op in plan.live_ops:
-            if op.is_parallel_op:
-                continue
-            if op.op_type == OperatorType.OP_GATED_DELTA_NET:
-                recurrent.append(op)
-            if op.op_type == OperatorType.OP_MULTIHEAD_ATTENTION:
-                if plan.info.get(op.inputs[1].guid, dec.AxisInfo()).is_live:
-                    mha_self.append(op)
-                else:
-                    mha_cross.append(op)
-
-        def _materialize_constants():
-            """Baked constants, with batch-uniform leading axes collapsed
-            to 1: decode may run at a different batch than compile (beam
-            search runs at num_beams), and constants like HF's extended
-            attention masks carry the compiled batch size — when every
-            row is identical (no per-sample padding was traced) a
-            broadcastable row-1 constant is exact."""
-            vals = {}
-            for guid, (pt, value) in self.constants.items():
-                shape = tuple(pt.material_shape())
-                if isinstance(value, np.ndarray):
-                    arr = value
-                    if (arr.ndim >= 1 and arr.shape[0] not in (1, batch)
-                            and np.array_equal(arr, np.broadcast_to(
-                                arr[:1], arr.shape), equal_nan=True)):
-                        arr = arr[:1]
-                    vals[guid] = jnp.asarray(arr, pt.data_type.jnp_dtype)
-                else:
-                    if len(shape) >= 1 and shape[0] not in (1, batch):
-                        shape = (1,) + shape[1:]
-                    vals[guid] = jnp.full(
-                        shape, value, pt.data_type.jnp_dtype
-                    )
-            return vals
-
-        def _compute_statics(params, static_arrays):
-            vals = _materialize_constants()
-            for pt, arr in zip(static_pts, static_arrays):
-                vals[pt.guid] = jnp.asarray(arr, pt.data_type.jnp_dtype)
-            for op in plan.static_ops:
-                if op.is_parallel_op:
-                    vals[op.outputs[0].guid] = vals[op.inputs[0].guid]
-                    continue
-                d = get_op_def(op.op_type)
-                ins = [vals[x.guid] for x in op.inputs]
-                w = (params or {}).get(op.name, {})
-                if (op.op_type == OperatorType.OP_RESHAPE
-                        and tuple(ins[0].shape)
-                        != tuple(op.inputs[0].material_shape())):
-                    # traced reshape params bake the compiled batch size;
-                    # decode may run at a different batch (beam search) —
-                    # recompute the batch axis
-                    target = list(op.outputs[0].material_shape())
-                    target[0] = -1
-                    outs = [jnp.reshape(ins[0], target)]
-                else:
-                    outs = d.forward(op.params, w, ins, ctx)
-                for x, v in zip(op.outputs, outs):
-                    vals[x.guid] = v
-            return vals
-
-        needs_params = bool(mha_cross) or any(
-            op.weights for op in plan.static_ops if not op.is_parallel_op
-        )
-
-        # static values whose ONLY live consumers are cross-attention k/v
-        # slots are folded into the precomputed K/V — keeping the raw
-        # encoder hidden states in the cache would waste HBM per layer
-        cross_kv_guids = {op.inputs[i].guid
-                          for op in mha_cross for i in (1, 2)}
-        other_uses = set()
-        for op in plan.live_ops:
-            if op.is_parallel_op or id(op) in {id(o) for o in mha_cross}:
-                continue
-            for x in op.inputs:
-                other_uses.add(x.guid)
-        for op in mha_cross:
-            other_uses.add(op.inputs[0].guid)
-        static_kept = [g for g in plan.static_needed
-                       if g not in cross_kv_guids or g in other_uses]
-
-        def init_caches(params=None, static_inputs=()):
-            assert len(static_inputs) == len(static_pts), (
-                f"need {len(static_pts)} static (non-decode) input arrays, "
-                f"got {len(static_inputs)}"
-            )
-            assert params is not None or not needs_params, (
-                "this graph has encoder-side ops: call "
-                "init_caches(params, static_inputs)"
-            )
-            svals = _compute_statics(params, static_inputs)
-            # the step consumes the caches (donation): a static value that
-            # IS the caller's array (an input or a weight a live op reads
-            # as it came) or that lies under two guids is copied, so every
-            # leaf is a buffer the caches alone own
-            taken = {id(x) for x in jax.tree_util.tree_leaves(
-                (params, list(static_inputs)))} if static_kept else set()
-            static = {}
-            for g in static_kept:
-                v = svals[g]
-                static[g] = jnp.copy(v) if id(v) in taken else v
-                taken.add(id(static[g]))
-            caches = {
-                "static": static,
-                "prefix": {},
-                "mha": {},
-                # beam-invariant per-op statics (cross-attention encoder
-                # K/V): separate key so serving's beam reorder can skip
-                # gathering them
-                "mha_static": {},
-                # state of fixed size per slot, beside the keys and values
-                "recurrent": {},
-            }
-            for op in recurrent:
-                caches["recurrent"][op.name] = init_recurrent_state(
-                    op.params, batch, cdt)
-            for g in plan.cached_guids:
-                pt = next(x for op in plan.live_ops for x in op.outputs
-                          if x.guid == g)
-                shape = list(pt.material_shape())
-                shape[plan.info[g].live] = max_len
-                if plan.info[g].live != 0:
-                    shape[0] = batch  # decode batch, not compile batch
-                caches["prefix"][g] = jnp.zeros(
-                    shape, pt.data_type.jnp_dtype
-                )
-            for op in mha_self:
-                caches["mha"][op.name] = init_decode_cache(
-                    op.params, batch, max_len, cdt
-                )
-            for op in mha_cross:
-                caches["mha_static"][op.name] = cross_decode_kv(
-                    op.params, params.get(op.name, {}),
-                    svals[op.inputs[1].guid], svals[op.inputs[2].guid],
-                    ctx,
-                )
-            return caches
-
-        info = plan.info
-        cached_set = set(plan.cached_guids)
-        mha_cross_set = {id(op) for op in mha_cross}
-        mha_self_set = {id(op) for op in mha_self}
-        recurrent_set = {id(op) for op in recurrent}
-        # the last live op that mixes positions: attention, a recurrent
-        # op, or a primitive-op attention's products and prefix softmax.
-        # Every op after it treats the positions of a block alike.
-        last_mixing = max(
-            (i for i, op in enumerate(plan.live_ops)
-             if not op.is_parallel_op and (
-                 id(op) in mha_self_set | mha_cross_set | recurrent_set
-                 or op.op_type in (OperatorType.OP_BATCHMATMUL,
-                                   OperatorType.OP_SOFTMAX)
-                 or any(x.guid in cached_set for x in op.outputs))),
-            default=-1)
-
-        def step(params, caches, t, batch_inputs, valid=None, row=None):
-            (tok,) = batch_inputs
-            tok = jnp.asarray(tok, plan.decode_pt.data_type.jnp_dtype)
-            s0 = tok.shape[1]
-            # one token a row is a decode step, a block of them a prefill
-            _count_trace("decode_step" if s0 == 1 else "prefill")
-            # t may be a scalar (all rows at the same position) or a (b,)
-            # vector of per-row positions (continuous batching: each slot
-            # of a running decode batch is mid-way through its own
-            # sequence — runtime/serving.ContinuousBatcher)
-            per_row_t = getattr(t, "ndim", 0) == 1
-            if per_row_t and tok.shape[0] != t.shape[0]:
-                raise NotImplementedError(
-                    f"per-row positions: {t.shape[0]} positions for "
-                    f"{tok.shape[0]} rows"
-                )
-            if valid is not None:
-                valid = jnp.broadcast_to(
-                    jnp.asarray(valid, jnp.int32), (tok.shape[0],))
-            consts = _materialize_constants()
-            statics = dict(caches["static"])
-            vals = {plan.decode_pt.guid: tok}
-            new_caches = {
-                "static": caches["static"],
-                "prefix": dict(caches["prefix"]),
-                "mha": dict(caches["mha"]),
-                "mha_static": caches["mha_static"],
-                "recurrent": dict(caches["recurrent"]),
-            }
-
-            def get_static(g):
-                if g in statics:
-                    return statics[g]
-                return consts[g]
-
-            def aligned_input(x, out_rank, out_info, site=""):
-                """A live op's input value: live tensors yield their
-                current slice; static/constant operands are sliced where
-                their full-length axes align with the live/prefix axes."""
-                g = x.guid
-                if g in vals:
-                    return vals[g]
-                full = get_static(g)
-                # runtime shape, not the compiled ParallelTensor's — a
-                # batch-collapsed constant differs on axis 0
-                amap = dec._static_alignment(
-                    tuple(full.shape), out_rank, out_info, plan.live_len,
-                )
-                return dec._slice_aligned(full, amap, t, s0, max_len,
-                                          out_rank=out_rank, site=site)
-
-            def run_op(op):
-                if op.is_parallel_op:
-                    vals[op.outputs[0].guid] = vals[op.inputs[0].guid]
-                    return
-                d = get_op_def(op.op_type)
-                w = params.get(op.name, {})
-                ot = op.op_type
-                out_info = info.get(op.outputs[0].guid, dec.AxisInfo())
-
-                if id(op) in mha_self_set:
-                    ins = [vals[x.guid] for x in op.inputs]
-                    outs, new_caches["mha"][op.name] = d.forward_decode(
-                        op.params, w, ins, ctx, caches["mha"][op.name], t
-                    )
-                elif id(op) in recurrent_set:
-                    ins = [vals[x.guid] for x in op.inputs]
-                    outs, new_caches["recurrent"][op.name] = \
-                        d.forward_decode(
-                            op.params, w, ins, ctx,
-                            caches["recurrent"][op.name], t, valid=valid)
-                elif id(op) in mha_cross_set:
-                    from ..ops.attention import _forward_decode_cross
-
-                    outs = _forward_decode_cross(
-                        op.params, w, vals[op.inputs[0].guid], ctx,
-                        caches["mha_static"][op.name],
-                    )
-                elif ot == OperatorType.OP_BATCHMATMUL:
-                    a_pt, b_pt = op.inputs
-                    # lhs may itself be static (live operand on the rhs)
-                    a = (vals[a_pt.guid] if a_pt.guid in vals
-                         else get_static(a_pt.guid))
-                    b_info = info.get(b_pt.guid, dec.AxisInfo())
-                    if b_pt.guid in cached_set:
-                        b = new_caches["prefix"][b_pt.guid]
-                    elif b_info.is_live:
-                        b = vals[b_pt.guid]
-                    else:
-                        b_full = get_static(b_pt.guid)
-                        a_info = info.get(a_pt.guid, dec.AxisInfo())
-                        rb = b_full.ndim
-                        if a_info.prefix == len(a_pt.material_shape()) - 1:
-                            # probs @ static V of compiled length: keep
-                            # only the cap positions the cache covers
-                            b_full = jax.lax.slice_in_dim(
-                                b_full, 0, max_len, axis=rb - 2
-                            )
-                        b = b_full
-                    outs = [jnp.matmul(
-                        a, b, preferred_element_type=jnp.float32
-                    ).astype(a.dtype)]
-                elif ot == OperatorType.OP_SOFTMAX:
-                    x = vals[op.inputs[0].guid]
-                    nd = x.ndim
-                    dim = op.params.dim % nd
-                    a_info = info[op.inputs[0].guid]
-                    if a_info.prefix is not None and dim == a_info.prefix:
-                        # attention row softmax over the prefix axis:
-                        # inject the causality/validity mask (hides the
-                        # cache's unwritten tail; for causal models this
-                        # matches the graph's own mask)
-                        assert a_info.live is not None, (
-                            "prefix softmax without a live query axis"
-                        )
-                        kv = jax.lax.broadcasted_iota(jnp.int32, x.shape, dim)
-                        if per_row_t:
-                            if x.shape[0] != t.shape[0]:
-                                raise NotImplementedError(
-                                    f"per-row positions: attention scores "
-                                    f"fold batch with another axis "
-                                    f"(axis 0 is {x.shape[0]}, batch "
-                                    f"{t.shape[0]})"
-                                )
-                            t_rows = t.reshape(
-                                (t.shape[0],) + (1,) * (x.ndim - 1)
-                            )
-                            qp = t_rows + jax.lax.broadcasted_iota(
-                                jnp.int32, x.shape, a_info.live
-                            )
-                        else:
-                            qp = t + jax.lax.broadcasted_iota(
-                                jnp.int32, x.shape, a_info.live
-                            )
-                        x = jnp.where(kv <= qp, x, dec.NEG_INF)
-                    outs = [jax.nn.softmax(x, axis=dim)]
-                elif ot in (OperatorType.OP_RESHAPE, OperatorType.OP_FLAT):
-                    x = vals[op.inputs[0].guid]
-                    target = list(op.outputs[0].material_shape())
-                    if out_info.live is not None:
-                        target[out_info.live] = s0
-                    if out_info.live != 0:
-                        target[0] = -1  # batch may differ from compile
-                    outs = [jnp.reshape(x, target)]
-                else:
-                    out_rank = len(op.outputs[0].material_shape())
-                    ins = [aligned_input(x, out_rank, out_info, op.name)
-                           for x in op.inputs]
-                    outs = d.forward(op.params, w, ins, ctx)
-
-                for x, v in zip(op.outputs, outs):
-                    vals[x.guid] = v
-                    if x.guid in cached_set:
-                        ax = info[x.guid].live
-                        cache = caches["prefix"][x.guid]
-                        if per_row_t:
-                            if ax == 0 or cache.shape[0] != t.shape[0]:
-                                raise NotImplementedError(
-                                    f"per-row positions: prefix cache guid "
-                                    f"{x.guid} has no batch-leading axis "
-                                    f"(live axis {ax}, axis 0 "
-                                    f"{cache.shape[0]})"
-                                )
-                            new_caches["prefix"][x.guid] = jax.vmap(
-                                lambda c, vv, tt, _ax=ax:
-                                jax.lax.dynamic_update_slice_in_dim(
-                                    c, vv, tt, axis=_ax - 1
-                                )
-                            )(cache, v.astype(cache.dtype), t)
-                        else:
-                            new_caches["prefix"][x.guid] = (
-                                jax.lax.dynamic_update_slice_in_dim(
-                                    cache, v.astype(cache.dtype), t, axis=ax
-                                )
-                            )
-
-            # the same scopes as the train step's forward: ff.decode, then
-            # one per PCG operator
-            with jax.named_scope("ff.decode"):
-                for i, op in enumerate(plan.live_ops):
-                    with jax.named_scope(op.name):
-                        run_op(op)
-                    if i == last_mixing and row is not None and s0 > 1:
-                        # from here on one position a row: every live
-                        # value is cut to it, and static operands are
-                        # sliced at that position (aligned_input reads
-                        # t and s0)
-                        at = jnp.broadcast_to(
-                            jnp.asarray(row, jnp.int32), (tok.shape[0],))
-                        for g, v in list(vals.items()):
-                            ax = info.get(g, dec.AxisInfo()).live
-                            if ax is None:
-                                continue
-                            if ax == 0:
-                                raise NotImplementedError(
-                                    "one row of a block: a live tensor has "
-                                    "no batch axis before its live axis")
-                            vals[g] = jax.vmap(
-                                lambda r, n, _ax=ax:
-                                jax.lax.dynamic_slice_in_dim(
-                                    r, n, 1, axis=_ax - 1)
-                            )(v, at)
-                        t, s0 = t + jnp.asarray(row, jnp.int32), 1
-            if donate:
-                # XLA aliases a donated leaf to the output of its own
-                # shape and type; one that comes back as another is
-                # copied every step after all, and says so
-                for sec in ("prefix", "mha", "recurrent"):
-                    for (path, old), new in zip(
-                            jax.tree_util.tree_leaves_with_path(caches[sec]),
-                            jax.tree_util.tree_leaves(new_caches[sec])):
-                        if (old.shape, old.dtype) != (new.shape, new.dtype):
-                            dec.decode_fallback(
-                                sec + jax.tree_util.keystr(path),
-                                "cache_not_donated",
-                                f"{old.dtype}{list(old.shape)} comes back "
-                                f"as {new.dtype}{list(new.shape)}")
-            return vals[self.logits_pt.guid], new_caches
-
-        built = (init_caches,
-                 jax.jit(step, donate_argnums=(1,) if donate else ()))
-        self._decode_builds[key] = built
+        built = self._decode_builds.get(key)
+        if built is None:
+            built = self._decode_builds[key] = dec.build_step(
+                self.topo, self.input_pts, self.constants, self.logits_pt,
+                self.compute_dtype, batch=batch, max_len=max_len,
+                cache_dtype=cache_dtype, decode_input=decode_input,
+                assume_causal=assume_causal, donate=donate)
         return built
 
     # -- data placement -----------------------------------------------------

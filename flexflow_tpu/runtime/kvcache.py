@@ -864,50 +864,36 @@ def kv_page_bytes(model, page_size: int,
     ~2x the sessions of an fp16 pool in the same byte budget; None keeps
     the executor's compute dtype (fp32 when unset).
     Returns None when the graph has no fused-MHA self-attention (e.g.
-    primitive-op imports, where the cache cost lives in prefix tensors)."""
+    primitive-op imports, where the cache cost lives in prefix tensors).
+    Which ops keep keys and values, and in what shape, is their own
+    definitions' answer (parallel/decode.py declared_state_bytes)."""
     import numpy as np
 
-    from ..ff_types import OperatorType
+    from ..parallel.decode import declared_state_bytes
 
     ex = getattr(model, "executor", None)
     if ex is None:
         return None
-    total = 0
-    if kv_dtype is not None:
-        itemsize = np.dtype(kv_dtype).itemsize
-    else:
-        itemsize = np.dtype(np.float32).itemsize
-        cdt = getattr(ex, "compute_dtype", None)
-        if cdt is not None:
-            itemsize = np.dtype(cdt).itemsize
-    for op in ex.topo:
-        if getattr(op, "op_type", None) != OperatorType.OP_MULTIHEAD_ATTENTION:
-            continue
-        p = op.params
-        total += page_size * p.num_heads * (p.qk_head_dim + p.v_head_dim) \
-            * itemsize
-    return total or None
+    dtype = kv_dtype or getattr(ex, "compute_dtype", None) or np.float32
+    return declared_state_bytes(
+        ex.topo, "kv", page_size, np.dtype(dtype)) or None
 
 
 def recurrent_slot_bytes(model) -> int:
-    """Bytes ONE slot holds of recurrent state across the model's gated
-    delta-rule layers (ops/linear_attention.py state_bytes): the second
+    """Bytes ONE slot holds of state of fixed size across the model's
+    layers (the gated delta-rule layers' recurrent state): the second
     kind of per-slot state. It does not grow with the sequence, so it is
     no page's cost: a slot pays it once, whatever it reserves in pages.
     0 for a graph with no such layer."""
     import numpy as np
 
-    from ..ff_types import OperatorType
-    from ..ops.linear_attention import state_bytes
+    from ..parallel.decode import declared_state_bytes
 
     ex = getattr(model, "executor", None)
     if ex is None:
         return 0
-    cdt = getattr(ex, "compute_dtype", None)
-    itemsize = np.dtype(cdt if cdt is not None else np.float32).itemsize
-    return sum(state_bytes(op.params, itemsize) for op in ex.topo
-               if getattr(op, "op_type", None)
-               == OperatorType.OP_GATED_DELTA_NET)
+    dtype = getattr(ex, "compute_dtype", None) or np.float32
+    return declared_state_bytes(ex.topo, "fixed", 1, np.dtype(dtype))
 
 
 def slot_reservation_bytes(model, config: KVCacheConfig,

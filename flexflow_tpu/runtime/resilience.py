@@ -546,13 +546,16 @@ class CheckpointManager:
             names = os.listdir(self.directory)
         except OSError:
             return
-        pid_suffix = str(os.getpid())
+        pid = str(os.getpid())
         for name in names:
             if ".tmp-" in name:
-                # tmp names end in the writer's pid; OUR pid means another
-                # manager in this process (warm spare / replica sharing the
-                # dir) may be mid-save — sweeping its tmp races os.replace
-                if name.rsplit("-", 1)[-1] == pid_suffix:
+                # tmp names carry the writer's pid (orbax appends a suffix
+                # of its own to the directory it fills); OUR pid means
+                # another manager in this process (warm spare / replica
+                # sharing the dir) may be mid-save — sweeping its tmp
+                # races os.replace
+                m = re.search(r"\.tmp-(?:old-|gc-)?(\d+)", name)
+                if m is not None and m.group(1) == pid:
                     continue
                 p = os.path.join(self.directory, name)
                 shutil.rmtree(p, ignore_errors=True)

@@ -64,6 +64,7 @@ from ..obs.request_trace import (
     mint_request_trace,
     record_request_stages,
 )
+from ..parallel import decode
 from .kvcache import (KVCacheConfig, KVCacheExhaustedError, PagePool,
                       slot_reservation_bytes)
 from .resilience import ResilienceError
@@ -362,19 +363,9 @@ def incremental_beam_generate(
             if eos_token_id is not None:
                 done = done[src_beams] | (beams[:, t] == eos_token_id)
             # per-beam caches follow their beams (identity gathers are
-            # common early on; jnp.take keeps the shuffle on-device).
-            # "static" and "mha_static" (cross-attention encoder K/V) stay
-            # untouched: they are beam-invariant, and constant-derived
-            # static entries have leading axis 1 — a batch gather would
-            # fill out-of-bounds rows with NaN.
-            idx = jnp.asarray(src_beams.astype(np.int32))
-            gathered = jax.tree_util.tree_map(
-                lambda c: jnp.take(c, idx, axis=0),
-                {"prefix": caches["prefix"], "mha": caches["mha"],
-                 "recurrent": caches["recurrent"]},
-            )
-            caches = {"static": caches["static"],
-                      "mha_static": caches["mha_static"], **gathered}
+            # common early on; the shuffle stays on the device)
+            caches = decode.take_rows(
+                caches, jnp.asarray(src_beams.astype(np.int32)))
             if (eos_token_id is not None and done.all()) or t == total - 1:
                 break
             logits, caches = step(
@@ -1344,41 +1335,16 @@ class ContinuousBatcher:
         with self._device_lock, obs.mark(
                 "ff.serve.insert", cat="serving",
                 into=(self.stats, "insert_s"), slot=slot_idx):
-            self._insert_slot_locked(jax, slot_idx, caches1)
+            self._insert_slot_locked(slot_idx, caches1)
 
-    def _insert_slot_locked(self, jax, slot_idx: int, caches1) -> None:
+    def _insert_slot_locked(self, slot_idx: int, caches1) -> None:
         if self._caches is None:
             self._caches = self._initB(self.model.state.params, ())
             self._note_state_bytes()
-        # each old leaf is let go as its successor is made: the insert
-        # holds one spare leaf, never a second generation of the caches
-        # beside the first (the decode step no longer does either)
+        # the insert consumes the caches as a step does: nothing here
+        # holds the old tree while its successor is made
         caches, self._caches = self._caches, None
-
-        def put(old, row):
-            return jax.lax.dynamic_update_slice_in_dim(
-                old, row.astype(old.dtype), slot_idx, axis=0)
-
-        out = {"static": caches["static"], "mha_static": caches["mha_static"],
-               "prefix": {}, "mha": {}, "recurrent": {}}
-        for g in list(caches["prefix"]):
-            row = caches1["prefix"][g]
-            shape = tuple(caches["prefix"][g].shape)
-            if shape != (self.config.slots,) + tuple(row.shape[1:]):
-                raise ServingConfigError(
-                    f"prefix cache guid {g} has no per-slot leading axis "
-                    f"(batch shape {shape} vs row "
-                    f"{tuple(row.shape)}) — this graph folds batch with "
-                    "another axis and cannot be continuously batched"
-                )
-            out["prefix"][g] = put(caches["prefix"].pop(g), row)
-        # (k, v) of an attention op; (S, conv_tail) of a recurrent one
-        for sec in ("mha", "recurrent"):
-            for opname in list(caches[sec]):
-                leaves = list(caches[sec].pop(opname))
-                out[sec][opname] = tuple(
-                    put(leaves.pop(0), row) for row in caches1[sec][opname])
-        self._caches = out
+        self._caches = decode.insert_row(caches, caches1, slot_idx)
 
     def _note_state_bytes(self) -> None:
         """What the slots hold of each kind of per-slot state, as gauges
@@ -1387,12 +1353,9 @@ class ContinuousBatcher:
         state, which does not."""
         from .. import obs
 
-        def nbytes(*sections):
-            return int(sum(leaf.nbytes for sec in sections for leaf in
-                           jax.tree_util.tree_leaves(self._caches[sec])))
-
-        self.stats["kv_cache_bytes"] = nbytes("mha", "prefix")
-        self.stats["recurrent_state_bytes"] = nbytes("recurrent")
+        held = decode.state_bytes(self._caches)
+        self.stats["kv_cache_bytes"] = held["kv"]
+        self.stats["recurrent_state_bytes"] = held["fixed"]
         for kind in ("kv_cache_bytes", "recurrent_state_bytes"):
             obs.gauge_set("ff_serving_" + kind, self.stats[kind],
                           help="bytes the decode slots hold of this kind "

@@ -164,8 +164,8 @@ def op_padded_flops(op: PCGOp, parts: int = 1) -> float:
     wide (output channels), 128 deep (contraction), with 8-row sublanes;
     a matmul whose dims are not tile multiples runs at the PADDED
     shape's cost (the public scaling-book tile-quantization rule, and
-    what our own silicon measurements show: head_dim-64 attention
-    matmuls cap at ~98 TF/s = half the 197 TF/s peak, BASELINE.md).
+    what a head_dim-64 attention matmul shows on the chip: half the
+    lanes are padding).
     Padding applies to the SHARD shape, not the logical one — splitting
     a 128-wide gemm two ways leaves each 64-wide shard paying a full
     tile, so over-sharding narrow dims correctly stops helping. This is

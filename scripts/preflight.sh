@@ -4,15 +4,17 @@
 # suite and ended with 3 red tests and an rc=1 driver dryrun; this script
 # makes that class of damage impossible to ship silently.
 #
-#   scripts/preflight.sh           # full: pytest + dryrun(8) + bench smoke
-#   scripts/preflight.sh --fast    # skip the bench smoke
+#   scripts/preflight.sh           # pytest + dryrun(8)
+#
+# On a TPU host run `python chip_smoke.py` as well: it is the check that
+# the trainer and the server start on the chip. Speed is measured by
+# perfbench/ alone (perfbench/README.md).
 #
 # Exits non-zero on ANY failure. Paste the tail of its output into the
 # snapshot commit message.
 set -uo pipefail
 cd "$(dirname "$0")/.."
 
-FAST=${1:-}
 FAIL=0
 
 echo "== preflight: pytest =="
@@ -42,20 +44,6 @@ then
 else
     echo "preflight dryrun: FAILED"
     FAIL=1
-fi
-
-if [ "$FAST" != "--fast" ]; then
-    echo "== preflight: bench smoke =="
-    # FF_BENCH_SMOKE trims steps so this is a compile+run sanity check
-    # on whatever backend is here, not a measurement (its JSON line names
-    # the platform). On a TPU host run `python chip_smoke.py` first: it
-    # is the check that the trainer and the server start on the chip.
-    if FF_BENCH_SMOKE=1 python bench.py; then
-        echo "preflight bench: OK"
-    else
-        echo "preflight bench: FAILED"
-        FAIL=1
-    fi
 fi
 
 if [ "$FAIL" -ne 0 ]; then

@@ -232,12 +232,12 @@ def test_the_batcher_serves_the_same_tokens_from_donated_caches(
         np.testing.assert_array_equal(a, b)
 
 
-def test_an_insert_never_holds_a_second_generation_of_the_caches(hybrid):
+def test_an_insert_never_holds_a_second_generation_of_the_caches(
+        hybrid, monkeypatch):
     """`_insert_slot` writes a prefilled strip into every per-slot leaf.
     Each old leaf is let go as its successor is made, so what the insert
     holds beside the caches is one leaf, not a copy of them all (on the
     chip that copy was the peak: PERF.md section 6, PR 29)."""
-    import types
     import weakref
 
     b = ContinuousBatcher(hybrid, _serve_cfg(), AdmissionQueue(max_depth=4))
@@ -251,12 +251,15 @@ def test_an_insert_never_holds_a_second_generation_of_the_caches(hybrid):
     assert len(old) == 4  # (k, v) and (S, conv_tail)
     alive = []
 
+    update = jax.lax.dynamic_update_slice_in_dim
+
     def spy(*a, **kw):
         alive.append(sum(ref() is not None for ref in old))
-        return jax.lax.dynamic_update_slice_in_dim(*a, **kw)
+        return update(*a, **kw)
 
-    b._insert_slot_locked(types.SimpleNamespace(lax=types.SimpleNamespace(
-        dynamic_update_slice_in_dim=spy)), 1, strip)
+    monkeypatch.setattr(jax.lax, "dynamic_update_slice_in_dim", spy)
+    b._insert_slot(1, strip)
+    monkeypatch.undo()
     assert alive == [4, 3, 2, 1]
     assert all(ref() is None for ref in old)
     assert len(_leaves(b._caches, "mha", "recurrent")) == 4

@@ -396,9 +396,11 @@ def test_on_hang_callback_fires_once():
     try:
         mon.step_started(7)
         deadline = time.monotonic() + 5.0
-        while not mon.hang_detected and time.monotonic() < deadline:
+        # the flag is set before the callback runs: wait for the callback
+        while not calls and time.monotonic() < deadline:
             time.sleep(0.02)
         assert mon.hang_detected
+        time.sleep(0.3)  # several more polls: it does not fire again
         assert len(calls) == 1 and calls[0]["step"] == 7
     finally:
         mon.stop()

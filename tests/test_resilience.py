@@ -189,6 +189,22 @@ def test_checkpoint_write_failure_never_leaves_partial(tmp_path):
     assert _no_partials(str(tmp_path)) == []  # ...and no debris either
 
 
+def test_stale_tmp_sweep_spares_this_process_own_writers(tmp_path):
+    """A manager opened while another in this process is mid-save (a
+    restarted replica restoring beside ReplicaSet.start's first
+    checkpoint) sweeps only other processes' debris. orbax fills a
+    directory named after ours plus a suffix of its own: that one is
+    ours too."""
+    mgr = CheckpointManager(str(tmp_path))
+    ours = f"step_0000000000.tmp-{os.getpid()}.orbax-checkpoint-tmp"
+    theirs = "step_0000000000.tmp-1.orbax-checkpoint-tmp"
+    for name in (ours, theirs, f"step_0000000001.tmp-{os.getpid()}"):
+        os.makedirs(tmp_path / name)
+    mgr.clean_stale_tmp()
+    assert sorted(os.listdir(tmp_path)) == [
+        ours, f"step_0000000001.tmp-{os.getpid()}"]
+
+
 def test_checkpoint_retention_and_latest_pointer(tmp_path):
     m = small_model()
     mgr = CheckpointManager(str(tmp_path), keep_last_n=2)

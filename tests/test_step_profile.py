@@ -26,9 +26,7 @@ from flexflow_tpu.obs.step_profile import (
     MEASURED_CAT,
     OVERLAY_FILE,
     HbmSampler,
-    bench_regression_attribution,
     capture_step_profile,
-    load_bench_history,
 )
 from flexflow_tpu.obs.tracer import (
     Tracer,
@@ -302,68 +300,8 @@ def test_counter_event_validation():
 
 
 # ----------------------------------------------------------------------
-# bench history + regression attribution
+# CLI
 # ----------------------------------------------------------------------
-def _round(tmp_path, n, value, phases=None, **extra):
-    doc = {"n": n, "parsed": {"metric": "transformer_train_throughput",
-                              "value": value, "unit": "samples/s/chip",
-                              **extra}}
-    if phases is not None:
-        doc["parsed"]["phases_s_per_step"] = phases
-    with open(tmp_path / f"BENCH_r{n:02d}.json", "w") as f:
-        json.dump(doc, f)
-
-
-def test_load_bench_history_tolerates_old_rounds(tmp_path):
-    _round(tmp_path, 1, 100.0)  # old round: no phases/n_chips/backend
-    _round(tmp_path, 2, 110.0, phases={"fwd": 0.02, "bwd": 0.04,
-                                       "opt": 0.002, "sync": 0.001},
-           n_chips=1, backend="tpu", jax_version="0.4.37")
-    hist = load_bench_history(str(tmp_path))
-    assert [r["round"] for r in hist] == [1, 2]
-    assert hist[0]["phases"] is None and hist[0]["n_chips"] is None
-    assert hist[1]["phases"]["fwd"] == 0.02
-    assert hist[1]["backend"] == "tpu"
-
-
-def test_bench_regression_attribution(tmp_path):
-    _round(tmp_path, 1, 100.0, phases={"fwd": 0.020, "bwd": 0.040,
-                                       "opt": 0.002, "sync": 0.001})
-    _round(tmp_path, 2, 80.0, phases={"fwd": 0.032, "bwd": 0.041,
-                                      "opt": 0.002, "sync": 0.001})
-    att = bench_regression_attribution(load_bench_history(str(tmp_path)),
-                                       tolerance=0.05)
-    assert att["status"] == "ok"
-    assert att["regressed"]
-    assert att["throughput_ratio"] == pytest.approx(0.8)
-    assert att["dominant_phase"] == "fwd"
-    fwd = att["phases"]["fwd"]
-    assert fwd["delta_s"] == pytest.approx(0.012)
-    assert fwd["share_of_regression"] > 0.9
-
-
-def test_bench_regression_attribution_insufficient(tmp_path):
-    _round(tmp_path, 1, 100.0)
-    att = bench_regression_attribution(load_bench_history(str(tmp_path)))
-    assert att["status"] == "insufficient_history"
-
-
-# ----------------------------------------------------------------------
-# CLI + gate script
-# ----------------------------------------------------------------------
-def test_cli_bench_subcommand(tmp_path):
-    _round(tmp_path, 1, 100.0, phases={"fwd": 0.02, "bwd": 0.04,
-                                       "opt": 0.002, "sync": 0.001})
-    _round(tmp_path, 2, 90.0, phases={"fwd": 0.025, "bwd": 0.04,
-                                      "opt": 0.002, "sync": 0.001})
-    r = subprocess.run(
-        [sys.executable, "-m", "flexflow_tpu.obs", "bench",
-         "--src", str(tmp_path), "--tolerance", "0.05", "--strict"],
-        capture_output=True, text=True, env=os.environ.copy(), timeout=300)
-    assert r.returncode == 1, r.stdout + r.stderr  # regressed + --strict
-    assert "dominant phase: fwd" in r.stdout
-
-
 def test_cli_summary_reports_step_observatory(tmp_path):
     m = small_model()
     x, y = data()
@@ -379,34 +317,6 @@ def test_cli_summary_reports_step_observatory(tmp_path):
     assert "step observatory" in r.stdout
     assert "overlap realization" in r.stdout
     assert "measured-vs-simulated drift" in r.stdout
-
-
-def test_bench_regression_script_phase_gate(tmp_path):
-    _round(tmp_path, 6, 480.0, phases={"fwd": 0.020, "bwd": 0.040,
-                                       "opt": 0.002, "sync": 0.001})
-    line = json.dumps({"metric": "transformer_train_throughput",
-                       "value": 470.0, "unit": "samples/s/chip",
-                       "phases_s_per_step": {"fwd": 0.026, "bwd": 0.041,
-                                             "opt": 0.002, "sync": 0.001}})
-    script = os.path.join(REPO, "scripts", "bench_regression.py")
-    r = subprocess.run(
-        [sys.executable, script, "-", "--history-dir", str(tmp_path)],
-        input=line, capture_output=True, text=True,
-        env=os.environ.copy(), timeout=300)
-    assert r.returncode == 1, r.stdout + r.stderr  # fwd +30% > 15%
-    assert "phase fwd" in r.stdout
-    r2 = subprocess.run(
-        [sys.executable, script, "-", "--history-dir", str(tmp_path),
-         "--warn-only"],
-        input=line, capture_output=True, text=True,
-        env=os.environ.copy(), timeout=300)
-    assert r2.returncode == 0, r2.stdout + r2.stderr
-    r3 = subprocess.run(
-        [sys.executable, script, "-", "--history-dir", str(tmp_path),
-         "--phase-tolerance", "fwd=0.5"],
-        input=line, capture_output=True, text=True,
-        env=os.environ.copy(), timeout=300)
-    assert r3.returncode == 0, r3.stdout + r3.stderr
 
 
 # ----------------------------------------------------------------------
