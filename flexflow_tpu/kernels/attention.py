@@ -10,9 +10,13 @@ Three tiers:
   * chunked_attention — lax.scan over KV chunks with running (max, sum,
     acc): O(seq) memory, jax-differentiable, what XLA fuses well. Default
     for long sequences on any backend.
-  * flash_attention  — Pallas TPU kernel for the forward (blocked QK^T on
-    the MXU, VMEM-resident accumulators), custom_vjp whose backward reuses
-    chunked_attention's VJP (same math, exact gradients).
+  * flash_attention  — two Pallas TPU kernels under one custom_vjp:
+    `ff_flash_fwd` (scores, row softmax and p @ v in VMEM, the row's
+    log-sum-exp saved) and `ff_flash_bwd` (_flash_bwd_kernel: dq, dk and
+    dv in one program, the probabilities recomputed from the saved
+    log-sum-exp). Both walk the (seq_q, seq_k) score matrix in blocks; a
+    causal call never computes a block that lies wholly above the
+    diagonal, and masks only the blocks the diagonal crosses.
   * ring_attention   — shard_map over a seq-sharded mesh axis: each step
     computes a partial-attention block against the resident KV shard, then
     ppermutes KV around the ring (compute/ICI overlap is XLA's job);
@@ -25,7 +29,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 
 import jax
 import jax.numpy as jnp
@@ -174,14 +177,16 @@ def attention_dropout_mask(seeds, rate: float, bh: int, sq: int, sk: int):
     return _keep_bits(idx, s0, s1) >= jnp.uint32(_drop_threshold(rate))
 
 
-def _keep_tile(seed_ref, row_u, sq: int, sk: int, kv_off, tile_q: int,
-               tile_k: int, rate: float):
+def _keep_tile(seed_ref, row_u, sq: int, sk: int, q_off: int, kv_off: int,
+               tile_q: int, tile_k: int, rate: float):
     """In-kernel keep-mask for one (tile_q, tile_k) score tile of row
-    `row_u` (uint32 scalar), with the kv axis offset by `kv_off` — the
-    blockwise view of attention_dropout_mask."""
+    `row_u` (uint32 scalar) whose first element is query `q_off`, key
+    `kv_off` — the blockwise view of attention_dropout_mask."""
     s0 = seed_ref[0]
     s1 = seed_ref[1]
-    qp = lax.broadcasted_iota(jnp.uint32, (tile_q, tile_k), 0)
+    qp = jnp.uint32(q_off) + lax.broadcasted_iota(
+        jnp.uint32, (tile_q, tile_k), 0
+    )
     kp = jnp.uint32(kv_off) + lax.broadcasted_iota(
         jnp.uint32, (tile_q, tile_k), 1
     )
@@ -190,27 +195,164 @@ def _keep_tile(seed_ref, row_u, sq: int, sk: int, kv_off, tile_q: int,
 
 
 # ---------------------------------------------------------------------------
+# The block walk both flash kernels share
+# ---------------------------------------------------------------------------
+# The (seq_q, seq_k) score matrix of one (batch*head) row is cut into
+# (block_q, block_k) tiles. Under the causal mask, which is top-left
+# aligned (kv_pos <= q_pos) whatever the two lengths are, a tile is
+#   "skipped"  every pair masked: its probabilities are exp(-1e30 - m) == 0
+#              exactly, so it is never computed: no dot, no exp, no mask;
+#   "masked"   the diagonal crosses it: computed, and masked;
+#   "full"     every pair unmasked: computed, and not masked.
+# Shapes are static, so the walk is a Python loop unrolled at trace time.
+
+def _axis_blocks(n: int, block: int):
+    """[(start, stop)] of an axis of n positions cut every `block`; the
+    last may be short."""
+    return [(s, min(s + block, n)) for s in range(0, n, block)]
+
+
+def _tile_state(q0: int, q1: int, k0: int, k1: int, causal: bool) -> str:
+    """How the mask meets the tile of queries [q0, q1) and keys [k0, k1)."""
+    if not causal or k1 - 1 <= q0:
+        return "full"
+    if k0 > q1 - 1:
+        return "skipped"
+    return "masked"
+
+
+def flash_tile_counts(seq_q: int, seq_k: int, block_q: int, block_k: int,
+                      causal: bool):
+    """(computed, skipped) tiles of one row: what ff_flash_tiles_total
+    counts for each kernel built."""
+    states = [_tile_state(*qb, *kb, causal)
+              for qb in _axis_blocks(seq_q, block_q)
+              for kb in _axis_blocks(seq_k, block_k)]
+    skipped = states.count("skipped")
+    return len(states) - skipped, skipped
+
+
+def _walk(outer, inner, state_of):
+    """[(outer block, reach)]: what each block of the outer axis computes
+    along the inner one. Along either axis the states run full.. masked..
+    skipped (or the reverse), so the computed blocks are one stretch:
+    reach = (lo, hi, mask_lo, mask_hi), one dot over inner positions
+    [lo, hi) of which [mask_lo, mask_hi) needs the mask, or None where
+    the outer block computes nothing."""
+    plan = []
+    for ob in outer:
+        states = [(ib, state_of(ob, ib)) for ib in inner]
+        seen = [ib for ib, st in states if st != "skipped"]
+        masked = [ib for ib, st in states if st == "masked"]
+        if not seen:
+            plan.append((ob, None))
+            continue
+        lo, hi = seen[0][0], seen[-1][1]
+        mask = (masked[0][0], masked[-1][1]) if masked else (hi, hi)
+        plan.append((ob, (lo, hi) + mask))
+    return plan
+
+
+def _default_blocks(seq_q: int, seq_k: int, causal: bool):
+    """(block_q, block_k) from the shape. A non-causal call has nothing
+    to skip and keeps one block a row."""
+    if not causal:
+        return seq_q, seq_k
+    block = 128
+    return (block if seq_q % block == 0 else seq_q,
+            block if seq_k % block == 0 else seq_k)
+
+
+def _resolve_blocks(which: str, seq_q, seq_k, causal, block_q, block_k):
+    """(block_q, block_k): the caller's, else from the shape; counted in
+    ff_flash_tiles_total{pass=which}, a Python side effect where a kernel
+    goes into a program: once a trace and never an execution (as
+    executor._count_trace)."""
+    from .. import obs
+
+    from_shape = _default_blocks(seq_q, seq_k, causal)
+    block_q, block_k = block_q or from_shape[0], block_k or from_shape[1]
+    counts = flash_tile_counts(seq_q, seq_k, block_q, block_k, causal)
+    for state, n in zip(("computed", "skipped"), counts):
+        obs.count("ff_flash_tiles_total", n,
+                  help="(query block, key block) tiles of one row program "
+                       "of a flash kernel, as it was built",
+                  **{"pass": which, "state": state})
+    return block_q, block_k
+
+
+# ---------------------------------------------------------------------------
 # Pallas flash-attention forward
 # ---------------------------------------------------------------------------
 
-def _causal_mask(s, *, q_axis: int, kv_axis: int, kv_offset=0):
-    """Apply the causal mask to a score tile; used (axis-swapped) by the
-    forward, dq, and dkv kernels so they can never disagree."""
-    q_pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
-    kv_pos = kv_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, kv_axis)
+def _causal_mask(s, *, q_offset: int, kv_offset: int):
+    """Apply the causal mask to the score tile whose first element is
+    (q_offset, kv_offset); forward and backward share it so they can
+    never disagree."""
+    q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    kv_pos = kv_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     return jnp.where(kv_pos <= q_pos, s, NEG_INF)
 
 
-def _flash_fwd_kernel(*refs, causal: bool, scale: float, g: int,
+def _mask_stretch(s, axis: int, reach, other_offset: int):
+    """Mask the part [mask_lo, mask_hi) of the score tile `s`, which
+    spans [lo, hi) along `axis` (0: queries, 1: keys) and starts at
+    `other_offset` along the other: only the blocks the diagonal crosses
+    pay the iota / compare / select."""
+    lo, hi, mask_lo, mask_hi = reach
+    parts = []
+    for a, b, masked in ((lo, mask_lo, False), (mask_lo, mask_hi, True),
+                         (mask_hi, hi, False)):
+        if a == b:
+            continue
+        part = lax.slice_in_dim(s, a - lo, b - lo, axis=axis)
+        if masked:
+            q_offset, kv_offset = ((a, other_offset) if axis == 0
+                                   else (other_offset, a))
+            part = _causal_mask(part, q_offset=q_offset,
+                                kv_offset=kv_offset)
+        parts.append(part)
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis)
+
+
+def _staged(items, first, second):
+    """second(item, first(item)) for every item, the next item's first
+    stage issued before this item's second: program order is what the
+    scheduler starts from, and so one block's dots run on the MXU under
+    the previous block's softmax (measured on the compiled schedule:
+    15-20% fewer bundles than block after block)."""
+    held = None
+    for it in items:
+        got = first(it)
+        if held is not None:
+            second(*held)
+        held = (it, got)
+    if held is not None:
+        second(*held)
+
+
+def _for_each_row(g: int, body):
+    """body(i) for the g (batch*head) rows of one program, as a loop and
+    not unrolled: several rows a program amortize the per-program
+    overhead, and one row's code keeps the Mosaic compile at one row's
+    (unrolled, 4 rows took 6 times as long to compile, once a layer)."""
+    if g == 1:
+        body(0)
+    else:
+        lax.fori_loop(0, g, lambda i, carry: (body(i), carry)[1], 0)
+
+
+def _flash_fwd_kernel(*refs, scale: float, g: int, plan,
                       dropout: float = 0.0):
-    """One program = g (batch*head) rows (g unrolled — measured 206→131 us
-    at the bench shape by amortizing per-program overhead). Q/K/V for the
-    whole row are VMEM resident (the fused path is capped to shapes where
-    that holds), so each score tile is ONE MXU dot followed by a row
-    softmax — no online accumulation. Dots take the inputs' dtype (bf16
-    on the mixed-precision path = native MXU rate) and accumulate f32;
-    scores/probs never touch HBM, which is what makes this beat the XLA
-    dense path (134 MB of f32 scores per layer at the bench shape).
+    """One program = g (batch*head) rows (_for_each_row: they amortize
+    the per-program overhead). Q/K/V of the whole row are VMEM resident (the
+    fused path is capped to shapes where that holds). `plan` gives each
+    query block the keys it attends, 0 .. the diagonal: ONE MXU dot whose
+    width grows with the block, the mask on the blocks the diagonal
+    crosses, then a row softmax — no online accumulation, and nothing at
+    all for the keys above the diagonal. Dots take the inputs' dtype
+    (bf16 on the mixed-precision path = native MXU rate) and accumulate
+    f32; scores/probs never touch HBM.
 
     dropout > 0 threads the counter-based keep-mask (_keep_tile) into the
     prob tile after the softmax statistics: l and the saved lse stay
@@ -223,117 +365,137 @@ def _flash_fwd_kernel(*refs, causal: bool, scale: float, g: int,
         q_ref, k_ref, v_ref, o_ref, lse_ref = refs
         seed_ref = None
     inv_keep = 1.0 / (1.0 - dropout) if dropout > 0.0 else 1.0
-    for i in range(g):
-        q = q_ref[i]                      # (seq_q, d), input dtype
-        k = k_ref[i]                      # (seq_k, d)
-        sq, sk = q.shape[0], k.shape[0]
+    sq, sk = q_ref.shape[1], k_ref.shape[1]
+    first_row = pl.program_id(0) * g      # read outside the row loop
+
+    def scores(i, step):
+        (q0, q1), reach = step
+        k0, k1 = reach[:2]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q_ref[i, q0:q1], k_ref[i, k0:k1], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale                         # (seq_q, seq_k) f32
-        if causal:
-            s = _causal_mask(s, q_axis=0, kv_axis=1)
+        ) * scale                         # (block_q, k1 - k0) f32
+        return _mask_stretch(s, 1, reach, q0)
+
+    def finish(i, step, s):
+        (q0, q1), reach = step
+        k0, k1 = reach[:2]
         m = jnp.max(s, axis=-1, keepdims=True)
         p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=-1, keepdims=True)
+        l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
         if dropout > 0.0:
-            row_u = (pl.program_id(0) * g + i).astype(jnp.uint32)
-            keep = _keep_tile(seed_ref, row_u, sq, sk, 0, sq, sk, dropout)
+            row_u = (first_row + i).astype(jnp.uint32)
+            keep = _keep_tile(seed_ref, row_u, sq, sk, q0, k0, q1 - q0,
+                              k1 - k0, dropout)
             p = jnp.where(keep, p * inv_keep, 0.0)
-        o = jnp.dot(p.astype(q.dtype), v_ref[i],
+        o = jnp.dot(p.astype(q_ref.dtype), v_ref[i, k0:k1],
                     preferred_element_type=jnp.float32)
-        o_ref[i] = (o / jnp.maximum(l, 1e-30).astype(jnp.float32)).astype(
-            o_ref.dtype
-        )
+        o_ref[i, q0:q1] = (o / l).astype(o_ref.dtype)
         # log-sum-exp per query row, the backward's softmax residual;
         # stored (1, seq_q) — lanes-major, so the block shape (g, 1,
         # seq_q) satisfies the Mosaic (sublane, lane) tiling rule
-        lse_ref[i] = (m + jnp.log(jnp.maximum(l, 1e-30))).T
+        lse_ref[i, :, q0:q1] = (m + jnp.log(l)).T
+
+    # every query row sees key 0, so no query block's reach is None
+    _for_each_row(g, lambda i: _staged(plan, functools.partial(scores, i),
+                                       functools.partial(finish, i)))
 
 
-def _flash_bwd_kernel(*refs, causal: bool, scale: float,
-                      g: int, bk: int, dropout: float = 0.0):
+def _flash_bwd_kernel(*refs, scale: float, g: int, plan,
+                      dropout: float = 0.0):
     """Fused dq/dk/dv for g (batch*head) rows in ONE program: the prob
-    tile is recomputed from q/k and the saved lse exactly once (the old
-    split dq/dkv kernels each recomputed it), delta = rowsum(do*o) is
-    computed in VMEM, and the transposed contractions for dk/dv avoid
-    materializing pᵀ. Measured 541→306 us fwd+bwd at the bench shape.
+    tile is recomputed from q/k and the saved lse exactly once, delta =
+    rowsum(do*o) is computed in VMEM, and the transposed contractions for
+    dk/dv avoid materializing pᵀ.
 
-    The kv axis is tiled at `bk` (unrolled — shapes are static): only a
-    (seq_q, bk) slab of the score/prob/ds tiles is live at a time, which
-    is what lets g=4 fit VMEM (full seq_k tiles capped g at 2; round-2
-    measured the full-tile g=4 variant REGRESSING on VMEM pressure).
+    `plan` gives each key block the query rows that see it, the diagonal
+    .. seq_q: one set of five dots over that stretch, the mask on the
+    blocks the diagonal crosses. dk and dv of a key block are complete
+    after it and written once; dq gathers a term from every key block, in
+    the float32 scratch `dq_acc`. A key block no query sees (seq_k >
+    seq_q) gets zero gradients and no dot.
 
     dropout > 0 regenerates the forward's counter-based keep-mask per
-    (row, kv-block) — same seeds, same indices, so bit-identical — and
+    tile — same seeds, same absolute (row, q, k), so bit-identical — and
     applies it where the chain rule puts it: dP = D ∘ (dO Vᵀ) before the
     softmax backward, and dV = (P ∘ D)ᵀ dO. delta = rowsum(dO ∘ O)
     already equals rowsum(P ∘ dP) under dropout, so the ds formula is
     unchanged."""
     if dropout > 0.0:
         (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, seed_ref,
-         dq_ref, dk_ref, dv_ref) = refs
+         dq_ref, dk_ref, dv_ref, dq_acc) = refs
     else:
         (q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-         dq_ref, dk_ref, dv_ref) = refs
+         dq_ref, dk_ref, dv_ref, dq_acc) = refs
         seed_ref = None
     inv_keep = 1.0 / (1.0 - dropout) if dropout > 0.0 else 1.0
-    n_blocks = (k_ref.shape[1] + bk - 1) // bk
-    sk_total = k_ref.shape[1]
-    for i in range(g):
-        q = q_ref[i]
-        do = do_ref[i]
+    sq, sk = q_ref.shape[1], k_ref.shape[1]
+    first_row = pl.program_id(0) * g      # read outside the row loop
+    def scores(i, step):
+        (k0, k1), reach = step
+        if reach is None:
+            return None
+        q0, q1 = reach[:2]
+        s = jax.lax.dot_general(
+            q_ref[i, q0:q1], k_ref[i, k0:k1], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                         # (q1 - q0, block_k)
+        s = _mask_stretch(s, 0, reach, k0)
+        dp = jax.lax.dot_general(
+            do_ref[i, q0:q1], v_ref[i, k0:k1], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        return s, dp
+
+    def finish(i, delta, lse_col, step, got):
+        (k0, k1), reach = step
+        if reach is None:
+            dk_ref[i, k0:k1] = jnp.zeros_like(dk_ref[i, k0:k1])
+            dv_ref[i, k0:k1] = jnp.zeros_like(dv_ref[i, k0:k1])
+            return
+        q0, q1 = reach[:2]
+        s, dp = got
+        q = q_ref[i, q0:q1]
+        p = jnp.exp(s - lse_col[q0:q1])
+        if dropout > 0.0:
+            row_u = (first_row + i).astype(jnp.uint32)
+            keep = _keep_tile(seed_ref, row_u, sq, sk, q0, k0, q1 - q0,
+                              k1 - k0, dropout)
+            dp = jnp.where(keep, dp * inv_keep, 0.0)
+            pb = jnp.where(keep, p * inv_keep, 0.0).astype(q.dtype)
+        else:
+            pb = p.astype(q.dtype)
+        dsb = (p * (dp - delta[q0:q1])).astype(q.dtype)
+        dq = jnp.dot(dsb, k_ref[i, k0:k1],
+                     preferred_element_type=jnp.float32)
+        if k0 == 0:
+            # every query sees key 0: the first key block's stretch is
+            # the whole axis, and sets what the later ones add to
+            dq_acc[...] = dq
+        else:
+            dq_acc[q0:q1] += dq
+        dk = jax.lax.dot_general(
+            dsb, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dk_ref[i, k0:k1] = (dk * scale).astype(dk_ref.dtype)
+        dv = jax.lax.dot_general(
+            pb, do_ref[i, q0:q1], (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+        dv_ref[i, k0:k1] = dv.astype(dv_ref.dtype)
+
+    def row(i):
         delta = jnp.sum(
-            do.astype(jnp.float32) * o_ref[i].astype(jnp.float32),
+            do_ref[i].astype(jnp.float32) * o_ref[i].astype(jnp.float32),
             axis=-1, keepdims=True,
         )                                 # (seq_q, 1)
-        lse_col = lse_ref[i].T            # lse (1, seq_q) -> column
-        dq_acc = None
-        for j in range(n_blocks):
-            if causal and j * bk > q_ref.shape[1] - 1:
-                # block entirely above the diagonal: p == 0 exactly —
-                # skip its four dots, just zero the dk/dv slabs
-                dk_ref[i, j * bk:(j + 1) * bk] = jnp.zeros_like(
-                    dk_ref[i, j * bk:(j + 1) * bk])
-                dv_ref[i, j * bk:(j + 1) * bk] = jnp.zeros_like(
-                    dv_ref[i, j * bk:(j + 1) * bk])
-                continue
-            k = k_ref[i, j * bk:(j + 1) * bk]
-            v = v_ref[i, j * bk:(j + 1) * bk]
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                     # (seq_q, bk)
-            if causal:
-                s = _causal_mask(s, q_axis=0, kv_axis=1, kv_offset=j * bk)
-            p = jnp.exp(s - lse_col)
-            dp = jax.lax.dot_general(
-                do, v, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            if dropout > 0.0:
-                row_u = (pl.program_id(0) * g + i).astype(jnp.uint32)
-                keep = _keep_tile(seed_ref, row_u, q.shape[0], sk_total,
-                                  j * bk, q.shape[0], k.shape[0], dropout)
-                dp = jnp.where(keep, dp * inv_keep, 0.0)
-                pb = jnp.where(keep, p * inv_keep, 0.0).astype(q.dtype)
-            else:
-                pb = p.astype(q.dtype)
-            ds = p * (dp - delta)
-            dsb = ds.astype(q.dtype)
-            dq = jnp.dot(dsb, k, preferred_element_type=jnp.float32)
-            dq_acc = dq if dq_acc is None else dq_acc + dq
-            dk = jax.lax.dot_general(
-                dsb, q, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dk_ref[i, j * bk:(j + 1) * bk] = (dk * scale).astype(dk_ref.dtype)
-            dv = jax.lax.dot_general(
-                pb, do, (((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )
-            dv_ref[i, j * bk:(j + 1) * bk] = dv.astype(dv_ref.dtype)
-        dq_ref[i] = (dq_acc * scale).astype(dq_ref.dtype)
+        lse_col = lse_ref[i].T            # stored (1, seq_q), lanes-major
+        _staged(plan, functools.partial(scores, i),
+                functools.partial(finish, i, delta, lse_col))
+        dq_ref[i] = (dq_acc[...] * scale).astype(dq_ref.dtype)
+
+    _for_each_row(g, row)
 
 
 def _bhsd_to_fold(x):
@@ -346,9 +508,10 @@ def _fold_to_bhsd(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-# The fused path keeps the full (seq_q, seq_k) f32 score tile plus Q/K/V
-# in VMEM per program; past this limit fall back to chunked_attention
-# (long-context single-chip) or ring attention (sequence-parallel).
+# The fused path keeps Q/K/V of a whole row in VMEM per program, and a
+# non-causal call its full (seq_q, seq_k) f32 score tile; past this limit
+# fall back to chunked_attention (long-context single-chip) or ring
+# attention (sequence-parallel).
 FLASH_FUSED_MAX_TILE = 1024 * 1024
 
 
@@ -356,29 +519,43 @@ def flash_supported(seq_q: int, seq_k: int) -> bool:
     return seq_q * seq_k <= FLASH_FUSED_MAX_TILE
 
 
-def _pick_g(bh: int, sq: int, sk: int, budget: int, cap: int) -> int:
-    """Rows per program: batch (b*h) rows until the f32 score tiles hit
-    the VMEM budget (floats) or the measured sweet spot `cap`. Measured on
-    v5e at 512x512/d64: fwd best at g=4, fused bwd (4 extra tiles live)
-    at g=2; g=8 regresses — VMEM pressure beats overhead amortization."""
+def _pick_g(bh: int, tile: int, budget: int) -> int:
+    """Rows per program: batch (b*h) rows until the f32 score tiles live
+    at once (`tile` floats: the widest block of the plan) hit the VMEM
+    budget (floats), 4 at the most. At 1,024 positions 1, 2, 4 and 8
+    rows a program measured the same on a v5e; shorter rows have more
+    per-program overhead to share."""
     g = 1
-    for cand in (2, 4, 8):
-        if cand > cap or bh % cand or cand * sq * sk > budget:
+    for cand in (2, 4):
+        if bh % cand or cand * tile > budget:
             break
         g = cand
     return g
 
 
-def _flash_fwd_folded(qf, kf, vf, *, causal: bool, interpret: bool,
-                      dropout: float = 0.0, seeds=None):
-    """Core forward on (b*h, s, d) folded operands."""
+# The two calls are jitted so that the layers of a model, which call them
+# on one shape, share ONE traced kernel: walking the blocks in Python for
+# each of 24 layers, forward and backward, in each build of the train step
+# cost 10 s of set-up that no compile cache gives back. Inlined, so the
+# program XLA gets is the one it would get without the jit.
+_STATIC = ("causal", "interpret", "dropout", "block_q", "block_k")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
+def _flash_fwd_call(qf, kf, vf, seeds, *, causal: bool, interpret: bool,
+                    dropout: float, block_q: int, block_k: int):
     bh, sq, d = qf.shape
     sk = kf.shape[1]
     dv = vf.shape[-1]                 # v_head_dim may differ from qk's d
-    g = _pick_g(bh, sq, sk, budget=2 * 1024 * 1024, cap=4)
+    plan = _walk(_axis_blocks(sq, block_q), _axis_blocks(sk, block_k),
+                 lambda qb, kb: _tile_state(*qb, *kb, causal))
+    # live at once: a query block's scores against every key it attends
+    tile = max((q1 - q0) * (reach[1] - reach[0])
+               for (q0, q1), reach in plan)
+    g = _pick_g(bh, tile, budget=2 * 1024 * 1024)
     scale = 1.0 / math.sqrt(d)
-    kernel = functools.partial(_flash_fwd_kernel, causal=causal, scale=scale,
-                               g=g, dropout=dropout)
+    kernel = functools.partial(_flash_fwd_kernel, scale=scale, g=g,
+                               plan=plan, dropout=dropout)
     in_specs = [
         pl.BlockSpec((g, sq, d), lambda i: (i, 0, 0)),
         pl.BlockSpec((g, sk, d), lambda i: (i, 0, 0)),
@@ -408,26 +585,30 @@ def _flash_fwd_folded(qf, kf, vf, *, causal: bool, interpret: bool,
     return out, lse
 
 
-def _flash_bwd_folded(qf, kf, vf, of, lse, dof, *, causal: bool,
-                      interpret: bool, dropout: float = 0.0, seeds=None):
-    """Core backward on (b*h, s, d) folded operands."""
+def _flash_fwd_folded(qf, kf, vf, *, causal: bool, interpret: bool,
+                      dropout: float = 0.0, seeds=None, block_q=None,
+                      block_k=None):
+    """Core forward on (b*h, s, d) folded operands."""
+    block_q, block_k = _resolve_blocks("fwd", qf.shape[1], kf.shape[1],
+                                       causal, block_q, block_k)
+    return _flash_fwd_call(qf, kf, vf, seeds, causal=causal,
+                           interpret=interpret, dropout=dropout,
+                           block_q=block_q, block_k=block_k)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
+def _flash_bwd_call(qf, kf, vf, of, lse, dof, seeds, *, causal: bool,
+                    interpret: bool, dropout: float, block_q: int,
+                    block_k: int):
     bh, sq, d = qf.shape
     sk = kf.shape[1]
     dv_d = vf.shape[-1]               # v_head_dim may differ from qk's d
-    # Default: FULL kv tile at g=2. The kv-blocked variant (bk < sk, which
-    # halves live VMEM and admits g=4) was the round-2 verdict's suggested
-    # retry; a round-3 sweep on a v5e found the full-tile g=2 schedule
-    # the fastest, so blocking ships as an env-tunable (FF_FLASH_BWD_BK /
-    # FF_FLASH_BWD_G, 0 = auto) rather than the default (ROADMAP C4: no
-    # cell has measured it since).
-    bk = int(os.environ.get("FF_FLASH_BWD_BK", "0")) or sk
-    if bk <= 0 or bk > sk:
-        bk = sk
-    gg = int(os.environ.get("FF_FLASH_BWD_G", "0"))
-    if gg <= 0 or bh % gg:
-        # invalid override (non-divisor g would truncate the grid and leave
-        # gradient rows unwritten) -> auto
-        gg = _pick_g(bh, sq, bk, budget=1024 * 1024, cap=2)
+    plan = _walk(_axis_blocks(sk, block_k), _axis_blocks(sq, block_q),
+                 lambda kb, qb: _tile_state(*qb, *kb, causal))
+    # live at once: the s, p, dp and ds tiles of one key block
+    tile = 4 * max((k1 - k0) * (reach[1] - reach[0])
+                   for (k0, k1), reach in plan if reach)
+    gg = _pick_g(bh, tile, budget=4 * 1024 * 1024)
     scale = 1.0 / math.sqrt(d)
     in_specs = [
         pl.BlockSpec((gg, sq, d), lambda i: (i, 0, 0)),
@@ -442,8 +623,8 @@ def _flash_bwd_folded(qf, kf, vf, of, lse, dof, *, causal: bool,
         in_specs.append(pl.BlockSpec(memory_space=pltpu.SMEM))
         args = args + (jnp.asarray(seeds, jnp.uint32),)
     dq, dk, dv = pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, causal=causal, scale=scale,
-                          g=gg, bk=bk, dropout=dropout),
+        functools.partial(_flash_bwd_kernel, scale=scale, g=gg, plan=plan,
+                          dropout=dropout),
         grid=(bh // gg,),
         in_specs=in_specs,
         out_specs=[
@@ -456,32 +637,50 @@ def _flash_bwd_folded(qf, kf, vf, of, lse, dof, *, causal: bool,
             out_struct((bh, sk, d), kf.dtype, qf),
             out_struct((bh, sk, dv_d), vf.dtype, qf),
         ],
+        scratch_shapes=[pltpu.VMEM((sq, d), jnp.float32)],
         interpret=interpret,
         name="ff_flash_bwd",
     )(*args)
     return dq, dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
-def _flash_folded_core(qf, kf, vf, seeds, causal, interpret, dropout):
+def _flash_bwd_folded(qf, kf, vf, of, lse, dof, *, causal: bool,
+                      interpret: bool, dropout: float = 0.0, seeds=None,
+                      block_q=None, block_k=None):
+    """Core backward on (b*h, s, d) folded operands."""
+    block_q, block_k = _resolve_blocks("bwd", qf.shape[1], kf.shape[1],
+                                       causal, block_q, block_k)
+    return _flash_bwd_call(qf, kf, vf, of, lse, dof, seeds, causal=causal,
+                           interpret=interpret, dropout=dropout,
+                           block_q=block_q, block_k=block_k)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_folded_core(qf, kf, vf, seeds, causal, interpret, dropout,
+                       block_q, block_k):
     out, _ = _flash_fwd_folded(qf, kf, vf, causal=causal,
                                interpret=interpret, dropout=dropout,
-                               seeds=seeds)
+                               seeds=seeds, block_q=block_q,
+                               block_k=block_k)
     return out
 
 
-def _flash_folded_vjp_fwd(qf, kf, vf, seeds, causal, interpret, dropout):
+def _flash_folded_vjp_fwd(qf, kf, vf, seeds, causal, interpret, dropout,
+                          block_q, block_k):
     out, lse = _flash_fwd_folded(qf, kf, vf, causal=causal,
                                  interpret=interpret, dropout=dropout,
-                                 seeds=seeds)
+                                 seeds=seeds, block_q=block_q,
+                                 block_k=block_k)
     return out, (qf, kf, vf, out, lse, seeds)
 
 
-def _flash_folded_vjp_bwd(causal, interpret, dropout, res, g):
+def _flash_folded_vjp_bwd(causal, interpret, dropout, block_q, block_k,
+                          res, g):
     qf, kf, vf, out, lse, seeds = res
     dq, dk, dv = _flash_bwd_folded(qf, kf, vf, out, lse, g, causal=causal,
                                    interpret=interpret, dropout=dropout,
-                                   seeds=seeds)
+                                   seeds=seeds, block_q=block_q,
+                                   block_k=block_k)
     return dq, dk, dv, None  # seeds are integral: no cotangent
 
 
@@ -490,11 +689,17 @@ _flash_folded_core.defvjp(_flash_folded_vjp_fwd, _flash_folded_vjp_bwd)
 
 def flash_attention_folded(qf, kf, vf, causal: bool = False,
                            interpret: bool = False, *,
-                           dropout: float = 0.0, seeds=None):
+                           dropout: float = 0.0, seeds=None,
+                           block_q: int | None = None,
+                           block_k: int | None = None):
     """flash_attention on PRE-FOLDED (batch*heads, seq, head_dim)
     operands. The MHA op's fast path projects q/k/v straight into this
     layout (einsum "bse,ehd->bhsd" + free reshape), so the per-layer
     fold/unfold transposes of the bshd wrapper never materialize.
+
+    block_q / block_k cut the score matrix into the tiles both kernels
+    walk (None: chosen from the shape, _default_blocks); a causal call
+    computes no tile above the diagonal.
 
     dropout/seeds thread attention dropout INTO the kernels: the
     counter-based keep-mask (attention_dropout_mask with these `seeds`,
@@ -510,24 +715,26 @@ def flash_attention_folded(qf, kf, vf, causal: bool = False,
         raise ValueError("flash dropout needs seeds (dropout_seeds(rng))")
     if seeds is None:
         seeds = jnp.zeros((2,), jnp.uint32)
-    return _flash_folded_core(qf, kf, vf, seeds, causal, interpret, dropout)
+    return _flash_folded_core(qf, kf, vf, seeds, causal, interpret, dropout,
+                              block_q, block_k)
 
 
-def flash_attention(q, k, v, causal: bool = False, block_q: int = 256,
-                    block_k: int = 256, interpret: bool = False, *,
+def flash_attention(q, k, v, causal: bool = False,
+                    block_q: int | None = None, block_k: int | None = None,
+                    interpret: bool = False, *,
                     dropout: float = 0.0, seeds=None):
     """Fused Pallas attention: forward AND backward keep scores/probs in
     VMEM (the backward recomputes the prob tile from the saved per-row
     log-sum-exp — the standard flash-attention scheme) and batch several
     (batch*head) rows per program (_pick_g). Requires
-    flash_supported(seq_q, seq_k); block_q/block_k are accepted for
-    signature stability but rows are processed as whole tiles. Routes
-    through the folded core, so gradients and RNG-threaded dropout
-    (dropout/seeds) behave identically to flash_attention_folded."""
+    flash_supported(seq_q, seq_k). Routes through the folded core, so
+    block sizes, gradients and RNG-threaded dropout (dropout/seeds)
+    behave identically to flash_attention_folded."""
     b, _, h, _ = q.shape
     out = flash_attention_folded(
         _bhsd_to_fold(q), _bhsd_to_fold(k), _bhsd_to_fold(v),
         causal=causal, interpret=interpret, dropout=dropout, seeds=seeds,
+        block_q=block_q, block_k=block_k,
     )
     return _fold_to_bhsd(out, b, h)
 

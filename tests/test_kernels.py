@@ -12,6 +12,8 @@ import pytest
 from flexflow_tpu.kernels.attention import (
     chunked_attention,
     flash_attention,
+    flash_attention_folded,
+    flash_tile_counts,
     ring_attention,
 )
 
@@ -116,6 +118,120 @@ def test_flash_pallas_bwd_all_grads(causal):
         gr = jax.grad(ref, argnums=i)(q, k, v)
         np.testing.assert_allclose(np.asarray(go), np.asarray(gr),
                                    atol=2e-4, rtol=1e-3)
+
+
+def _qkv_shapes(sq, sk, d=16, dv=None, b=2, h=2, seed=11):
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(b, s, h, w).astype(np.float32))
+                 for s, w in ((sq, d), (sk, d), (sk, dv or d), (sq, dv or d)))
+
+
+# (seq_q, seq_k, v's width, block_q, block_k): several blocks along both
+# axes, so a causal call has tiles of all three kinds (skipped, masked,
+# full) and every gradient is gathered over more than one tile
+BLOCKED_SHAPES = [
+    (64, 64, 16, 16, 16),     # the square: 6 of 16 tiles above the diagonal
+    (64, 64, 16, 32, 16),     # blocks of two sizes
+    (16, 32, 16, 8, 8),       # more keys than queries: keys 16.. are never seen
+    (32, 16, 16, 8, 8),       # more queries than keys: rows 16.. see every key
+    (32, 32, 24, 8, 16),      # v wider than k
+    (48, 48, 16, 32, 32),     # a short last block
+]
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("sq,sk,dv,bq,bk", BLOCKED_SHAPES)
+def test_flash_blocked_forward_and_grads_match_naive(sq, sk, dv, bq, bk,
+                                                     causal):
+    """The blocked walk (block_q / block_k forced, interpret mode) gives
+    the output and all three gradients of the naive reference, whichever
+    tiles it skips."""
+    q, k, v, g_out = _qkv_shapes(sq, sk, dv=dv)
+
+    def ours(q_, k_, v_):
+        return flash_attention(q_, k_, v_, causal, bq, bk, True)
+
+    def ref(q_, k_, v_):
+        return naive_attention(q_, k_, v_, causal=causal)
+
+    np.testing.assert_allclose(np.asarray(ours(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=1e-5)
+    go = jax.grad(lambda *a: jnp.sum(ours(*a) * g_out), (0, 1, 2))(q, k, v)
+    gr = jax.grad(lambda *a: jnp.sum(ref(*a) * g_out), (0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", go, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4,
+                                   rtol=1e-3, err_msg=f"d{name}")
+
+
+def test_flash_blocks_do_not_change_the_answer():
+    """One algorithm, whatever the blocks: the default walk, one block a
+    row and blocks of 16 agree to float32 rounding (they differ only in
+    the order of the sums)."""
+    q, k, v, g_out = _qkv_shapes(64, 64)
+    fold = lambda x: x.transpose(0, 2, 1, 3).reshape(4, 64, 16)
+    qf, kf, vf, gf = fold(q), fold(k), fold(v), fold(g_out)
+
+    def grads(**blocks):
+        return jax.grad(lambda *a: jnp.sum(flash_attention_folded(
+            *a, True, True, **blocks) * gf), (0, 1, 2))(qf, kf, vf)
+
+    base = grads()
+    for blocks in ({"block_q": 64, "block_k": 64},
+                   {"block_q": 16, "block_k": 16}):
+        for a, b in zip(grads(**blocks), base):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-5)
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((1024, 1024, 256, 256, True), (10, 6)),
+    ((1024, 1024, 128, 128, True), (36, 28)),
+    ((1024, 1024, 512, 512, True), (3, 1)),
+    ((1024, 1024, 1024, 1024, False), (1, 0)),   # a non-causal row
+    ((1024, 1024, 256, 256, False), (16, 0)),    # nothing to skip
+    ((16, 32, 8, 8, True), (3, 5)),
+    ((32, 16, 8, 8, True), (7, 1)),
+])
+def test_flash_tile_counts(shape, want):
+    assert flash_tile_counts(*shape) == want
+
+
+@pytest.mark.parametrize("sq,sk,bq,bk", [(16, 32, 8, 8), (32, 16, 8, 8),
+                                          (48, 40, 16, 32), (24, 24, 8, 16)])
+def test_flash_skips_no_tile_that_holds_an_unmasked_pair(sq, sk, bq, bk):
+    """Top-left aligned (kv_pos <= q_pos) whatever the two lengths: a
+    tile is skipped exactly when every pair in it is masked, and goes
+    unmasked exactly when none is."""
+    from flexflow_tpu.kernels.attention import _axis_blocks, _tile_state
+
+    seen = np.tril(np.ones((sq, sk), bool))
+    for q0, q1 in _axis_blocks(sq, bq):
+        for k0, k1 in _axis_blocks(sk, bk):
+            tile = seen[q0:q1, k0:k1]
+            state = _tile_state(q0, q1, k0, k1, True)
+            assert (state == "skipped") == (not tile.any()), (q0, k0)
+            assert (state == "full") == bool(tile.all()), (q0, k0)
+            assert _tile_state(q0, q1, k0, k1, False) == "full"
+
+
+def test_flash_tiles_counter_reads_the_walk(tmp_path):
+    """ff_flash_tiles_total{pass, state} after one traced forward +
+    backward is flash_tile_counts of the blocks, once a kernel built."""
+    from flexflow_tpu import obs
+    from flexflow_tpu.obs import TelemetryConfig
+
+    q, k, v, _ = _qkv_shapes(64, 64)
+    with obs.session(TelemetryConfig(dir=str(tmp_path / "tel"))):
+        jax.grad(lambda q_: jnp.sum(
+            flash_attention(q_, k, v, True, 16, 16, True)))(q)
+        found = obs.active().metrics.find
+        read = {(p, s): found("ff_flash_tiles_total", **{"pass": p,
+                                                         "state": s}).value
+                for p in ("fwd", "bwd") for s in ("computed", "skipped")}
+    computed, skipped = flash_tile_counts(64, 64, 16, 16, True)
+    assert (computed, skipped) == (10, 6)
+    assert read == {("fwd", "computed"): 10.0, ("fwd", "skipped"): 6.0,
+                    ("bwd", "computed"): 10.0, ("bwd", "skipped"): 6.0}
 
 
 @pytest.mark.parametrize("causal", [False, True])

@@ -83,18 +83,64 @@ def test_flash_dropout_backward_matches_dense(causal):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
 
 
-def test_flash_dropout_blocked_backward_matches_dense(monkeypatch):
-    """The kv-blocked backward schedule (FF_FLASH_BWD_BK) must regenerate
-    the same mask per block — offsets, not materialization."""
-    monkeypatch.setenv("FF_FLASH_BWD_BK", "8")
+def test_flash_dropout_blocked_backward_matches_dense():
+    """The blocked backward must regenerate the same mask per tile —
+    offsets, not materialization."""
     qf, kf, vf = _folded_qkv(bh=2, sq=16, sk=32)
     seeds = dropout_seeds(jax.random.PRNGKey(3))
     rate = 0.4
     g1 = jax.grad(lambda k_: jnp.sum(flash_attention_folded(
-        qf, k_, vf, False, True, dropout=rate, seeds=seeds) ** 2))(kf)
+        qf, k_, vf, False, True, dropout=rate, seeds=seeds,
+        block_k=8) ** 2))(kf)
     g2 = jax.grad(lambda k_: jnp.sum(_dense_dropout_ref(
         qf, k_, vf, seeds, rate, False) ** 2))(kf)
     np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), atol=3e-4)
+
+
+@pytest.mark.parametrize("causal", [False, True])
+@pytest.mark.parametrize("sq,sk,bq,bk,rate", [
+    (32, 32, 8, 8, 0.25),     # a query offset and a key offset in every tile
+    (32, 32, 16, 8, 0.3),
+    (16, 32, 8, 16, 0.4),     # seq_q != seq_k
+])
+def test_flash_dropout_blocked_walk_matches_dense(sq, sk, bq, bk, rate,
+                                                  causal):
+    """With the query axis tiled too, forward and backward regenerate
+    attention_dropout_mask's bits at the absolute (row, q, k): output and
+    all three gradients equal the dense path under the same mask."""
+    qf, kf, vf = _folded_qkv(bh=2, sq=sq, sk=sk)
+    seeds = dropout_seeds(jax.random.PRNGKey(11))
+
+    def ours(q_, k_, v_):
+        return flash_attention_folded(q_, k_, v_, causal, True, dropout=rate,
+                                      seeds=seeds, block_q=bq, block_k=bk)
+
+    def ref(q_, k_, v_):
+        return _dense_dropout_ref(q_, k_, v_, seeds, rate, causal)
+
+    np.testing.assert_allclose(np.asarray(ours(qf, kf, vf)),
+                               np.asarray(ref(qf, kf, vf)), atol=2e-5)
+    g1 = jax.grad(lambda *a: jnp.sum(ours(*a) ** 2), (0, 1, 2))(qf, kf, vf)
+    g2 = jax.grad(lambda *a: jnp.sum(ref(*a) ** 2), (0, 1, 2))(qf, kf, vf)
+    for a, b in zip(g1, g2):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=3e-4)
+
+
+def test_flash_ignores_the_old_tile_variables(monkeypatch):
+    """FF_FLASH_BWD_G / FF_FLASH_BWD_BK are read nowhere: the blocks come
+    from the shape or from the keywords, so setting them changes no bit."""
+    qf, kf, vf = _folded_qkv(bh=4, sq=32, sk=32)
+
+    def grads():
+        return jax.grad(lambda *a: jnp.sum(flash_attention_folded(
+            *a, True, True, block_q=16, block_k=16) ** 2),
+            (0, 1, 2))(qf, kf, vf)
+
+    base = grads()
+    monkeypatch.setenv("FF_FLASH_BWD_G", "1")
+    monkeypatch.setenv("FF_FLASH_BWD_BK", "8")
+    for a, b in zip(grads(), base):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_dropout_mask_deterministic_and_rate():

@@ -26,9 +26,17 @@ from flexflow_tpu.kernels.attention import (
 from flexflow_tpu.kernels.decode import paged_flash_decode
 
 
+def _mosaic_operands(fn, *args):
+    """The operand and result types of each Mosaic call in the module
+    exported for the TPU, one string a call."""
+    text = jax.export.export(jax.jit(fn), platforms=["tpu"])(
+        *args).mlir_module()
+    return [line.split(" : ", 1)[-1] for line in text.splitlines()
+            if "@tpu_custom_call" in line]
+
+
 def _mosaic_calls(fn, *args) -> int:
-    exported = jax.export.export(jax.jit(fn), platforms=["tpu"])(*args)
-    return exported.mlir_module().count("tpu_custom_call")
+    return len(_mosaic_operands(fn, *args))
 
 
 def _compile_for_v5e(fn, *args):
@@ -56,18 +64,42 @@ def _fwd_bwd(attn):
     )
 
 
-@pytest.mark.parametrize("bh,seq,dtype,dropout", [
-    (128, 512, jnp.bfloat16, 0.0),   # the train step's shape
-    (128, 512, jnp.bfloat16, 0.1),   # in-kernel dropout: SMEM seeds, u32 hash
-    (16, 1024, jnp.float32, 0.0),    # the largest tile flash_supported admits
-    (16, 1024, jnp.bfloat16, 0.0),
+@pytest.mark.parametrize("bh,seq,dtype,dropout,causal", [
+    (128, 512, jnp.bfloat16, 0.0, True),   # chip_smoke.py's train step
+    (128, 512, jnp.bfloat16, 0.1, True),   # in-kernel dropout: SMEM seeds, u32 hash
+    (16, 1024, jnp.float32, 0.0, True),    # the largest tile flash_supported admits
+    (16, 1024, jnp.bfloat16, 0.0, True),
+    (64, 1024, jnp.bfloat16, 0.0, True),   # the cell train-gpt2m-1chip
+    (64, 1024, jnp.bfloat16, 0.0, False),  # one block a row: nothing to skip
 ])
-def test_flash_forward_backward_lowers_for_tpu(bh, seq, dtype, dropout):
+def test_flash_forward_backward_lowers_for_tpu(bh, seq, dtype, dropout,
+                                               causal):
     x = jax.ShapeDtypeStruct((bh, seq, 64), dtype)
     seeds = jnp.array([1, 2], jnp.uint32) if dropout else None
-    attn = functools.partial(flash_attention_folded, causal=True,
+    attn = functools.partial(flash_attention_folded, causal=causal,
                              dropout=dropout, seeds=seeds)
-    assert _mosaic_calls(_fwd_bwd(attn), x, x, x) == 2
+    calls = _mosaic_operands(_fwd_bwd(attn), x, x, x)
+    assert len(calls) == 2
+    # perfbench's flash_attn_roofline knows the kernels by the folded
+    # operand (harness/trace.py Summary.kernel): both calls work on it
+    folded = "tensor<%dx%dx64x%s>" % (bh, seq, jnp.dtype(dtype).name
+                                      .replace("bfloat", "bf")
+                                      .replace("float", "f"))
+    assert all(folded in c for c in calls), calls
+
+
+@pytest.mark.parametrize("causal", [True, False])
+def test_flash_compiled_calls_keep_the_folded_operand(causal):
+    """The same two kernels through the real TPU compiler, for a
+    described v5e, at the training cell's shape: two Mosaic calls, each
+    with `bf16[64,1024,64]` among its operands in the compiled HLO."""
+    x = jax.ShapeDtypeStruct((64, 1024, 64), jnp.bfloat16)
+    attn = functools.partial(flash_attention_folded, causal=causal)
+    text = _compile_for_v5e(_fwd_bwd(attn), x, x, x).as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 2, calls
+    assert all("bf16[64,1024,64]" in c for c in calls), calls
 
 
 def _paged_decode_args(slots, heads, d, max_len, page, dtype):
