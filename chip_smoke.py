@@ -62,6 +62,12 @@ if REHEARSAL:
     DECODE_LENGTHS = [1, 16, 17, 64]
     # (heads, key size, value size), then (bucket, real tokens) per row
     DELTA_HEADS, DELTA_BLOCKS = (2, 16, 8), [(64, 50), (128, 128), (128, 0)]
+    # grouped-query paged decode: (query heads, key-value heads, head size)
+    GQA_HEADS = (4, 2, 64)
+    # Mamba-2: (heads, head size, groups, state size, chunk), (bucket, real)
+    SSM_SIZES, SSM_BLOCKS = (4, 8, 2, 16, 16), [(32, 20), (8, 8), (48, 33)]
+    # expert bank: (hidden, experts, held, top k, width, shared width), tokens
+    BANK_SIZES, BANK_TOKENS = (32, 8, 4, 2, 24, 48), [4, 50]
 else:
     HIDDEN, HEADS, LAYERS, SEQ, BATCH = 1024, 16, 12, 512, 8
     VOCAB, MAX_LEN, SLOTS = 32000, 1024, 8
@@ -80,6 +86,15 @@ else:
     DELTA_HEADS = (30, 96, 192)
     DELTA_BLOCKS = [(256, 256), (512, 300), (1024, 1000), (2048, 1536),
                     (4096, 3584)]
+    # the state-space, expert and grouped-query serving cell's sizes: 32
+    # query heads over 2 key-value heads of 128 (a pool row of 256 lanes);
+    # 64 Mamba-2 heads of 64 in 8 groups, state 128, chunk 128, the buckets
+    # a prompt of 32..1,024 takes; 64 held of 128 experts of 1,856, top 6,
+    # at a decode step's 64 tokens and at a prefill's 1,024
+    GQA_HEADS = (32, 2, 128)
+    SSM_SIZES = (64, 64, 8, 128, 128)
+    SSM_BLOCKS = [(32, 32), (64, 40), (256, 200), (1024, 777)]
+    BANK_SIZES, BANK_TOKENS = (2688, 128, 64, 6, 1856, 3712), [64, 1024]
 HEAD_DIM = HIDDEN // HEADS
 PAGE = 16
 NEW_TOKENS = 8
@@ -243,6 +258,98 @@ def phase_kernels(ctx):
         log(f"  {name}: rel err state/out = "
             + "/".join(f"{e:.1e}" for e in errs))
         assert all(np.isfinite(e) and e < KERNEL_TOL for e in errs), name
+        ctx["kernels"].append(name)
+
+    # grouped-query heads in the paged kernel: a pool row holds the
+    # key-value heads alone and a query head reads its group's lanes
+    hq, hkv, d = GQA_HEADS
+    q = jnp.asarray(rng.randn(slots, hq, d), jnp.bfloat16)
+    kp, vp = (jnp.asarray(rng.randn(slots * pages_per_slot, PAGE, hkv, d),
+                          jnp.bfloat16) for _ in range(2))
+    out = jax.jit(lambda *a: paged_flash_decode(
+        *a, interpret=interpret))(q, kp, vp, table, lengths)
+    with jax.default_matmul_precision("highest"):
+        want = paged_decode_reference(q, kp, vp, table, lengths)
+    err = rel_err(out, want)
+    name = (f"paged_flash_decode grouped slots={slots} heads={hq} "
+            f"kv_heads={hkv} d{d} max_len={MAX_LEN} page={PAGE} bfloat16")
+    log(f"  {name}: rel err = {err:.1e}")
+    assert np.isfinite(err) and err < KERNEL_TOL, name
+    ctx["kernels"].append(name)
+
+    # the Mamba-2 recurrence (ops/state_space.py): the chunked form on a
+    # bucket whose tail is masked (dt = 0), against the one-token step over
+    # the real tokens alone, from a state that is not zero
+    from flexflow_tpu.ops.state_space import ssd_chunked, ssm_step
+
+    h, p, g, n, chunk = SSM_SIZES
+
+    def ssm_recurrence(S, x, B, C, dt, A):
+        def token(S, c):
+            y, S = ssm_step(S, *c, A)
+            return S, y
+        S, y = jax.lax.scan(token, S, tuple(
+            jnp.moveaxis(a, 1, 0) for a in (x, B, C, dt)))
+        return jnp.moveaxis(y, 0, 1), S
+
+    A = -np.exp(rng.uniform(-1.0, 1.0, h)).astype(np.float32)
+    for bucket, real in SSM_BLOCKS:
+        x = rng.randn(1, bucket, h, p).astype(np.float32)
+        B, C = (rng.randn(1, bucket, g, n).astype(np.float32) / np.sqrt(n)
+                for _ in range(2))
+        live = (np.arange(bucket) < real)[None, :, None]
+        dt = np.where(live, rng.uniform(0.001, 0.1, (1, bucket, h)), 0.0) \
+            .astype(np.float32)
+        S0 = jnp.asarray(0.1 * rng.randn(1, h, p, n), jnp.float32)
+        y, S = jax.jit(ssd_chunked, static_argnums=6)(
+            S0, x, B, C, dt, A, min(chunk, bucket))
+        y_r, S_r = jax.jit(ssm_recurrence)(
+            S0, *(a[:, :real] for a in (x, B, C, dt)), A)
+        errs = [rel_err(S, S_r), rel_err(y[:, :real], y_r)]
+        name = (f"ssd_chunked heads={h} p={p} groups={g} state={n} bucket="
+                f"{bucket} real={real}")
+        log(f"  {name}: rel err state/out = "
+            + "/".join(f"{e:.1e}" for e in errs))
+        assert all(np.isfinite(e) and e < KERNEL_TOL for e in errs), name
+        ctx["kernels"].append(name)
+
+    # the expert bank (ops/moe.py): router, the sorted grouped product over
+    # the held experts and the shared expert, against every held expert
+    # applied to every token under the router's own mask
+    from flexflow_tpu.ff_types import DataType, OperatorType
+    from flexflow_tpu.ops.moe import ExpertBankParams, route
+    from flexflow_tpu.ops.registry import FwdCtx, get_op_def
+
+    e, experts, held, top_k, width, shared = BANK_SIZES
+    bank = ExpertBankParams(experts, 0, held, top_k, width, shared, 2.5)
+    op = get_op_def(OperatorType.OP_EXPERT_BANK)
+    dtype = jnp.float32 if REHEARSAL else jnp.bfloat16
+    w = {spec.name: jnp.asarray(
+        rng.randn(*spec.shape) / np.sqrt(spec.shape[-2] if len(spec.shape) > 1
+                                         else 1e30), dtype)
+         for spec in op.weights(bank, [(1, e)], [DataType.DT_FLOAT])}
+
+    def dense_bank(w, x):
+        with jax.default_matmul_precision("highest"):
+            f32 = {k: v.astype(jnp.float32) for k, v in w.items()}
+            x = x.astype(jnp.float32)
+            ids, gate = route(bank, f32["router"], f32["b_corr"], x)
+            mask = jnp.sum(jax.nn.one_hot(ids, experts) * gate[..., None], 1)
+            act = lambda t: jnp.square(jnp.maximum(t, 0.0))  # noqa: E731
+            y = (jnp.einsum(
+                "ntf,nfe->nte", act(jnp.einsum("te,nef->ntf", x, f32["w_up"])),
+                f32["w_down"]) * mask.T[:held, :, None]).sum(0)
+            return y + act(x @ f32["shared_up"]) @ f32["shared_down"]
+
+    for tokens in BANK_TOKENS:
+        x = jnp.asarray(rng.randn(tokens, e), dtype)
+        (y,) = jax.jit(lambda w, x: op.forward(
+            bank, w, [x], FwdCtx(training=False)))(w, x)
+        err = rel_err(y, jax.jit(dense_bank)(w, x))
+        name = (f"expert_bank hidden={e} held={held}/{experts} top{top_k} "
+                f"width={width} tokens={tokens} {jnp.dtype(dtype).name}")
+        log(f"  {name}: rel err = {err:.1e}")
+        assert np.isfinite(err) and err < KERNEL_TOL, name
         ctx["kernels"].append(name)
 
 

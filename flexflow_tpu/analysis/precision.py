@@ -75,6 +75,7 @@ _ACCUMULATING = frozenset({
     OperatorType.OP_LINEAR, OperatorType.OP_CONV2D,
     OperatorType.OP_BATCHMATMUL, OperatorType.OP_MATMUL,
     OperatorType.OP_MULTIHEAD_ATTENTION, OperatorType.OP_GATED_DELTA_NET,
+    OperatorType.OP_MAMBA2, OperatorType.OP_EXPERT_BANK,
     OperatorType.OP_AGGREGATE,
     OperatorType.OP_AGG_SPEC, OperatorType.OP_REDUCE_SUM,
     OperatorType.OP_REDUCE_MEAN, OperatorType.OP_MEAN,

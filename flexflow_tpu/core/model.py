@@ -368,8 +368,12 @@ class FFModel:
         causal: bool = False,
         qk_norm: bool = False,
         qk_norm_eps: float = 1e-6,
+        num_kv_heads: int = 0,
         name: str = "",
     ) -> Tensor:
+        """`num_kv_heads` (0 = `num_heads`): grouped-query attention, query
+        head i reading key-value head i // (num_heads / num_kv_heads); the
+        decode cache holds the key-value heads alone."""
         p = MultiHeadAttentionParams(
             embed_dim=embed_dim,
             num_heads=num_heads,
@@ -382,6 +386,7 @@ class FFModel:
             causal=causal,
             qk_norm=qk_norm,
             qk_norm_eps=qk_norm_eps,
+            num_kv_heads=0 if num_kv_heads == num_heads else num_kv_heads,
         )
         inits = (
             {k: kernel_initializer for k in ("wq", "wk", "wv", "wo")}
@@ -649,6 +654,53 @@ class FFModel:
         )
         return self._add_layer(OperatorType.OP_GATED_DELTA_NET, p, [input],
                                name, inits)
+
+    def mamba2(self, input: Tensor, num_heads: int, head_dim: int,
+               state_size: int, n_groups: int = 1, conv_kernel: int = 4,
+               chunk_size: int = 128, norm_eps: float = 1e-5,
+               kernel_initializer=None, name="") -> Tensor:
+        """Mamba-2 state-space mixer over (batch, seq, embed): `num_heads`
+        heads of `head_dim` channels, each with a (head_dim x state_size)
+        recurrent state in place of keys and values, B and C shared by
+        `n_groups` groups of heads (ops/state_space.py)."""
+        from ..ops.state_space import Mamba2Params
+
+        p = Mamba2Params(
+            embed_dim=input.dims[-1], num_heads=num_heads, head_dim=head_dim,
+            state_size=state_size, n_groups=n_groups, conv_kernel=conv_kernel,
+            chunk_size=chunk_size, norm_eps=norm_eps)
+        inits = (
+            {k: kernel_initializer for k in ("w_in", "w_out", "conv")}
+            if kernel_initializer else None
+        )
+        return self._add_layer(OperatorType.OP_MAMBA2, p, [input], name,
+                               inits)
+
+    def expert_bank(self, input: Tensor, experts: int, top_k: int, width: int,
+                    held=None, shared_width: int = 0, scale: float = 1.0,
+                    norm_topk: bool = True, act="relu2",
+                    kernel_initializer=None, name="") -> Tensor:
+        """One expert layer as ONE op: a sigmoid router over all `experts`
+        (top `top_k` a token, no capacity, no dropped token), the routed
+        experts `held` = (first, one past the last) that live on this chip
+        (None: all of them), and a shared expert
+        of `shared_width` (0: none). What the experts elsewhere would add is
+        left out: expert parallelism's share of the layer, without its
+        exchange (ops/moe.py)."""
+        from ..ops.moe import ExpertBankParams
+
+        lo, hi = held if held is not None else (0, experts)
+        p = ExpertBankParams(
+            experts=experts, held_from=lo, held_count=hi - lo, top_k=top_k,
+            width=width, shared_width=shared_width, scale=scale,
+            norm_topk=norm_topk, activation=_to_acti(act))
+        inits = (
+            {k: kernel_initializer for k in
+             ("router", "w_up", "w_down", "shared_up", "shared_down")}
+            if kernel_initializer else None
+        )
+        return self._add_layer(OperatorType.OP_EXPERT_BANK, p, [input], name,
+                               inits)
 
     # MoE family (reference: moe.cc:20-44 FFModel::moe composite)
     def group_by(self, input: Tensor, assign: Tensor, n: int, alpha: float, name=""):
@@ -3226,4 +3278,5 @@ def _to_acti(a) -> ActiMode:
         "tanh": ActiMode.AC_MODE_TANH,
         "gelu": ActiMode.AC_MODE_GELU,
         "silu": ActiMode.AC_MODE_SILU,
+        "relu2": ActiMode.AC_MODE_RELU2,
     }[a]

@@ -84,6 +84,9 @@ class ActiMode(enum.IntEnum):
     AC_MODE_GELU = 14
     # TPU addition: x * sigmoid(x), the gate of a gated feed-forward
     AC_MODE_SILU = 15
+    # TPU addition: relu(x)^2, the ungated feed-forward of the Nemotron-H
+    # family ("relu2")
+    AC_MODE_RELU2 = 16
 
 
 class AggrMode(enum.IntEnum):
@@ -255,6 +258,12 @@ class OperatorType(enum.IntEnum):
     # place of keys and values (ops/linear_attention.py)
     OP_GATED_DELTA_NET = 1131
     OP_SILU = 1132
+    # Mamba-2 state-space mixer: a second op with a recurrent per-slot
+    # state (ops/state_space.py)
+    OP_MAMBA2 = 1133
+    # a layer's routed experts held here, its router and its shared expert
+    # as ONE op: no token dropped, told which experts it holds (ops/moe.py)
+    OP_EXPERT_BANK = 1134
 
 
 PARALLEL_OP_TYPES = frozenset(

@@ -42,6 +42,11 @@ cache in its own type, and ``P V_blk`` accumulates (heads, heads*dv) of
 which head h's lanes of row h are the answer (the other blocks are the
 price of never re-laying the cache out by head: 1/heads of the MXU work
 is kept, and the kernel is bound by the cache's bytes all the same).
+With grouped-query heads (fewer key-value heads than query heads) a page
+row is kv_heads*d wide and row h of the spread query lies on the lanes of
+ITS GROUP's key-value head, h // (heads / kv_heads): the rows of a group
+share lanes, so the caller spreads the query and picks each row's lanes
+out of the (heads, kv_heads*dv) result, and the kernel does neither.
 Scores, the running max / sum and the accumulator are f32; ``p`` is cast
 to the cache's type before ``p v``, as the dense branch does.
 
@@ -112,11 +117,14 @@ def decode_block_pages(hd_k: int, hd_v: int, page_size: int,
 def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
                          kbuf, vbuf, sems, buf_ref, m_ref, l_ref, acc_ref,
                          *, page_size: int, block_pages: int,
-                         pages_per_slot: int, d: int, dv: int, scale: float):
+                         pages_per_slot: int, d: int, dv: int, scale: float,
+                         grouped: bool):
     """One program = one slot, all heads. (m, l, acc) live in VMEM scratch
     across the slot's blocks; ``buf_ref`` (SMEM) says which of the two
     buffers holds the slot's first block, which the previous program
-    started on its way out."""
+    started on its way out. ``grouped``: the query block arrives spread,
+    (heads, kv_heads*d), and the whole (heads, kv_heads*dv) result goes
+    out (paged_flash_decode spreads and picks)."""
     slot = pl.program_id(0)
     n_slots = pl.num_programs(0)
     block = block_pages * page_size
@@ -167,8 +175,12 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
     # the select runs on 32-bit registers (the mask's layout), the
     # product on the cache's own type
-    q = q_ref[0].astype(jnp.float32)              # (1, heads*d)
-    q_bd = jnp.where(own_lanes(q.shape[-1], d), q, 0.0).astype(kbuf.dtype)
+    if grouped:
+        q_bd = q_ref[0].astype(kbuf.dtype)        # (rows_q, kv_heads*d)
+    else:
+        q = q_ref[0].astype(jnp.float32)          # (1, heads*d)
+        q_bd = jnp.where(own_lanes(q.shape[-1], d), q, 0.0) \
+            .astype(kbuf.dtype)
 
     def body(blk, buf):
         nxt = 1 - buf
@@ -205,18 +217,23 @@ def _paged_decode_kernel(pt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
     buf_ref[0] = jax.lax.fori_loop(0, n_blocks, body, buf_ref[0])
 
     out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-    out = jnp.where(own_lanes(out.shape[-1], dv), out, 0.0)
-    o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    if grouped:
+        o_ref[0] = out.astype(o_ref.dtype)
+    else:
+        out = jnp.where(own_lanes(out.shape[-1], dv), out, 0.0)
+        o_ref[0] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
 def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
                        interpret: bool = False):
     """Single-token attention over the paged KV pool.
 
-    q (slots, heads, d); k_pages/v_pages (num_pages, page_size, heads,
-    d/dv), or already (num_pages, page_size, heads*d/dv); page_table
-    (slots, pages_per_slot) int32; lengths (slots,) int32. Returns
-    (slots, heads, dv). interpret=True runs the same kernel on CPU, at
+    q (slots, heads, d); k_pages/v_pages (num_pages, page_size, kv_heads,
+    d/dv), or already (num_pages, page_size, kv_heads*d/dv), kv_heads =
+    heads or a divisor of it (grouped-query heads: query head i reads
+    key-value head i // (heads / kv_heads)); page_table (slots,
+    pages_per_slot) int32; lengths (slots,) int32. Returns (slots, heads,
+    dv). interpret=True runs the same kernel on CPU, at
     any shape; compiled, a shape `decode_block_pages` cannot tile is a
     ValueError (ops/attention.py asks first and takes the dense branch)."""
     b, h, d = q.shape
@@ -224,7 +241,9 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
     k_pages = k_pages.reshape(n_phys, page_size, -1)
     v_pages = v_pages.reshape(n_phys, page_size, -1)
     hd_k, hd_v = k_pages.shape[-1], v_pages.shape[-1]
-    dv = hd_v // h
+    kv_heads = hd_k // d
+    group = h // kv_heads
+    dv = hd_v // kv_heads
     pages_per_slot = page_table.shape[1]
     block_pages = decode_block_pages(hd_k, hd_v, page_size, k_pages.dtype)
     if block_pages is None:
@@ -238,6 +257,17 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
     # heads to a whole sublane tile of the operand the MXU takes them in
     tile = _sublane_tile(k_pages.dtype)
     rows_q = -(-h // tile) * tile
+    grouped = group > 1
+    if grouped:
+        # row h on its group's lanes, zeros elsewhere; rows past the last
+        # head are zeros (their softmax is uniform, their result unread)
+        own = (jnp.arange(hd_k)[None, :] // d
+               == jnp.arange(h)[:, None] // group)             # (h, kv*d)
+        q_in = jnp.where(own, jnp.tile(q, (1, 1, kv_heads)), 0)
+        q_in = jnp.pad(q_in, ((0, 0), (0, rows_q - h), (0, 0)))
+        q_rows = out_rows = rows_q
+    else:
+        q_in, q_rows, out_rows = q.reshape(b, 1, hd_k), 1, 1
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
@@ -246,11 +276,12 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
         # slot, so they ride as (slots, 1, heads*d): the block's last two
         # dims are then the array's own.
         in_specs=[
-            pl.BlockSpec((1, 1, hd_k), lambda s, pt, ln: (s, 0, 0)),
+            pl.BlockSpec((1, q_rows, hd_k), lambda s, pt, ln: (s, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, hd_v), lambda s, pt, ln: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, out_rows, hd_v),
+                               lambda s, pt, ln: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((2, block, hd_k), k_pages.dtype),
             pltpu.VMEM((2, block, hd_v), v_pages.dtype),
@@ -265,9 +296,9 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
         functools.partial(
             _paged_decode_kernel, page_size=page_size,
             block_pages=block_pages, pages_per_slot=pages_per_slot,
-            d=d, dv=dv, scale=1.0 / math.sqrt(d)),
+            d=d, dv=dv, scale=1.0 / math.sqrt(d), grouped=grouped),
         grid_spec=grid_spec,
-        out_shape=out_struct((b, 1, hd_v), q.dtype, q),
+        out_shape=out_struct((b, out_rows, hd_v), q.dtype, q),
         # the slots run in order: each starts the next one's first block
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
@@ -275,22 +306,30 @@ def paged_flash_decode(q, k_pages, v_pages, page_table, lengths, *,
         name="ff_paged_decode",
     )(jnp.asarray(page_table, jnp.int32).reshape(-1),
       jnp.asarray(lengths, jnp.int32),
-      q.reshape(b, 1, hd_k).astype(k_pages.dtype), k_pages, v_pages)
-    return out.reshape(b, h, dv)
+      q_in.astype(k_pages.dtype), k_pages, v_pages)
+    if not grouped:
+        return out.reshape(b, h, dv)
+    # row h's answer lies on its group's lanes
+    out = out[:, :h].reshape(b, kv_heads, group, kv_heads, dv)
+    return jnp.einsum("bkgkd->bkgd", out).reshape(b, h, dv)
 
 
 def paged_decode_reference(q, k_pages, v_pages, page_table, lengths):
     """Dense parity oracle: gather every slot's pages, mask positions
     past its length, one softmax. O(slots * pages * page_size) memory:
-    test-sized only. Pools are (num_pages, page_size, heads, d)."""
+    test-sized only. Pools are (num_pages, page_size, kv_heads, d)."""
     b, h, d = q.shape
     n_pages = page_table.shape[1]
     page_size = k_pages.shape[1]
-    # (slots, n_pages, page_size, heads, d) -> (slots, positions, heads, d)
-    k = jnp.take(k_pages, page_table, axis=0) \
-        .reshape(b, n_pages * page_size, h, d)
-    v = jnp.take(v_pages, page_table, axis=0) \
-        .reshape(b, n_pages * page_size, h, -1)
+    kv_heads = k_pages.shape[2]
+    # (slots, n_pages, page_size, kv_heads, d) -> (slots, positions, heads,
+    # d), a key-value head repeated for its group's query heads
+    k = jnp.repeat(jnp.take(k_pages, page_table, axis=0)
+                   .reshape(b, n_pages * page_size, kv_heads, d),
+                   h // kv_heads, axis=2)
+    v = jnp.repeat(jnp.take(v_pages, page_table, axis=0)
+                   .reshape(b, n_pages * page_size, kv_heads, -1),
+                   h // kv_heads, axis=2)
     s = jnp.einsum("bhd,bthd->bht", q.astype(jnp.float32),
                    k.astype(jnp.float32)) / math.sqrt(d)
     pos = jnp.arange(n_pages * page_size)[None, None, :]

@@ -113,6 +113,25 @@ class MultiHeadAttentionParams:
     # size projection with a learned scale (the OLMo 2 block's QK-norm)
     qk_norm: bool = False
     qk_norm_eps: float = 1e-6
+    # grouped-query attention: key-value heads fewer than query heads, query
+    # head i reading key-value head i // (num_heads / num_kv_heads). 0 means
+    # num_heads (every query head its own keys and values).
+    num_kv_heads: int = 0
+
+    def __post_init__(self):
+        if self.num_kv_heads and self.num_heads % self.num_kv_heads:
+            raise ValueError(
+                f"num_kv_heads {self.num_kv_heads} does not divide "
+                f"num_heads {self.num_heads}")
+
+    @property
+    def kv_heads(self):
+        return self.num_kv_heads or self.num_heads
+
+    @property
+    def group(self):
+        """Query heads that share one key-value head."""
+        return self.num_heads // self.kv_heads
 
     # reference semantics (attention.cc:86): kdim/vdim are PER-HEAD
     # projection sizes (qProjSize = kdim); 0 means embed_dim/num_heads.
@@ -137,20 +156,20 @@ def _infer(params: MultiHeadAttentionParams, in_shapes, in_dtypes):
 
 def _weights(params: MultiHeadAttentionParams, in_shapes, in_dtypes):
     q, k, v = in_shapes
-    h = params.num_heads
+    h, hkv = params.num_heads, params.kv_heads
     dqk, dv = params.qk_head_dim, params.v_head_dim
     dt = in_dtypes[0]
     ws = [
         WeightSpec("wq", (q[-1], h, dqk), dt, "glorot_uniform", ("", "head", "")),
-        WeightSpec("wk", (k[-1], h, dqk), dt, "glorot_uniform", ("", "head", "")),
-        WeightSpec("wv", (v[-1], h, dv), dt, "glorot_uniform", ("", "head", "")),
+        WeightSpec("wk", (k[-1], hkv, dqk), dt, "glorot_uniform", ("", "head", "")),
+        WeightSpec("wv", (v[-1], hkv, dv), dt, "glorot_uniform", ("", "head", "")),
         WeightSpec("wo", (h, dv, params.embed_dim), dt, "glorot_uniform", ("head", "", "")),
     ]
     if params.bias:
         ws.append(WeightSpec("bias_o", (params.embed_dim,), dt, "zero"))
     if params.qk_norm:
         ws.append(WeightSpec("q_norm", (h * dqk,), dt, "one"))
-        ws.append(WeightSpec("k_norm", (h * dqk,), dt, "one"))
+        ws.append(WeightSpec("k_norm", (hkv * dqk,), dt, "one"))
     return ws
 
 
@@ -161,10 +180,10 @@ def _qk_norm(params: MultiHeadAttentionParams, weights, q, k, layout="bshd"):
     if not params.qk_norm:
         return q, k
     h_ax = layout.index("h")
-    shape = [1, 1, 1, q.shape[3]]
-    shape[h_ax] = q.shape[h_ax]
 
     def norm(x, scale):
+        shape = [1, 1, 1, x.shape[3]]
+        shape[h_ax] = x.shape[h_ax]
         xf = x.astype(jnp.float32)
         ms = jnp.mean(xf * xf, axis=(h_ax, 3), keepdims=True)
         scale = scale.astype(jnp.float32).reshape(x.shape[h_ax], x.shape[3])
@@ -250,6 +269,7 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     # round-trip each way (fold + unfold, fwd and bwd).
     if (impl in ("auto", "flash")
             and on_tpu
+            and params.group == 1
             and (not use_dropout or flash_dropout_ok)
             and flash_supported(seq_len, kv_len)
             and data_degree * model_degree * seq_degree * expert_degree
@@ -292,6 +312,11 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     q, k = _qk_norm(params, weights, q.astype(q_in.dtype),
                     k.astype(q_in.dtype))
     v = v.astype(q_in.dtype)
+    if params.group > 1:
+        # every kernel below indexes K and V by the query's head: a group's
+        # queries get their shared head repeated
+        k = jnp.repeat(k, params.group, axis=2)
+        v = jnp.repeat(v, params.group, axis=2)
 
     # Dispatch: on TPU the fused Pallas kernel (fwd + bwd in VMEM,
     # kernels/attention.py) wins whenever its score tile fits — measured
@@ -476,8 +501,10 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
     parallel/decode.py). Inputs are the NEW positions' slices
     (b, s0, e) starting at position t (s0 = 1 for token-by-token decode,
     s0 = prompt_len for one-shot prefill); cache holds (k, v) of shape
-    (b, max_len, h*d) with positions < t valid (init_decode_cache says
-    why the heads are folded). Appends the block's K/V and attends its
+    (b, max_len, kv_heads*d) with positions < t valid (init_decode_cache
+    says why the heads are folded; with grouped-query heads a row holds the
+    key-value heads alone, and a query head reads its group's lanes).
+    Appends the block's K/V and attends its
     queries against the prefix with intra-block causal masking —
     cache-width attention rows per token instead of the full O(L²)
     forward the reference's serving prototype would re-run (it has no KV
@@ -512,9 +539,17 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
     q, k_new = _qk_norm(params, weights, q, k_new)
     k_cache, v_cache = cache
     b, s0, h = q.shape[:3]
+    group = params.group
     max_len = k_cache.shape[1]
+
+    def by_query_head(cache):
+        """The folded cache as (b, max_len, h, d): each key-value head
+        repeated for the query heads of its group."""
+        c = cache.astype(q.dtype).reshape(b, max_len, params.kv_heads, -1)
+        return c if group == 1 else jnp.repeat(c, group, axis=2)
+
     # the cache keeps a position's heads folded into one row (b, max_len,
-    # h*d): the new rows fold the same way
+    # kv_heads*d): the new rows fold the same way
     k_new = k_new.reshape(b, s0, -1).astype(k_cache.dtype)
     v_new = v_new.reshape(b, s0, -1).astype(v_cache.dtype)
     per_row_t = getattr(t, "ndim", 0) == 1
@@ -584,8 +619,7 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
         from ..kernels.attention import _chunk_scan
 
         attn, _, _ = _chunk_scan(
-            q, k_cache.astype(q.dtype).reshape(b, max_len, h, -1),
-            v_cache.astype(q.dtype).reshape(b, max_len, h, -1),
+            q, by_query_head(k_cache), by_query_head(v_cache),
             causal=True, chunk_size=min(256, max_len), q_offset=t)
     else:
         k_all, v_all = k_cache.astype(q.dtype), v_cache.astype(q.dtype)
@@ -597,18 +631,26 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
             # the cache is made: on the TPU that view is a whole-cache
             # relayout (init_decode_cache), while heads times the MXU work
             # is nothing beside the cache's bytes.
+            # (Grouped-query heads: row h holds head h's values on the
+            # lanes of ITS GROUP's key-value head, so the folded row is
+            # kv_heads*d wide and a group's rows share their lanes.)
             def own_lanes(per_head):
-                return (jnp.arange(h * per_head)[None, :] // per_head
-                        == jnp.arange(h)[:, None])             # (h, h*d)
-            q_bd = jnp.where(own_lanes(params.qk_head_dim),
-                             q.reshape(b, 1, -1), 0)           # (b, h, h*d)
+                lanes = jnp.arange(params.kv_heads * per_head)[None, :] \
+                    // per_head
+                rows = jnp.arange(h)[:, None]
+                return lanes == (rows if group == 1 else rows // group)
+            q_bd = jnp.where(
+                own_lanes(params.qk_head_dim),
+                q.reshape(b, 1, -1) if group == 1
+                else jnp.tile(q[:, 0], (1, 1, params.kv_heads)),
+                0)                                         # (b, h, kv*d)
             scores = jnp.einsum(
                 "bhk,btk->bht", q_bd, k_all,
                 preferred_element_type=jnp.float32,
             )[:, :, None] * scale
         else:
             scores = jnp.einsum(
-                "bshd,bthd->bhst", q, k_all.reshape(b, max_len, h, -1),
+                "bshd,bthd->bhst", q, by_query_head(k_all),
                 preferred_element_type=jnp.float32,
             ) * scale                  # (b, h, s0, max_len)
         pos = jnp.arange(max_len)               # cache positions
@@ -629,12 +671,16 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
             attn = jnp.einsum(
                 "bht,btk->bhk", probs[:, :, 0], v_all,
                 preferred_element_type=jnp.float32,
-            )                          # (b, h, h*dv): row h's own lanes
-            attn = jnp.where(own_lanes(params.v_head_dim), attn, 0).sum(1) \
-                .reshape(b, 1, h, -1).astype(q.dtype)
+            )                          # (b, h, kv*dv): row h's own lanes
+            attn = jnp.where(own_lanes(params.v_head_dim), attn, 0)
+            if group == 1:
+                attn = attn.sum(1)
+            else:  # a group's rows share lanes: fold the lanes, not the rows
+                attn = attn.reshape(b, h, params.kv_heads, -1).sum(2)
+            attn = attn.reshape(b, 1, h, -1).astype(q.dtype)
         else:
             attn = jnp.einsum(
-                "bhst,bthd->bshd", probs, v_all.reshape(b, max_len, h, -1),
+                "bhst,bthd->bshd", probs, by_query_head(v_all),
                 preferred_element_type=jnp.float32,
             ).astype(q.dtype)
     out = jnp.einsum("bshd,hde->bse", attn, wo,
@@ -682,6 +728,9 @@ def _forward_decode_cross(params, weights, inputs, ctx, kv):
     q = jnp.einsum("bse,ehd->bshd", q_in, wq,
                    preferred_element_type=jnp.float32).astype(q_in.dtype)
     k, v = kv
+    if params.group > 1:
+        k = jnp.repeat(k, params.group, axis=2)
+        v = jnp.repeat(v, params.group, axis=2)
     scale = 1.0 / jnp.sqrt(jnp.asarray(params.qk_head_dim, jnp.float32))
     scores = jnp.einsum(
         "bshd,bthd->bhst", q, k.astype(q.dtype),
@@ -702,15 +751,17 @@ def _forward_decode_cross(params, weights, inputs, ctx, kv):
 
 def init_decode_cache(params: MultiHeadAttentionParams, batch: int,
                       max_len: int, dtype):
-    """Fresh (k, v) cache for one MHA op: (batch, max_len, heads*d), one
-    position's heads folded into one row. The fold decides how the cache
+    """Fresh (k, v) cache for one MHA op: (batch, max_len, kv_heads*d), one
+    position's key-value heads folded into one row (kv_heads = heads but
+    for grouped-query attention, where the cache is narrower by the group).
+    The fold decides how the cache
     lies in the TPU's memory: XLA lays a 4-D (batch, max_len, heads, 64)
     array out with max_len innermost (a 64-wide minor axis would be padded
     to the 128 lanes), so every view of it by position, the paged kernel's
     pages and the per-token append alike, was a whole-cache transpose;
     with heads*d innermost a position is one contiguous row and a page a
     contiguous run of them."""
-    h, dqk, dv = params.num_heads, params.qk_head_dim, params.v_head_dim
+    h, dqk, dv = params.kv_heads, params.qk_head_dim, params.v_head_dim
     return (
         jnp.zeros((batch, max_len, h * dqk), dtype),
         jnp.zeros((batch, max_len, h * dv), dtype),

@@ -23,4 +23,6 @@ def apply_activation(mode: ActiMode, x):
         return jax.nn.gelu(x)
     if mode == ActiMode.AC_MODE_SILU:
         return jax.nn.silu(x)
+    if mode == ActiMode.AC_MODE_RELU2:
+        return jnp.square(jax.nn.relu(x))
     raise ValueError(f"unknown activation {mode}")

@@ -1,5 +1,5 @@
 """Mixture-of-Experts operator family: Group_by, Aggregate, AggregateSpec,
-Cache.
+Cache, and the expert bank.
 
 TPU-native equivalents of reference src/ops/group_by.cc (534 LoC + CUDA),
 aggregate.cc (569), aggregate_spec.cc (519), cache.cc (291). The reference
@@ -7,7 +7,16 @@ routes tokens to per-expert tensors with scatter CUDA kernels; the TPU-native
 formulation is the dense dispatch/combine einsum (Mesh-TensorFlow / GShard
 style): a one-hot dispatch mask [tokens, experts, capacity] turns routing into
 two MXU matmuls, which is both jit-static and shardable over an expert mesh
-axis (expert parallelism).
+axis (expert parallelism). What that costs: the mask is float32
+[tokens x k, experts, capacity] and both einsums contract over it, so at 128
+experts, top-6 and a 1,024-token prefill (capacity 48 at factor 1) it is 151
+MB a layer and each einsum 2 x 6,144 x 128 x 48 x 2,688 = 203 GFLOP of
+products that only move data, where the 128 experts' own work is 123; and a
+token past an expert's capacity is dropped. A served model takes `expert_bank` below instead: ONE
+op for a layer's router, the routed experts held here and its shared expert,
+every held expert's matrices read once a block under the router's weights,
+no capacity and no dropped token. (`models/zoo.py` `build_moe_transformer` still builds the
+reference's composite from the ops above.)
 
 Load balancing: the reference injects a lambda_bal term directly into the
 gate gradients in aggregate's hand-written backward (aggregate.cc backward
@@ -23,8 +32,9 @@ import math
 import jax
 import jax.numpy as jnp
 
-from ..ff_types import DataType, OperatorType
-from .registry import register_op
+from ..ff_types import ActiMode, DataType, OperatorType
+from .common import apply_activation
+from .registry import WeightSpec, register_op
 
 
 def _capacity(batch_tokens: int, k: int, n: int, alpha: float) -> int:
@@ -224,4 +234,162 @@ register_op(
     forward=lambda p, w, x, ctx: [x[0]],
     state_spec=_cache_state,
     forward_stateful=_cache_forward_stateful,
+)
+
+
+# ---------------------------------------------------------------------------
+# The expert bank: router + the routed experts held here + the shared expert
+# ---------------------------------------------------------------------------
+@dataclasses.dataclass(frozen=True)
+class ExpertBankParams:
+    """One expert layer (DeepSeek-V3-style routing): `experts` routed
+    experts of which this op HOLDS `held_count` from `held_from` on (expert
+    parallelism's share of a layer: the rest live on other chips), `top_k`
+    of all `experts` chosen a token by sigmoid scores, ungated two-matrix
+    experts of `width`, one shared expert of `shared_width` (0: none)."""
+
+    experts: int
+    held_from: int
+    held_count: int
+    top_k: int
+    width: int
+    shared_width: int = 0
+    scale: float = 1.0            # routed_scaling_factor
+    norm_topk: bool = True        # weights divided by their sum
+    activation: ActiMode = ActiMode.AC_MODE_RELU2
+
+    def __post_init__(self):
+        if not (0 <= self.held_from
+                and self.held_from + self.held_count <= self.experts
+                and 0 < self.held_count and 0 < self.top_k <= self.experts):
+            raise ValueError(f"expert bank holds [{self.held_from}, "
+                             f"{self.held_from + self.held_count}) of "
+                             f"{self.experts} experts, top {self.top_k}")
+
+
+EXPERT_BANK_COUNTERS = ("moe_assignments_held", "moe_assignments_elsewhere",
+                        "moe_experts_touched", "moe_expert_load_max")
+
+
+def _bank_infer(params: ExpertBankParams, in_shapes, in_dtypes):
+    return [tuple(in_shapes[0])], [in_dtypes[0]]
+
+
+def _bank_weights(params: ExpertBankParams, in_shapes, in_dtypes):
+    e, dt = in_shapes[0][-1], in_dtypes[0]
+    n, f = params.held_count, params.width
+    ws = [
+        WeightSpec("router", (e, params.experts), dt),
+        WeightSpec("b_corr", (params.experts,), dt, "zero"),
+        WeightSpec("w_up", (n, e, f), dt),
+        WeightSpec("w_down", (n, f, e), dt),
+    ]
+    if params.shared_width:
+        ws += [WeightSpec("shared_up", (e, params.shared_width), dt),
+               WeightSpec("shared_down", (params.shared_width, e), dt)]
+    return ws
+
+
+def route(params: ExpertBankParams, router, b_corr, x):
+    """The router on tokens x (T, e), in float32: sigmoid scores over ALL
+    experts, the `top_k` largest of score + b_corr chosen, the chosen
+    scores (without b_corr) normalised and scaled. Returns (ids (T, k)
+    int32, weights (T, k) float32)."""
+    f32 = jnp.float32
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(f32), router.astype(f32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(scores + b_corr.astype(f32), params.top_k)
+    w = jnp.take_along_axis(scores, ids, axis=-1)
+    if params.norm_topk:
+        w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    return ids.astype(jnp.int32), w * params.scale
+
+
+# bytes of float32 hidden activations (tokens x experts x width) one pass over
+# a group of experts may hold; a block of tokens too long for all the held
+# experts at once takes them a group at a time
+_BANK_HIDDEN_BYTES = 256 << 20
+
+
+def _bank_forward(params: ExpertBankParams, weights, inputs, ctx):
+    """out = sum over a token's chosen experts THAT ARE HELD HERE of w_e
+    e(x) + shared(x); what the experts elsewhere would add is left out, and
+    no token is dropped: there is no capacity.
+
+    Every held expert's first matrix meets every token of the block in one
+    batched product, the router's weights (0 for an expert a token did not
+    choose) scale the activations, and the second product contracts over
+    experts x width at once, which sums the chosen experts' outputs. At a
+    decode batch this reads each held expert once, which is the work's own
+    bytes but for the few experts no token chose (61 of 64 at 64 tokens), and
+    runs at 90% of the HBM roofline: 1.73 ms for 64 tokens at hidden 2,688,
+    64 experts of 1,856, where `jax.lax.ragged_dot` over the assignments
+    sorted by expert took 14.7 ms (its kernel walks 128 x 128 tiles of each
+    touched matrix) and 21.8 ms against 9.2 for a 1,024-token block (TPU v5e,
+    PR 33). The price is operations: tokens x held experts, not tokens
+    x top_k; past a few thousand tokens a block a sorted grouped product
+    with wide tiles (a Pallas kernel, ROADMAP B-M3) is what this wants."""
+    (x,) = inputs
+    cdt = ctx.compute_dtype
+    if cdt is not None:
+        x = x.astype(cdt)
+    w = {n: (a.astype(x.dtype) if cdt is not None else a)
+         for n, a in weights.items()}
+    f32 = jnp.float32
+    lead, e = x.shape[:-1], x.shape[-1]
+    xt = x.reshape(-1, e)
+    T, n, f = xt.shape[0], params.held_count, params.width
+    with jax.named_scope("ff.moe.route"):
+        # the router reads the weights as stored, not rounded to the
+        # compute type
+        ids, gate = route(params, weights["router"], weights["b_corr"], xt)
+    with jax.named_scope("ff.moe.dispatch"):
+        local = ids - params.held_from                        # (T, k)
+        held = (local >= 0) & (local < n)
+        # an assignment elsewhere indexes past the last column: all zeros
+        chosen = jax.nn.one_hot(jnp.where(held, local, n), n, dtype=f32)
+        scale = jnp.sum(chosen * gate[..., None], axis=1)     # (T, n)
+        load = jnp.sum(chosen, axis=(0, 1)).astype(jnp.int32)  # (n,)
+        ctx.count("moe_assignments_held", jnp.sum(held, dtype=jnp.int32))
+        ctx.count("moe_assignments_elsewhere",
+                  jnp.sum(~held, dtype=jnp.int32))
+        ctx.count("moe_experts_touched", jnp.sum(load > 0, dtype=jnp.int32))
+        ctx.count("moe_expert_load_max", jnp.max(load))
+
+    def group(up, down, scale):
+        """The experts `up` (g, e, f), `down` (g, f, e) on every token."""
+        with jax.named_scope("ff.moe.experts"):
+            h = jnp.einsum("te,gef->tgf", xt, up, preferred_element_type=f32)
+            h = apply_activation(params.activation, h)
+        with jax.named_scope("ff.moe.combine"):
+            h = (h * scale[:, :, None]).astype(x.dtype)
+            return jnp.dot(h.reshape(T, -1), down.reshape(-1, e),
+                           preferred_element_type=f32)
+
+    g = n
+    while g > 1 and (4 * T * g * f > _BANK_HIDDEN_BYTES or n % g):
+        g -= 1
+    if g == n:
+        out = group(w["w_up"], w["w_down"], scale)
+    else:
+        def body(acc, part):
+            return acc + group(*part), None
+        out, _ = jax.lax.scan(body, jnp.zeros((T, e), f32), (
+            w["w_up"].reshape(n // g, g, e, f),
+            w["w_down"].reshape(n // g, g, f, e),
+            jnp.moveaxis(scale.reshape(T, n // g, g), 1, 0)))
+    if params.shared_width:
+        with jax.named_scope("ff.moe.shared"):
+            s = jnp.dot(xt, w["shared_up"], preferred_element_type=f32)
+            s = apply_activation(params.activation, s).astype(x.dtype)
+            out = out + jnp.dot(s, w["shared_down"],
+                                preferred_element_type=f32)
+    return [out.astype(x.dtype).reshape(lead + (e,))]
+
+
+register_op(
+    OperatorType.OP_EXPERT_BANK, "ExpertBank", infer=_bank_infer,
+    weights=_bank_weights, forward=_bank_forward, num_inputs=1,
+    seq_pointwise=True, decode_counters=EXPERT_BANK_COUNTERS,
 )

@@ -71,6 +71,11 @@ class OpDef:
     # outs reads it and appends nothing.
     init_decode_static: Optional[Callable] = None
     forward_decode_static: Optional[Callable] = None
+    # Names of the integer counters the op's forward reports through
+    # FwdCtx.count while a decode step is traced (parallel/decode.py keeps
+    # them in the caches' "counters" section, one scalar a name for the
+    # whole step; a name that ends in "_max" is a maximum, any other a sum).
+    decode_counters: Tuple[str, ...] = ()
     # Cross-batch mutable buffers (reference: cuDNN BN running stats,
     # Cache op's CACHE_UPDATE_TASK). state_spec declares them like
     # weights; forward_stateful(params, weights, state, inputs, ctx) ->
@@ -103,6 +108,7 @@ def register_op(
     decode_section: Optional[str] = None,
     init_decode_static: Optional[Callable] = None,
     forward_decode_static: Optional[Callable] = None,
+    decode_counters: Tuple[str, ...] = (),
     state_spec: Optional[Callable] = None,
     forward_stateful: Optional[Callable] = None,
 ) -> OpDef:
@@ -119,6 +125,7 @@ def register_op(
         decode_section=decode_section,
         init_decode_static=init_decode_static,
         forward_decode_static=forward_decode_static,
+        decode_counters=tuple(decode_counters),
         state_spec=state_spec,
         forward_stateful=forward_stateful,
     )
@@ -163,10 +170,27 @@ class FwdCtx:
     # fallback warn-once/metric keys on it). "" when the caller has no
     # layer identity (raw op-def invocations in tests).
     op_name: str = ""
+    # Integer counters of one traced decode step, by name (OpDef.
+    # decode_counters); None wherever nobody collects them.
+    counters: Optional[dict] = None
 
     def add_aux_loss(self, value):
         if self.aux_losses is not None:
             self.aux_losses.append(value)
+
+    def count(self, name: str, value):
+        """Add `value` (a traced integer scalar) to the step's counter
+        `name`; a name that ends in "_max" keeps the largest."""
+        if self.counters is None:
+            return
+        if name not in self.counters:
+            self.counters[name] = value
+        elif name.endswith("_max"):
+            import jax.numpy as jnp
+
+            self.counters[name] = jnp.maximum(self.counters[name], value)
+        else:
+            self.counters[name] = self.counters[name] + value
 
 
 def ensure_ops_loaded():
@@ -188,5 +212,6 @@ def ensure_ops_loaded():
         pool2d,
         reduce,
         softmax,
+        state_space,
         tensor_ops,
     )

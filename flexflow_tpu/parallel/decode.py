@@ -231,7 +231,7 @@ class _Propagator:
             set_out(0, AxisInfo(live=1))
             return
 
-        if t == OperatorType.OP_GATED_DELTA_NET:
+        if t in (OperatorType.OP_GATED_DELTA_NET, OperatorType.OP_MAMBA2):
             (a,) = ins
             if a.live != 1 or a.prefix is not None:
                 fail("the input must be (batch, seq, embed) with the live "
@@ -242,13 +242,16 @@ class _Propagator:
             return
 
         if _is_unary_pointwise(op) or (
-            t == OperatorType.OP_LINEAR
+            # a product over the LAST axis alone: every position its own
+            # (the expert bank routes and computes token by token)
+            t in (OperatorType.OP_LINEAR, OperatorType.OP_EXPERT_BANK)
         ) or (
             t == OperatorType.OP_EMBEDDING
             and op.params.aggr == AggrMode.AGGR_MODE_NONE
         ):
             a = ins[0]
-            if t == OperatorType.OP_LINEAR and (
+            if t in (OperatorType.OP_LINEAR,
+                     OperatorType.OP_EXPERT_BANK) and (
                 a.live == len(in_shapes[0]) - 1
                 or a.prefix == len(in_shapes[0]) - 1
             ):
@@ -858,7 +861,9 @@ def _static_alignment(shape, out_rank, out_info: AxisInfo, live_len):
 #     "static"            guid -> an encoder-side value a live op reads
 #     "mha_static"        op name -> a static-keyed attention's (k, v)
 SLOT_SECTIONS = ("prefix", "mha", "recurrent")
-SHARED_SECTIONS = ("static", "mha_static")
+# "counters": one integer scalar a name, what the ops of the LAST step
+# counted (OpDef.decode_counters); a step replaces them, nothing else does
+SHARED_SECTIONS = ("static", "mha_static", "counters")
 # what a slot pays for, by kind: keys and values, which grow with a
 # sequence up to max_len (pages), and state of fixed size, which does not
 STATE_KINDS = {"kv": ("prefix", "mha"), "fixed": ("recurrent",)}
@@ -1130,6 +1135,9 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
         op.weights for op in plan.static_ops if not op.is_parallel_op
     )
     static_kept = _kept_statics(plan, static_keyed)
+    counter_names = sorted({
+        name for op in plan.live_ops if not op.is_parallel_op
+        for name in get_op_def(op.op_type).decode_counters})
 
     def init_caches(params=None, static_inputs=()):
         assert len(static_inputs) == len(static_pts), (
@@ -1166,6 +1174,8 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
             d = get_op_def(op.op_type)
             caches[d.decode_section][op.name] = d.init_decode_state(
                 op.params, batch, max_len, cdt)
+        for name in counter_names:
+            caches["counters"][name] = jnp.zeros((), jnp.int32)
         for op in static_keyed:
             caches["mha_static"][op.name] = get_op_def(
                 op.op_type).init_decode_static(
@@ -1210,6 +1220,9 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
         statics = dict(caches["static"])
         vals = {plan.decode_pt.guid: tok}
         new_caches = _stepped(caches)
+        # what the ops count is of THIS trace's step
+        sctx = dataclasses.replace(ctx, counters={}) if counter_names \
+            else ctx
 
         def get_static(g):
             if g in statics:
@@ -1245,11 +1258,11 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                 ins = [vals[x.guid] for x in op.inputs]
                 outs, new_caches[d.decode_section][op.name] = \
                     d.forward_decode(
-                        op.params, w, ins, ctx,
+                        op.params, w, ins, sctx,
                         caches[d.decode_section][op.name], t, valid=valid)
             elif id(op) in static_keyed_set:
                 outs = d.forward_decode_static(
-                    op.params, w, [vals[op.inputs[0].guid]], ctx,
+                    op.params, w, [vals[op.inputs[0].guid]], sctx,
                     caches["mha_static"][op.name],
                 )
             elif ot == OperatorType.OP_BATCHMATMUL:
@@ -1299,7 +1312,7 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                 out_rank = len(op.outputs[0].material_shape())
                 ins = [aligned_input(x, out_rank, out_info, op.name)
                        for x in op.inputs]
-                outs = d.forward(op.params, w, ins, ctx)
+                outs = d.forward(op.params, w, ins, sctx)
 
             for x, v in zip(op.outputs, outs):
                 vals[x.guid] = v
@@ -1335,6 +1348,10 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                                 r, n, 1, axis=_ax - 1)
                         )(v, at)
                     t, s0 = t + jnp.asarray(row, jnp.int32), 1
+        if counter_names:
+            new_caches["counters"] = {
+                name: jnp.asarray(sctx.counters.get(name, 0), jnp.int32)
+                for name in counter_names}
         if donate:
             _check_donated(caches, new_caches)
         return vals[logits_pt.guid], new_caches

@@ -1341,6 +1341,8 @@ class ContinuousBatcher:
         if self._caches is None:
             self._caches = self._initB(self.model.state.params, ())
             self._note_state_bytes()
+            for name in self._caches["counters"]:
+                self.stats.setdefault(name, 0)
         # the insert consumes the caches as a step does: nothing here
         # holds the old tree while its successor is made
         caches, self._caches = self._caches, None
@@ -1365,6 +1367,21 @@ class ContinuousBatcher:
                       help="1 where the decode step owns these caches and "
                            "appends in place, 0 where it copies them first",
                       replica=self.name)
+
+    def _note_step_counters(self, counted) -> None:
+        """Fold one decode step's counters (parallel/decode.py, the caches'
+        "counters" section: whatever the graph's ops report, by name) into
+        `stats` and the session's `ff_serving_*` gauges: a name that ends
+        in "_max" keeps the largest a step has seen, any other sums."""
+        from .. import obs
+
+        for name, value in counted.items():
+            value = int(value)
+            self.stats[name] = max(self.stats[name], value) \
+                if name.endswith("_max") else self.stats[name] + value
+            obs.gauge_set("ff_serving_" + name, self.stats[name],
+                          help="what the decode steps' ops counted under "
+                               "this name", replica=self.name)
 
     # -- retirement ------------------------------------------------------
     def _release(self, slot_idx: int) -> None:
@@ -1475,7 +1492,11 @@ class ContinuousBatcher:
                     jax.block_until_ready(logits)
                 with obs.mark("ff.serve.decode.fetch", cat="serving",
                               into=(stats, "decode_fetch_s")) as fetch:
-                    logits = np.asarray(logits)
+                    # what the step's ops counted (a few scalars, or
+                    # nothing) rides the fetch of its logits
+                    logits, counted = jax.device_get(
+                        (logits, self._caches["counters"]))
+            self._note_step_counters(counted)
             # a sampled request's share of the iteration: from the
             # ff.serve.decode span's start to the logits on the host
             span_dur = fetch.t0 + fetch.dur - span.t0

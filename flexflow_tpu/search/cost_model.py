@@ -107,7 +107,9 @@ def op_flops(op: PCGOp) -> float:
         h, d = p.num_heads, p.qk_head_dim
         bq, sq, eq = q[0], q[1], q[2]
         sk = k[1]
-        proj = 2.0 * bq * sq * eq * h * d * 3  # q,k,v projections
+        # q, and k and v at the key-value heads (fewer under grouped-query
+        # attention)
+        proj = 2.0 * bq * sq * eq * d * (h + 2 * p.kv_heads)
         scores = 2.0 * bq * h * sq * sk * d
         av = 2.0 * bq * h * sq * sk * p.v_head_dim
         out = 2.0 * bq * sq * h * p.v_head_dim * p.embed_dim
@@ -124,6 +126,24 @@ def op_flops(op: PCGOp) -> float:
         state = 6.0 * tokens * h * dv * dk
         conv = 2.0 * tokens * p.conv_kernel * p.conv_channels
         return proj + state + conv
+    if t == OperatorType.OP_MAMBA2:
+        (x,) = in_shapes
+        p = op.params
+        tokens, e = x[0] * x[1], x[2]
+        # in- and out-projection; per token and head the state's decay, its
+        # rank-one update and the read (6 P N), and the short convolution
+        proj = 2.0 * tokens * e * (p.in_width + p.inner)
+        state = 6.0 * tokens * p.num_heads * p.head_dim * p.state_size
+        conv = 2.0 * tokens * p.conv_kernel * p.conv_channels
+        return proj + state + conv
+    if t == OperatorType.OP_EXPERT_BANK:
+        (x,) = in_shapes
+        p = op.params
+        tokens, e = _vol(x[:-1]), x[-1]
+        # the router over all experts, the shared expert, and every held
+        # expert on every token (ops/moe.py: the router's weights pick)
+        return 2.0 * tokens * e * (p.experts + 2 * p.shared_width
+                                   + 2 * p.held_count * p.width)
     if t == OperatorType.OP_LAYERNORM and op.params.rms:
         # square, mean, normalise, scale: four operations an element
         return 4.0 * _vol(out_shapes[0])
@@ -214,7 +234,8 @@ def op_padded_flops(op: PCGOp, parts: int = 1) -> float:
         # the DP grants it single-part views, so charging one shard here
         # would let a TP candidate undercut without paying its devices
         h, d = p.num_heads, p.qk_head_dim
-        proj = 2.0 * _pad(bq * sq, MXU_SUBLANES) * _pad(eq, MXU_LANES) * _pad(h * d, MXU_LANES) * 3
+        proj = 2.0 * _pad(bq * sq, MXU_SUBLANES) * _pad(eq, MXU_LANES) * (
+            _pad(h * d, MXU_LANES) + 2 * _pad(p.kv_heads * d, MXU_LANES))
         scores = 2.0 * bq * h * _pad(sq, MXU_SUBLANES) * _pad(d, MXU_LANES) * _pad(sk, MXU_LANES)
         av = 2.0 * bq * h * _pad(sq, MXU_SUBLANES) * _pad(sk, MXU_LANES) * _pad(p.v_head_dim, MXU_LANES)
         out = 2.0 * _pad(bq * sq, MXU_SUBLANES) * _pad(h * p.v_head_dim, MXU_LANES) * _pad(p.embed_dim, MXU_LANES)
@@ -267,8 +288,9 @@ def op_decode_bytes(op: PCGOp) -> float:
         # over — byte-equivalent to the full k/v inputs; the cache is
         # materialized at the compute width (bf16 under AMP)
         for x in op.inputs[1:3]:
-            n += _vol(x.material_shape()) * x.effective_itemsize()
-    if op.op_type == OperatorType.OP_GATED_DELTA_NET:
+            n += _vol(x.material_shape()) * x.effective_itemsize() \
+                / op.params.group
+    if op.op_type in _RECURRENT_OPS:
         n += _recurrent_state_traffic(op)
     for x in list(op.inputs) + list(op.outputs):
         n += _vol(x.material_shape()) * x.effective_itemsize() \
@@ -276,13 +298,22 @@ def op_decode_bytes(op: PCGOp) -> float:
     return n
 
 
+# the ops that keep a recurrent state a slot (section "recurrent" of the
+# decode caches)
+_RECURRENT_OPS = (OperatorType.OP_GATED_DELTA_NET, OperatorType.OP_MAMBA2)
+
+
 def _recurrent_state_traffic(op: PCGOp) -> float:
     """Bytes of per-slot recurrent state one decode step of a gated
-    delta-rule op reads AND writes, for the whole batch: the float32 state
-    matrices and the convolution's tail (its length-independent stand-in
-    for the keys and values an attention op re-reads)."""
-    from ..ops.linear_attention import state_bytes
+    delta-rule or state-space op reads AND writes, for the whole batch: the
+    float32 state matrices and the convolution's tail (its length-
+    independent stand-in for the keys and values an attention op
+    re-reads)."""
+    from ..ops import linear_attention, state_space
 
+    state_bytes = {
+        OperatorType.OP_GATED_DELTA_NET: linear_attention.state_bytes,
+        OperatorType.OP_MAMBA2: state_space.state_bytes}[op.op_type]
     batch = op.inputs[0].material_shape()[0]
     return 2.0 * batch * state_bytes(
         op.params, op.inputs[0].effective_itemsize())
@@ -572,8 +603,8 @@ class CostModel:
             )
             kv = sum(_vol(x.material_shape()) * x.effective_itemsize()
                      for x in op.inputs[1:3])
-            membytes += kv / max(1, batch_deg * head_deg)
-        if op.op_type == OperatorType.OP_GATED_DELTA_NET:
+            membytes += kv / max(1, batch_deg * head_deg) / op.params.group
+        if op.op_type in _RECURRENT_OPS:
             # over the batch alone: the op's weights are not head-sharded
             membytes += _recurrent_state_traffic(op) / batch_deg
         for x in list(op.inputs) + list(op.outputs):
