@@ -893,10 +893,12 @@ class AdmissionQueue:
 # continuous (in-flight) batching
 # ----------------------------------------------------------------------
 @jax.jit
-def _best_id(logits):
-    """The best id of a batch-1, one-position output (1, 1, vocab), picked
-    on the device: the host fetches a scalar."""
-    return jnp.argmax(logits[0, 0])
+def _best_ids(logits):
+    """The best id of every row of a step's one-position output
+    (rows, 1, vocab), picked on the device: the host fetches `rows` ids,
+    not `rows x vocab` logits. Greedy, and numpy's answer: the lowest
+    index wins a tie."""
+    return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
 
 
 @dataclasses.dataclass
@@ -922,7 +924,10 @@ class ContinuousBatcher:
          prefilled through a batch-1 decode step bucketed to powers of
          two (bounds recompilation), and the prefilled cache strip
          inserted into the running batch;
-      3. run ONE batched decode step for every active slot.
+      3. run ONE batched decode step for every active slot and, behind
+         it on the device, pick each row's best id: the iteration's one
+         sync fetches `slots` ids (and the step's counters), never the
+         slots x vocabulary logits.
 
     Decoder-only models only (one graph input): encoder-decoder graphs
     compute per-request encoder statics that a shared running batch
@@ -1049,6 +1054,9 @@ class ContinuousBatcher:
                       "decode_s": 0.0, "decode_prepare_s": 0.0,
                       "decode_dispatch_s": 0.0, "decode_wait_s": 0.0,
                       "decode_fetch_s": 0.0, "decode_sample_s": 0.0,
+                      # bytes the decode iterations fetched, summed: over
+                      # "iterations" it is 4 x slots and a few scalars
+                      "decode_fetch_bytes": 0,
                       "idle_s": 0.0, "prefill_tokens": 0,
                       "prefill_bucket_tokens": 0,
                       # bucket - prompt, summed: positions a prefill ran
@@ -1322,8 +1330,8 @@ class ContinuousBatcher:
                 [jnp.asarray(padded)], jnp.int32(plen), jnp.int32(plen - 1),
             )
             # the step put out the one row needed, the last real token's,
-            # not bucket x vocabulary of them; its best id is a scalar
-            first = int(_best_id(logits))
+            # not bucket x vocabulary of them; its best id is one number
+            first = int(jax.device_get(_best_ids(logits))[0])
         return first, caches1
 
     def _insert_slot(self, slot_idx: int, caches1) -> None:
@@ -1368,20 +1376,21 @@ class ContinuousBatcher:
                            "appends in place, 0 where it copies them first",
                       replica=self.name)
 
-    def _note_step_counters(self, counted) -> None:
-        """Fold one decode step's counters (parallel/decode.py, the caches'
-        "counters" section: whatever the graph's ops report, by name) into
-        `stats` and the session's `ff_serving_*` gauges: a name that ends
-        in "_max" keeps the largest a step has seen, any other sums."""
-        from .. import obs
-
-        for name, value in counted.items():
+    def _step_counts(self, fetched) -> dict:
+        """What one decode iteration adds to `stats`, as the values to set:
+        the bytes it brought to the host (`slots` ids and the step's
+        counters, whatever the vocabulary) and the step's counters folded
+        (parallel/decode.py, the caches' "counters" section: whatever the
+        graph's ops report, by name): a name that ends in "_max" keeps the
+        largest a step has seen, any other sums."""
+        stats = self.stats
+        new = {"decode_fetch_bytes": stats["decode_fetch_bytes"] + sum(
+            leaf.nbytes for leaf in jax.tree_util.tree_leaves(fetched))}
+        for name, value in fetched[1].items():
             value = int(value)
-            self.stats[name] = max(self.stats[name], value) \
-                if name.endswith("_max") else self.stats[name] + value
-            obs.gauge_set("ff_serving_" + name, self.stats[name],
-                          help="what the decode steps' ops counted under "
-                               "this name", replica=self.name)
+            new[name] = max(stats[name], value) if name.endswith("_max") \
+                else stats[name] + value
+        return new
 
     # -- retirement ------------------------------------------------------
     def _release(self, slot_idx: int) -> None:
@@ -1451,7 +1460,10 @@ class ContinuousBatcher:
             self._finish_slot(slot_idx)
 
     # -- the iteration loop ---------------------------------------------
-    def _decode_iteration(self) -> None:
+    def _decode_iteration(self) -> dict:
+        """One batched step for every active slot; returns what it adds
+        to `stats` (`_step_counts`), which the loop folds in with its count
+        of the iteration."""
         from .. import obs
 
         stats = self.stats
@@ -1485,20 +1497,24 @@ class ContinuousBatcher:
                         self.model.state.params, self._caches,
                         jnp.asarray(t_vec), [jnp.asarray(toks)],
                     )
+                    # each row's next token is picked behind the step, on
+                    # the device: both enqueue, neither waits
+                    ids = _best_ids(logits)
                 # ONE sync, split in two: the wait for the device, then
-                # the copy of the slots x vocabulary logits to the host
+                # the copy of `slots` ids to the host. The logits stay
+                # where they were made
                 with obs.mark("ff.serve.decode.wait", cat="serving",
                               into=(stats, "decode_wait_s")):
-                    jax.block_until_ready(logits)
+                    jax.block_until_ready(ids)
                 with obs.mark("ff.serve.decode.fetch", cat="serving",
                               into=(stats, "decode_fetch_s")) as fetch:
                     # what the step's ops counted (a few scalars, or
-                    # nothing) rides the fetch of its logits
-                    logits, counted = jax.device_get(
-                        (logits, self._caches["counters"]))
-            self._note_step_counters(counted)
+                    # nothing) rides the fetch of its ids
+                    fetched = jax.device_get(
+                        (ids, self._caches["counters"]))
+            ids = fetched[0].tolist()
             # a sampled request's share of the iteration: from the
-            # ff.serve.decode span's start to the logits on the host
+            # ff.serve.decode span's start to the ids on the host
             span_dur = fetch.t0 + fetch.dur - span.t0
             occupancy = len(active)
             with obs.mark("ff.serve.decode.sample", cat="serving",
@@ -1507,7 +1523,7 @@ class ContinuousBatcher:
                     slot = self.slots[i]
                     if slot is None:
                         continue  # taken by a concurrent teardown sweep mid-step
-                    slot.tokens.append(int(logits[i, 0].argmax(-1)))
+                    slot.tokens.append(ids[i])
                     slot.req.token_t.append(time.monotonic())
                     slot.pos += 1
                     new_pages = self.pool.touch(
@@ -1529,29 +1545,38 @@ class ContinuousBatcher:
                                                  pages=len(new_pages),
                                                  pos=slot.pos)
                     self._maybe_retire(i)
+        return self._step_counts(fetched)
 
     def _warmup_compiles(self) -> None:
-        """Compile the batched decode step and every prefill bucket on
-        throwaway caches before taking traffic. Runs on the serve thread
+        """Compile the batched decode step, the pick of its rows' best
+        ids and every prefill bucket on throwaway caches before taking
+        traffic. Runs on the serve thread
         under the HealthMonitor's compile grace window; the running batch
         then never waits on XLA mid-request."""
         params = self.model.state.params
         with self._device_lock:
-            caches = self._initB(params, ())
-            t_vec = jnp.zeros((self.config.slots,), jnp.int32)
-            toks = jnp.zeros((self.config.slots, 1), self._id_dt)
-            self._stepB(params, caches, t_vec, [toks])
             b = 1
             while True:
                 caches1 = self._init1(params, ())
-                logits, _ = self._step1(
+                logits, caches1 = self._step1(
                     params, caches1, jnp.int32(0),
                     [jnp.zeros((1, b), self._id_dt)], jnp.int32(b),
                     jnp.int32(b - 1))
-                _best_id(logits)
+                _best_ids(logits)
                 if b >= self.config.max_len:
                     break
                 b = min(2 * b, self.config.max_len)
+            # the batched step and its pick, on caches made as the served
+            # ones are (a prefilled strip inserted into a fresh batch): the
+            # first served iteration then finds both programs built. The
+            # prefills above were only enqueued: the batch is made once they
+            # are done, as an admission makes it, so that the device never
+            # holds their temporaries beside both generations of a leaf
+            jax.block_until_ready(caches1)
+            caches = decode.insert_row(self._initB(params, ()), caches1, 0)
+            t_vec = jnp.zeros((self.config.slots,), jnp.int32)
+            toks = jnp.zeros((self.config.slots, 1), self._id_dt)
+            _best_ids(self._stepB(params, caches, t_vec, [toks])[0])
 
     def _strand_slots(self) -> int:
         """Hand every occupied slot back to the shared queue (or shed it
@@ -1724,7 +1749,7 @@ class ContinuousBatcher:
                         # stalls INSIDE the monitored step window so the
                         # HealthMonitor watchdog sees a hung step
                         time.sleep(float(plan.get("delay_s", 1.0)))
-                self._decode_iteration()
+                counts = self._decode_iteration()
                 dt = time.monotonic() - t0
                 if self.monitor is not None:
                     self.monitor.step_finished(it)
@@ -1735,7 +1760,15 @@ class ContinuousBatcher:
                     else 0.8 * self._token_ewma_s + 0.2 * dt
                 )
                 self._iteration += 1
-                self.stats["iterations"] += 1
+                # ONE update: whoever a finished request wakes reads
+                # `stats` of whole iterations, never a step's counters
+                # without its count
+                self.stats.update(
+                    counts, iterations=self.stats["iterations"] + 1)
+                for name, value in counts.items():
+                    obs.gauge_set("ff_serving_" + name, value,
+                                  help="what the decode iterations counted "
+                                       "under this name", replica=self.name)
                 obs.gauge_set("ff_serving_batch_occupancy",
                               self.active_slots,
                               help="occupied decode slots", replica=self.name)

@@ -118,6 +118,8 @@ def test_serve_loop_phase_counters_and_token_stamps(lm):
                             "decode_wait_s", "decode_fetch_s",
                             "decode_sample_s")]
     assert all(p > 0 for p in parts) and s["decode_s"] >= sum(parts)
+    # what the iterations fetched: 2 slots' ids, this graph counts nothing
+    assert s["decode_fetch_bytes"] == 8 * s["iterations"]
     assert s["idle_s"] > 0
     # prompts of 3, 5 and 2 tokens in buckets of 4, 8 and 2
     assert (s["prefill_tokens"], s["prefill_bucket_tokens"]) == (10, 14)
@@ -148,6 +150,9 @@ def test_serve_loop_spans_are_on_the_serve_threads_line(lm, tmp_path):
     assert str(admit["skipped"]) in ("False", "0")
     assert names["ff.serve.prefill"][0]["request"] == reqs[0].id
     assert [d["iteration"] for d in names["ff.serve.decode"]] == [0, 1]
+    # each part once an iteration, with the pick on the device too
+    for part in ("prepare", "dispatch", "wait", "fetch", "sample"):
+        assert len(names[f"ff.serve.decode.{part}"]) == 2, part
     assert names["ff.serve.decode"][0]["occupancy"] == 1
 
 
