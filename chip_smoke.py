@@ -68,6 +68,11 @@ if REHEARSAL:
     SSM_SIZES, SSM_BLOCKS = (4, 8, 2, 16, 16), [(32, 20), (8, 8), (48, 33)]
     # expert bank: (hidden, experts, held, top k, width, shared width), tokens
     BANK_SIZES, BANK_TOKENS = (32, 8, 4, 2, 24, 48), [4, 50]
+    # window attention: (query heads, key-value heads, head size, window,
+    # slots), the prefill buckets, the position whose YaRN angles are checked
+    WINDOW_SIZES, WINDOW_BUCKETS, YARN_POSITION = (6, 2, 64, 16, 4), [64], 63
+    YARN = dict(theta=500000.0, dim=32, scaling="yarn", factor=128.0,
+                original_max_position_embeddings=16)
 else:
     HIDDEN, HEADS, LAYERS, SEQ, BATCH = 1024, 16, 12, 512, 8
     VOCAB, MAX_LEN, SLOTS = 32000, 1024, 8
@@ -95,6 +100,15 @@ else:
     SSM_SIZES = (64, 64, 8, 128, 128)
     SSM_BLOCKS = [(32, 32), (64, 40), (256, 200), (1024, 777)]
     BANK_SIZES, BANK_TOKENS = (2688, 128, 64, 6, 1856, 3712), [64, 1024]
+    # the window-attention serving cell's sizes: 72 query heads over 8
+    # key-value heads of 128, window 512 (a ring of 32 pages), 32 slots; the
+    # shortest and the longest bucket a prompt of 128..6,144 takes a banded
+    # prefill at; a full layer's YaRN at the cell's last position
+    WINDOW_SIZES, WINDOW_BUCKETS, YARN_POSITION = \
+        (72, 8, 128, 512, 32), [1024, 8192], 8191
+    YARN = dict(theta=500000.0, dim=64, scaling="yarn", factor=128.0,
+                original_max_position_embeddings=8192,
+                attention_factor=1.4852030263919618)
 HEAD_DIM = HIDDEN // HEADS
 PAGE = 16
 NEW_TOKENS = 8
@@ -351,6 +365,84 @@ def phase_kernels(ctx):
         log(f"  {name}: rel err = {err:.1e}")
         assert np.isfinite(err) and err < KERNEL_TOL, name
         ctx["kernels"].append(name)
+
+    # window attention (ops/attention.py): the paged kernel on a ring pool,
+    # rings full and filling; the banded prefill over a bucket, behind a
+    # ring's worth of positions below 0 as a first block meets them; YaRN's
+    # angles at the last position
+    from flexflow_tpu.kernels.attention import _chunk_scan
+    from flexflow_tpu.ops.attention import RotaryParams, apply_rotary
+
+    heads, kv, d, window, slots = WINDOW_SIZES
+    ring_pages = window // PAGE
+    dtype = jnp.float32 if REHEARSAL else jnp.bfloat16
+    q = jnp.asarray(rng.randn(slots, heads, d), dtype)
+    kp, vp = (jnp.asarray(rng.randn(slots * ring_pages, PAGE, kv, d), dtype)
+              for _ in range(2))
+    table = jnp.asarray(rng.permutation(slots * ring_pages)
+                        .reshape(slots, ring_pages), jnp.int32)
+    # min(t + 1, ring): half the slots past the window, the rest on the way
+    lengths = jnp.asarray([window if i % 2 else 1 + (i * 37) % window
+                           for i in range(slots)], jnp.int32)
+    out = jax.jit(lambda *a: paged_flash_decode(
+        *a, interpret=interpret))(q, kp, vp, table, lengths)
+    with jax.default_matmul_precision("highest"):
+        want = paged_decode_reference(q, kp, vp, table, lengths)
+    err = rel_err(out, want)
+    name = (f"paged_flash_decode ring slots={slots} heads={heads}/{kv} "
+            f"d{d} ring={window} page={PAGE} {jnp.dtype(dtype).name}")
+    log(f"  {name}: rel err = {err:.1e}")
+    assert np.isfinite(err) and err < KERNEL_TOL, name
+    ctx["kernels"].append(name)
+
+    for bucket in WINDOW_BUCKETS:
+        q = jnp.asarray(rng.randn(1, bucket, heads, d), dtype)
+        k, v = (jnp.asarray(rng.randn(1, window + bucket, heads, d), dtype)
+                for _ in range(2))
+        out = jax.jit(lambda q, k, v: _chunk_scan(
+            q, k, v, causal=True, chunk_size=min(256, window), q_offset=0,
+            kv_offset=-window, window=window)[0])(q, k, v)
+        errs = []
+        # the first rows, rows across a block's edge, the last rows
+        for lo in sorted({0, bucket // 2 - 3, bucket - min(128, bucket)}):
+            hi = min(lo + 128, bucket)
+            with jax.default_matmul_precision("highest"):
+                f32 = jnp.float32
+                rows = jnp.arange(lo, hi)[:, None]
+                cols = jnp.arange(-window, bucket)[None, :]
+                seen = (cols <= rows) & (cols > rows - window) & (cols >= 0)
+                sc = jnp.einsum("bqhd,bkhd->bhqk", q[:, lo:hi].astype(f32),
+                                k.astype(f32)) / math.sqrt(d)
+                pr = jax.nn.softmax(jnp.where(seen, sc, -1e30), -1)
+                want = jnp.einsum("bhqk,bkhd->bqhd", pr, v.astype(f32))
+            errs.append(rel_err(out[:, lo:hi], want))
+        name = (f"banded prefill bucket={bucket} heads={heads} d{d} "
+                f"window={window} {jnp.dtype(dtype).name}")
+        log(f"  {name}: rel err of the row blocks = "
+            + "/".join(f"{e:.1e}" for e in errs))
+        assert all(np.isfinite(e) and e < KERNEL_TOL for e in errs), name
+        ctx["kernels"].append(name)
+
+    rope = RotaryParams(**YARN)
+    x = rng.randn(1, 2, 4, d).astype(np.float32)
+    at = np.array([YARN_POSITION - 1, YARN_POSITION])
+    got = np.asarray(jax.jit(lambda x, p: apply_rotary(rope, x, p))(
+        jnp.asarray(x), jnp.asarray(at)))
+    from flexflow_tpu.ops.attention import rotary_table
+
+    inv, factor = rotary_table(rope, d)
+    ang = at[:, None].astype(np.float64) * inv.astype(np.float64)
+    cos, sin = (np.concatenate([f(ang), f(ang)], -1)[None, :, None, :] * factor
+                for f in (np.cos, np.sin))
+    rot = 2 * len(inv)
+    xr = x[..., :rot].astype(np.float64)
+    half = np.concatenate([-xr[..., rot // 2:], xr[..., :rot // 2]], -1)
+    want = np.concatenate([xr * cos + half * sin, x[..., rot:]], -1)
+    err = rel_err(got, want)
+    name = f"yarn rotary dim={rot} of d{d} at position {YARN_POSITION}"
+    log(f"  {name}: rel err = {err:.1e}")
+    assert np.isfinite(err) and err < KERNEL_TOL, name
+    ctx["kernels"].append(name)
 
 
 # ---------------------------------------------------------------------------

@@ -369,11 +369,23 @@ class FFModel:
         qk_norm: bool = False,
         qk_norm_eps: float = 1e-6,
         num_kv_heads: int = 0,
+        rope=None,
+        window: int = 0,
+        head_gate: bool = False,
         name: str = "",
     ) -> Tensor:
         """`num_kv_heads` (0 = `num_heads`): grouped-query attention, query
         head i reading key-value head i // (num_heads / num_kv_heads); the
-        decode cache holds the key-value heads alone."""
+        decode cache holds the key-value heads alone. `rope` (a
+        `RotaryParams`, or a dict of its fields: theta, dim, scaling,
+        factor, ...): q and k rotated by position. `window` (causal ops):
+        query i sees keys i - window < j <= i, and the decode cache is a
+        ring of the window's positions. `head_gate`: head h's output times
+        sigmoid(x wg)[h] (weight `wg`, embed x heads)."""
+        from ..ops.attention import RotaryParams
+
+        if isinstance(rope, dict):
+            rope = RotaryParams(**rope)
         p = MultiHeadAttentionParams(
             embed_dim=embed_dim,
             num_heads=num_heads,
@@ -387,9 +399,12 @@ class FFModel:
             qk_norm=qk_norm,
             qk_norm_eps=qk_norm_eps,
             num_kv_heads=0 if num_kv_heads == num_heads else num_kv_heads,
+            rope=rope,
+            window=window,
+            head_gate=head_gate,
         )
         inits = (
-            {k: kernel_initializer for k in ("wq", "wk", "wv", "wo")}
+            {k: kernel_initializer for k in ("wq", "wk", "wv", "wo", "wg")}
             if kernel_initializer
             else None
         )
@@ -679,24 +694,31 @@ class FFModel:
     def expert_bank(self, input: Tensor, experts: int, top_k: int, width: int,
                     held=None, shared_width: int = 0, scale: float = 1.0,
                     norm_topk: bool = True, act="relu2",
+                    router: str = "sigmoid",
                     kernel_initializer=None, name="") -> Tensor:
-        """One expert layer as ONE op: a sigmoid router over all `experts`
-        (top `top_k` a token, no capacity, no dropped token), the routed
+        """One expert layer as ONE op: a router over all `experts`
+        (`router`: "sigmoid" scores or their "softmax"; top `top_k` a token,
+        no capacity, no dropped token), the routed
         experts `held` = (first, one past the last) that live on this chip
         (None: all of them), and a shared expert
         of `shared_width` (0: none). What the experts elsewhere would add is
         left out: expert parallelism's share of the layer, without its
-        exchange (ops/moe.py)."""
+        exchange (ops/moe.py). `act` "silu_gated" makes the experts (and the
+        shared one) gated: silu(x w_gate) * (x w_up) into w_down."""
         from ..ops.moe import ExpertBankParams
 
         lo, hi = held if held is not None else (0, experts)
+        gated = act == "silu_gated"
         p = ExpertBankParams(
             experts=experts, held_from=lo, held_count=hi - lo, top_k=top_k,
             width=width, shared_width=shared_width, scale=scale,
-            norm_topk=norm_topk, activation=_to_acti(act))
+            norm_topk=norm_topk,
+            activation=_to_acti("silu" if gated else act), router=router,
+            gated=gated)
         inits = (
             {k: kernel_initializer for k in
-             ("router", "w_up", "w_down", "shared_up", "shared_down")}
+             ("router", "w_up", "w_down", "w_gate", "shared_up",
+              "shared_down", "shared_gate")}
             if kernel_initializer else None
         )
         return self._add_layer(OperatorType.OP_EXPERT_BANK, p, [input], name,

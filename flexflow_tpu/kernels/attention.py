@@ -58,9 +58,60 @@ def out_struct(shape, dtype, like):
     return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
+def _band_scan(q, k, v, *, window: int, block: int, q_offset, kv_offset):
+    """_chunk_scan under a window (query at position p sees keys at
+    p - window < j <= p, and none below position 0): the walk is over
+    blocks of `block` queries, and each meets only the window + block keys
+    its band reaches, one softmax a block, so what lies wholly outside the
+    band is never computed. Offsets may be traced."""
+    b, sq, h, d = q.shape
+    sk, dv = k.shape[1], v.shape[-1]
+    n_blocks = -(-sq // block)
+    pad = n_blocks * block - sq
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+    span = min(sk, window + block)
+    scale = 1.0 / math.sqrt(d)
+    qb = q.reshape(b, n_blocks, block, h, d).transpose(1, 0, 2, 3, 4)
+
+    def body(inputs):
+        i, q_blk = inputs
+        q_pos = q_offset + i * block + jnp.arange(block)
+        # the first key the block's first query sees, held inside the keys
+        # there are: the mask below goes by true positions
+        start = jnp.clip(q_offset - kv_offset + i * block - window + 1,
+                         0, sk - span)
+        k_blk = lax.dynamic_slice_in_dim(k, start, span, axis=1)
+        v_blk = lax.dynamic_slice_in_dim(v, start, span, axis=1)
+        kv_pos = kv_offset + start + jnp.arange(span)
+        s = jnp.einsum("bqhd,bkhd->bhqk", q_blk, k_blk,
+                       preferred_element_type=jnp.float32) * scale
+        mask = (kv_pos[None, :] <= q_pos[:, None]) & (kv_pos[None, :] >= 0) \
+            & (kv_pos[None, :] > q_pos[:, None] - window)
+        s = jnp.where(mask[None, None], s, NEG_INF)
+        m = jnp.max(s, axis=-1)
+        p = jnp.exp(s - m[..., None])
+        l = jnp.sum(p, axis=-1)
+        acc = jnp.einsum("bhqk,bkhd->bqhd", p.astype(v.dtype), v_blk,
+                         preferred_element_type=jnp.float32)
+        out = acc / jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
+        return out.astype(q.dtype), m, l
+
+    out, m, l = lax.map(body, (jnp.arange(n_blocks), qb))
+    out = out.transpose(1, 0, 2, 3, 4).reshape(b, n_blocks * block, h, dv)
+    m, l = (a.transpose(1, 2, 0, 3).reshape(b, h, -1)[..., :sq]
+            for a in (m, l))
+    return out[:, :sq], m, l
+
+
 def _chunk_scan(q, k, v, *, causal: bool, chunk_size: int, q_offset=0,
-                kv_offset=0):
-    """Online-softmax accumulation over KV chunks. q: (b, sq, h, d)."""
+                kv_offset=0, window: int = 0):
+    """Online-softmax accumulation over KV chunks. q: (b, sq, h, d). With
+    a `window` (causal only) the walk is _band_scan's."""
+    if window:
+        assert causal, "a window is a band under the causal mask"
+        return _band_scan(q, k, v, window=window, block=chunk_size,
+                          q_offset=q_offset, kv_offset=kv_offset)
     b, sq, h, d = q.shape
     sk = k.shape[1]
     dv = v.shape[-1]                  # v_head_dim may differ from qk's d
@@ -109,10 +160,12 @@ def _chunk_scan(q, k, v, *, causal: bool, chunk_size: int, q_offset=0,
     return out.transpose(0, 2, 1, 3).astype(q.dtype), m, l
 
 
-def chunked_attention(q, k, v, *, causal: bool = False, chunk_size: int = 256):
+def chunked_attention(q, k, v, *, causal: bool = False, chunk_size: int = 256,
+                      window: int = 0):
     """Memory-efficient exact attention. (b, s, h, d) -> (b, s, h, d)."""
     out, _, _ = _chunk_scan(q, k, v, causal=causal,
-                            chunk_size=min(chunk_size, k.shape[1]))
+                            chunk_size=min(chunk_size, k.shape[1]),
+                            window=window)
     return out
 
 
@@ -204,6 +257,8 @@ def _keep_tile(seed_ref, row_u, sq: int, sk: int, q_off: int, kv_off: int,
 #              exactly, so it is never computed: no dot, no exp, no mask;
 #   "masked"   the diagonal crosses it: computed, and masked;
 #   "full"     every pair unmasked: computed, and not masked.
+# Under a window (kv_pos > q_pos - window besides) the unmasked pairs are
+# a band, and a tile wholly below it is skipped like one above the diagonal.
 # Shapes are static, so the walk is a Python loop unrolled at trace time.
 
 def _axis_blocks(n: int, block: int):
@@ -212,20 +267,23 @@ def _axis_blocks(n: int, block: int):
     return [(s, min(s + block, n)) for s in range(0, n, block)]
 
 
-def _tile_state(q0: int, q1: int, k0: int, k1: int, causal: bool) -> str:
+def _tile_state(q0: int, q1: int, k0: int, k1: int, causal: bool,
+                window: int = 0) -> str:
     """How the mask meets the tile of queries [q0, q1) and keys [k0, k1)."""
-    if not causal or k1 - 1 <= q0:
+    if not causal:
         return "full"
-    if k0 > q1 - 1:
-        return "skipped"
+    if k0 > q1 - 1 or (window and k1 - 1 <= q0 - window):
+        return "skipped"  # above the diagonal, or wholly below the band
+    if k1 - 1 <= q0 and (not window or k0 > q1 - 1 - window):
+        return "full"
     return "masked"
 
 
 def flash_tile_counts(seq_q: int, seq_k: int, block_q: int, block_k: int,
-                      causal: bool):
+                      causal: bool, window: int = 0):
     """(computed, skipped) tiles of one row: what ff_flash_tiles_total
     counts for each kernel built."""
-    states = [_tile_state(*qb, *kb, causal)
+    states = [_tile_state(*qb, *kb, causal, window)
               for qb in _axis_blocks(seq_q, block_q)
               for kb in _axis_blocks(seq_k, block_k)]
     skipped = states.count("skipped")
@@ -235,10 +293,12 @@ def flash_tile_counts(seq_q: int, seq_k: int, block_q: int, block_k: int,
 def _walk(outer, inner, state_of):
     """[(outer block, reach)]: what each block of the outer axis computes
     along the inner one. Along either axis the states run full.. masked..
-    skipped (or the reverse), so the computed blocks are one stretch:
+    skipped (or the reverse; under a window skipped.. masked.. full..
+    masked.. skipped), so the computed blocks are one stretch:
     reach = (lo, hi, mask_lo, mask_hi), one dot over inner positions
-    [lo, hi) of which [mask_lo, mask_hi) needs the mask, or None where
-    the outer block computes nothing."""
+    [lo, hi) of which [mask_lo, mask_hi) needs the mask (under a window it
+    spans both edges of the band, and the full blocks between them are
+    masked to no effect), or None where the outer block computes nothing."""
     plan = []
     for ob in outer:
         states = [(ib, state_of(ob, ib)) for ib in inner]
@@ -263,7 +323,8 @@ def _default_blocks(seq_q: int, seq_k: int, causal: bool):
             block if seq_k % block == 0 else seq_k)
 
 
-def _resolve_blocks(which: str, seq_q, seq_k, causal, block_q, block_k):
+def _resolve_blocks(which: str, seq_q, seq_k, causal, block_q, block_k,
+                    window: int = 0):
     """(block_q, block_k): the caller's, else from the shape; counted in
     ff_flash_tiles_total{pass=which}, a Python side effect where a kernel
     goes into a program: once a trace and never an execution (as
@@ -272,7 +333,8 @@ def _resolve_blocks(which: str, seq_q, seq_k, causal, block_q, block_k):
 
     from_shape = _default_blocks(seq_q, seq_k, causal)
     block_q, block_k = block_q or from_shape[0], block_k or from_shape[1]
-    counts = flash_tile_counts(seq_q, seq_k, block_q, block_k, causal)
+    counts = flash_tile_counts(seq_q, seq_k, block_q, block_k, causal,
+                               window)
     for state, n in zip(("computed", "skipped"), counts):
         obs.count("ff_flash_tiles_total", n,
                   help="(query block, key block) tiles of one row program "
@@ -285,16 +347,19 @@ def _resolve_blocks(which: str, seq_q, seq_k, causal, block_q, block_k):
 # Pallas flash-attention forward
 # ---------------------------------------------------------------------------
 
-def _causal_mask(s, *, q_offset: int, kv_offset: int):
-    """Apply the causal mask to the score tile whose first element is
-    (q_offset, kv_offset); forward and backward share it so they can
-    never disagree."""
+def _causal_mask(s, *, q_offset: int, kv_offset: int, window: int = 0):
+    """Apply the causal mask (under a window, the band) to the score tile
+    whose first element is (q_offset, kv_offset); forward and backward
+    share it so they can never disagree."""
     q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
     kv_pos = kv_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    return jnp.where(kv_pos <= q_pos, s, NEG_INF)
+    seen = kv_pos <= q_pos
+    if window:
+        seen = seen & (kv_pos > q_pos - window)
+    return jnp.where(seen, s, NEG_INF)
 
 
-def _mask_stretch(s, axis: int, reach, other_offset: int):
+def _mask_stretch(s, axis: int, reach, other_offset: int, window: int = 0):
     """Mask the part [mask_lo, mask_hi) of the score tile `s`, which
     spans [lo, hi) along `axis` (0: queries, 1: keys) and starts at
     `other_offset` along the other: only the blocks the diagonal crosses
@@ -310,7 +375,7 @@ def _mask_stretch(s, axis: int, reach, other_offset: int):
             q_offset, kv_offset = ((a, other_offset) if axis == 0
                                    else (other_offset, a))
             part = _causal_mask(part, q_offset=q_offset,
-                                kv_offset=kv_offset)
+                                kv_offset=kv_offset, window=window)
         parts.append(part)
     return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis)
 
@@ -343,7 +408,7 @@ def _for_each_row(g: int, body):
 
 
 def _flash_fwd_kernel(*refs, scale: float, g: int, plan,
-                      dropout: float = 0.0):
+                      dropout: float = 0.0, window: int = 0):
     """One program = g (batch*head) rows (_for_each_row: they amortize
     the per-program overhead). Q/K/V of the whole row are VMEM resident (the
     fused path is capped to shapes where that holds). `plan` gives each
@@ -375,7 +440,7 @@ def _flash_fwd_kernel(*refs, scale: float, g: int, plan,
             q_ref[i, q0:q1], k_ref[i, k0:k1], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                         # (block_q, k1 - k0) f32
-        return _mask_stretch(s, 1, reach, q0)
+        return _mask_stretch(s, 1, reach, q0, window)
 
     def finish(i, step, s):
         (q0, q1), reach = step
@@ -396,13 +461,13 @@ def _flash_fwd_kernel(*refs, scale: float, g: int, plan,
         # seq_q) satisfies the Mosaic (sublane, lane) tiling rule
         lse_ref[i, :, q0:q1] = (m + jnp.log(l)).T
 
-    # every query row sees key 0, so no query block's reach is None
+    # every query row sees its own key, so no query block's reach is None
     _for_each_row(g, lambda i: _staged(plan, functools.partial(scores, i),
                                        functools.partial(finish, i)))
 
 
 def _flash_bwd_kernel(*refs, scale: float, g: int, plan,
-                      dropout: float = 0.0):
+                      dropout: float = 0.0, window: int = 0):
     """Fused dq/dk/dv for g (batch*head) rows in ONE program: the prob
     tile is recomputed from q/k and the saved lse exactly once, delta =
     rowsum(do*o) is computed in VMEM, and the transposed contractions for
@@ -440,7 +505,7 @@ def _flash_bwd_kernel(*refs, scale: float, g: int, plan,
             q_ref[i, q0:q1], k_ref[i, k0:k1], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale                         # (q1 - q0, block_k)
-        s = _mask_stretch(s, 0, reach, k0)
+        s = _mask_stretch(s, 0, reach, k0, window)
         dp = jax.lax.dot_general(
             do_ref[i, q0:q1], v_ref[i, k0:k1], (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -468,7 +533,7 @@ def _flash_bwd_kernel(*refs, scale: float, g: int, plan,
         dsb = (p * (dp - delta[q0:q1])).astype(q.dtype)
         dq = jnp.dot(dsb, k_ref[i, k0:k1],
                      preferred_element_type=jnp.float32)
-        if k0 == 0:
+        if k0 == 0 and not window:
             # every query sees key 0: the first key block's stretch is
             # the whole axis, and sets what the later ones add to
             dq_acc[...] = dq
@@ -491,6 +556,8 @@ def _flash_bwd_kernel(*refs, scale: float, g: int, plan,
             axis=-1, keepdims=True,
         )                                 # (seq_q, 1)
         lse_col = lse_ref[i].T            # stored (1, seq_q), lanes-major
+        if window:  # no key block is seen by every query: start from zero
+            dq_acc[...] = jnp.zeros_like(dq_acc)
         _staged(plan, functools.partial(scores, i),
                 functools.partial(finish, i, delta, lse_col))
         dq_ref[i] = (dq_acc[...] * scale).astype(dq_ref.dtype)
@@ -538,24 +605,25 @@ def _pick_g(bh: int, tile: int, budget: int) -> int:
 # each of 24 layers, forward and backward, in each build of the train step
 # cost 10 s of set-up that no compile cache gives back. Inlined, so the
 # program XLA gets is the one it would get without the jit.
-_STATIC = ("causal", "interpret", "dropout", "block_q", "block_k")
+_STATIC = ("causal", "interpret", "dropout", "block_q", "block_k", "window")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _flash_fwd_call(qf, kf, vf, seeds, *, causal: bool, interpret: bool,
-                    dropout: float, block_q: int, block_k: int):
+                    dropout: float, block_q: int, block_k: int,
+                    window: int = 0):
     bh, sq, d = qf.shape
     sk = kf.shape[1]
     dv = vf.shape[-1]                 # v_head_dim may differ from qk's d
     plan = _walk(_axis_blocks(sq, block_q), _axis_blocks(sk, block_k),
-                 lambda qb, kb: _tile_state(*qb, *kb, causal))
+                 lambda qb, kb: _tile_state(*qb, *kb, causal, window))
     # live at once: a query block's scores against every key it attends
     tile = max((q1 - q0) * (reach[1] - reach[0])
                for (q0, q1), reach in plan)
     g = _pick_g(bh, tile, budget=2 * 1024 * 1024)
     scale = 1.0 / math.sqrt(d)
     kernel = functools.partial(_flash_fwd_kernel, scale=scale, g=g,
-                               plan=plan, dropout=dropout)
+                               plan=plan, dropout=dropout, window=window)
     in_specs = [
         pl.BlockSpec((g, sq, d), lambda i: (i, 0, 0)),
         pl.BlockSpec((g, sk, d), lambda i: (i, 0, 0)),
@@ -587,24 +655,24 @@ def _flash_fwd_call(qf, kf, vf, seeds, *, causal: bool, interpret: bool,
 
 def _flash_fwd_folded(qf, kf, vf, *, causal: bool, interpret: bool,
                       dropout: float = 0.0, seeds=None, block_q=None,
-                      block_k=None):
+                      block_k=None, window: int = 0):
     """Core forward on (b*h, s, d) folded operands."""
     block_q, block_k = _resolve_blocks("fwd", qf.shape[1], kf.shape[1],
-                                       causal, block_q, block_k)
+                                       causal, block_q, block_k, window)
     return _flash_fwd_call(qf, kf, vf, seeds, causal=causal,
                            interpret=interpret, dropout=dropout,
-                           block_q=block_q, block_k=block_k)
+                           block_q=block_q, block_k=block_k, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC, inline=True)
 def _flash_bwd_call(qf, kf, vf, of, lse, dof, seeds, *, causal: bool,
                     interpret: bool, dropout: float, block_q: int,
-                    block_k: int):
+                    block_k: int, window: int = 0):
     bh, sq, d = qf.shape
     sk = kf.shape[1]
     dv_d = vf.shape[-1]               # v_head_dim may differ from qk's d
     plan = _walk(_axis_blocks(sk, block_k), _axis_blocks(sq, block_q),
-                 lambda kb, qb: _tile_state(*qb, *kb, causal))
+                 lambda kb, qb: _tile_state(*qb, *kb, causal, window))
     # live at once: the s, p, dp and ds tiles of one key block
     tile = 4 * max((k1 - k0) * (reach[1] - reach[0])
                    for (k0, k1), reach in plan if reach)
@@ -624,7 +692,7 @@ def _flash_bwd_call(qf, kf, vf, of, lse, dof, seeds, *, causal: bool,
         args = args + (jnp.asarray(seeds, jnp.uint32),)
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, scale=scale, g=gg, plan=plan,
-                          dropout=dropout),
+                          dropout=dropout, window=window),
         grid=(bh // gg,),
         in_specs=in_specs,
         out_specs=[
@@ -646,41 +714,41 @@ def _flash_bwd_call(qf, kf, vf, of, lse, dof, seeds, *, causal: bool,
 
 def _flash_bwd_folded(qf, kf, vf, of, lse, dof, *, causal: bool,
                       interpret: bool, dropout: float = 0.0, seeds=None,
-                      block_q=None, block_k=None):
+                      block_q=None, block_k=None, window: int = 0):
     """Core backward on (b*h, s, d) folded operands."""
     block_q, block_k = _resolve_blocks("bwd", qf.shape[1], kf.shape[1],
-                                       causal, block_q, block_k)
+                                       causal, block_q, block_k, window)
     return _flash_bwd_call(qf, kf, vf, of, lse, dof, seeds, causal=causal,
                            interpret=interpret, dropout=dropout,
-                           block_q=block_q, block_k=block_k)
+                           block_q=block_q, block_k=block_k, window=window)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_folded_core(qf, kf, vf, seeds, causal, interpret, dropout,
-                       block_q, block_k):
+                       block_q, block_k, window):
     out, _ = _flash_fwd_folded(qf, kf, vf, causal=causal,
                                interpret=interpret, dropout=dropout,
                                seeds=seeds, block_q=block_q,
-                               block_k=block_k)
+                               block_k=block_k, window=window)
     return out
 
 
 def _flash_folded_vjp_fwd(qf, kf, vf, seeds, causal, interpret, dropout,
-                          block_q, block_k):
+                          block_q, block_k, window):
     out, lse = _flash_fwd_folded(qf, kf, vf, causal=causal,
                                  interpret=interpret, dropout=dropout,
                                  seeds=seeds, block_q=block_q,
-                                 block_k=block_k)
+                                 block_k=block_k, window=window)
     return out, (qf, kf, vf, out, lse, seeds)
 
 
 def _flash_folded_vjp_bwd(causal, interpret, dropout, block_q, block_k,
-                          res, g):
+                          window, res, g):
     qf, kf, vf, out, lse, seeds = res
     dq, dk, dv = _flash_bwd_folded(qf, kf, vf, out, lse, g, causal=causal,
                                    interpret=interpret, dropout=dropout,
                                    seeds=seeds, block_q=block_q,
-                                   block_k=block_k)
+                                   block_k=block_k, window=window)
     return dq, dk, dv, None  # seeds are integral: no cotangent
 
 
@@ -691,7 +759,7 @@ def flash_attention_folded(qf, kf, vf, causal: bool = False,
                            interpret: bool = False, *,
                            dropout: float = 0.0, seeds=None,
                            block_q: int | None = None,
-                           block_k: int | None = None):
+                           block_k: int | None = None, window: int = 0):
     """flash_attention on PRE-FOLDED (batch*heads, seq, head_dim)
     operands. The MHA op's fast path projects q/k/v straight into this
     layout (einsum "bse,ehd->bhsd" + free reshape), so the per-layer
@@ -699,7 +767,8 @@ def flash_attention_folded(qf, kf, vf, causal: bool = False,
 
     block_q / block_k cut the score matrix into the tiles both kernels
     walk (None: chosen from the shape, _default_blocks); a causal call
-    computes no tile above the diagonal.
+    computes no tile above the diagonal, nor, under a `window` (query i
+    sees keys i - window < j <= i), one wholly below the band.
 
     dropout/seeds thread attention dropout INTO the kernels: the
     counter-based keep-mask (attention_dropout_mask with these `seeds`,
@@ -715,14 +784,16 @@ def flash_attention_folded(qf, kf, vf, causal: bool = False,
         raise ValueError("flash dropout needs seeds (dropout_seeds(rng))")
     if seeds is None:
         seeds = jnp.zeros((2,), jnp.uint32)
+    if window and not causal:
+        raise ValueError("a window is a band under the causal mask")
     return _flash_folded_core(qf, kf, vf, seeds, causal, interpret, dropout,
-                              block_q, block_k)
+                              block_q, block_k, window)
 
 
 def flash_attention(q, k, v, causal: bool = False,
                     block_q: int | None = None, block_k: int | None = None,
                     interpret: bool = False, *,
-                    dropout: float = 0.0, seeds=None):
+                    dropout: float = 0.0, seeds=None, window: int = 0):
     """Fused Pallas attention: forward AND backward keep scores/probs in
     VMEM (the backward recomputes the prob tile from the saved per-row
     log-sum-exp — the standard flash-attention scheme) and batch several
@@ -734,19 +805,19 @@ def flash_attention(q, k, v, causal: bool = False,
     out = flash_attention_folded(
         _bhsd_to_fold(q), _bhsd_to_fold(k), _bhsd_to_fold(v),
         causal=causal, interpret=interpret, dropout=dropout, seeds=seeds,
-        block_q=block_q, block_k=block_k,
+        block_q=block_q, block_k=block_k, window=window,
     )
     return _fold_to_bhsd(out, b, h)
 
 
-def local_attention(q, k, v, *, causal: bool = False):
+def local_attention(q, k, v, *, causal: bool = False, window: int = 0):
     """The single device-local streaming dispatch: fused Pallas kernel on
     TPU while its VMEM tile fits, chunked scan otherwise. Both the MHA
     op's streaming branch (ops/attention.py) and ulysses_attention route
     through here so the selection policy cannot drift between them."""
     if pallas_compiled() and flash_supported(q.shape[1], k.shape[1]):
-        return flash_attention(q, k, v, causal)
-    return chunked_attention(q, k, v, causal=causal)
+        return flash_attention(q, k, v, causal, window=window)
+    return chunked_attention(q, k, v, causal=causal, window=window)
 
 
 # ---------------------------------------------------------------------------

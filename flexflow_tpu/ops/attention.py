@@ -15,7 +15,9 @@ Inputs are (batch, seq, embed) like the reference's (N, L, E).
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import math
 import os
 import warnings
 from typing import Optional
@@ -34,6 +36,11 @@ from .registry import WeightSpec, register_op
 # ff_attention_fallback_total{reason=...} metric instead (obs.count — a
 # no-op without an active telemetry session).
 _FALLBACK_WARNED: set = set()
+
+
+class AttentionConfigError(ValueError):
+    """An attention op was asked for a combination no path computes: a
+    window under ring or ulysses sequence parallelism."""
 
 
 def reset_attention_fallback_warnings() -> None:
@@ -97,6 +104,104 @@ _DENSE_SCORE_BYTES = 256 * 1024 * 1024
 
 
 @dataclasses.dataclass(frozen=True)
+class RotaryParams:
+    """A rotary position embedding on q and k: the first `dim` channels of
+    a head (0 = all of them) rotated by position x inv_freq, channel i
+    paired with i + dim/2 (`rotate_half`), the rest passed as they are.
+    `scaling` "yarn" blends interpolated and extrapolated frequencies as
+    `transformers` `_compute_yarn_parameters` does and multiplies cos and
+    sin by `attention_factor` (0 = 0.1 ln(factor) + 1)."""
+
+    theta: float = 10000.0
+    dim: int = 0
+    scaling: str = "default"      # "default" | "yarn"
+    factor: float = 1.0
+    original_max_position_embeddings: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 0.0
+
+    def __post_init__(self):
+        if self.scaling not in ("default", "yarn"):
+            raise ValueError(f"rotary scaling {self.scaling!r}: expected "
+                             "default|yarn")
+        if self.dim % 2:
+            raise ValueError(f"rotary dim {self.dim} is odd")
+        if self.scaling == "yarn" and (
+                self.factor < 1.0
+                or self.original_max_position_embeddings <= 0):
+            raise ValueError("yarn needs factor >= 1 and "
+                             "original_max_position_embeddings")
+
+
+def rotary_table(rope: RotaryParams, head_dim: int):
+    """(inv_freq (dim/2,) float32 numpy, the factor on cos and sin) of one
+    op, computed once where the op is traced."""
+    import numpy as np
+
+    dim = rope.dim or head_dim
+    if dim > head_dim:
+        raise ValueError(f"rotary dim {dim} exceeds the head size {head_dim}")
+    inv = rope.theta ** -(np.arange(0, dim, 2, dtype=np.float64) / dim)
+    if rope.scaling == "default":
+        return inv.astype(np.float32), 1.0
+
+    def correction(rotations):
+        return dim * math.log(rope.original_max_position_embeddings
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(rope.theta))
+
+    low = max(math.floor(correction(rope.beta_fast)), 0)
+    high = min(math.ceil(correction(rope.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001  # as the source: no division by zero
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / (high - low), 0.0, 1.0)
+    inv = inv / rope.factor * ramp + inv * (1.0 - ramp)
+    return inv.astype(np.float32), (
+        rope.attention_factor or 0.1 * math.log(rope.factor) + 1.0)
+
+
+def apply_rotary(rope: RotaryParams, x, positions):
+    """x (b, s, heads, d) rotated by `positions` ((s,) or (b, s), the true
+    positions of its rows), in float32, back in x's type:
+    out = x * cos + pair(x) * sin over whole heads, with constants that
+    make the channels past the rotated ones pass (angle 0) and give the
+    pairs their signs. pair(x) (channel i's partner, i +- rot/2) is a
+    product with a 0/1 matrix, exact in x's own type: slices of a head
+    joined again would ask the TPU compiler for unaligned lane offsets."""
+    import numpy as np
+
+    d = x.shape[-1]
+    inv, factor = rotary_table(rope, d)
+    half = inv.shape[0]
+    rot = 2 * half
+    inv_d, cos_f, sin_f = (np.zeros(d, np.float32) for _ in range(3))
+    inv_d[:rot] = np.concatenate([inv, inv])
+    cos_f[:rot], cos_f[rot:] = factor, 1.0
+    sin_f[:half], sin_f[half:rot] = -factor, factor
+    partner = np.zeros((d, d), np.float32)
+    i = np.arange(half)
+    partner[i + half, i] = partner[i, i + half] = 1.0
+    ang = positions.astype(jnp.float32)[..., None] * jnp.asarray(inv_d)
+    cos = (jnp.cos(ang) * cos_f)[..., None, :]         # over the heads
+    sin = (jnp.sin(ang) * sin_f)[..., None, :]
+    pair = jnp.einsum(
+        "bshd,de->bshe", x, jnp.asarray(partner, x.dtype),
+        precision=jax.lax.Precision.HIGHEST
+        if x.dtype == jnp.float32 else None,
+        preferred_element_type=jnp.float32)
+    return (x.astype(jnp.float32) * cos + pair * sin).astype(x.dtype)
+
+
+def window_ring(window: int, max_len: int) -> int:
+    """Positions a window layer's cache leaf keeps: the window rounded up
+    to whole pages of 16, or `max_len` where that is no longer (such a leaf
+    never wraps)."""
+    return min(max_len, -(-window // 16) * 16)
+
+
+@dataclasses.dataclass(frozen=True)
 class MultiHeadAttentionParams:
     """reference: include/flexflow/ops/attention_params.h"""
 
@@ -117,12 +222,34 @@ class MultiHeadAttentionParams:
     # head i reading key-value head i // (num_heads / num_kv_heads). 0 means
     # num_heads (every query head its own keys and values).
     num_kv_heads: int = 0
+    # rotary position embedding on q and the new keys (None: none)
+    rope: Optional[RotaryParams] = None
+    # window: query i sees keys j with i - window < j <= i (0: every key the
+    # causal mask leaves); the decode cache of such an op is a ring
+    # (init_decode_cache)
+    window: int = 0
+    # per-head output gate: head h's attention output times
+    # sigmoid(x wg)[h], wg (embed, heads), before the output projection
+    head_gate: bool = False
 
     def __post_init__(self):
         if self.num_kv_heads and self.num_heads % self.num_kv_heads:
             raise ValueError(
                 f"num_kv_heads {self.num_kv_heads} does not divide "
                 f"num_heads {self.num_heads}")
+        if self.window < 0 or (self.window and not self.causal):
+            raise ValueError(f"window {self.window} needs a causal op")
+
+    @property
+    def marked(self):
+        """An op with any of rope, window and head_gate marks its parts
+        with scopes (ff.attn.*) and counts the positions its decode steps
+        read; an op with none lowers as it always did."""
+        return bool(self.rope is not None or self.window or self.head_gate)
+
+    @property
+    def kind(self):
+        return "window" if self.window else "full"
 
     @property
     def kv_heads(self):
@@ -170,7 +297,43 @@ def _weights(params: MultiHeadAttentionParams, in_shapes, in_dtypes):
     if params.qk_norm:
         ws.append(WeightSpec("q_norm", (h * dqk,), dt, "one"))
         ws.append(WeightSpec("k_norm", (hkv * dqk,), dt, "one"))
+    if params.head_gate:
+        ws.append(WeightSpec("wg", (q[-1], h), dt, "glorot_uniform",
+                             ("", "head")))
     return ws
+
+
+def _scope(params: MultiHeadAttentionParams, name: str):
+    """`ff.attn.<name>` around a part of a marked op (params.marked)."""
+    return jax.named_scope("ff.attn." + name) if params.marked \
+        else contextlib.nullcontext()
+
+
+def _rope(params, q, k, positions):
+    """q and k rotated by the rows' true positions, or as they came."""
+    if params.rope is None:
+        return q, k
+    with _scope(params, "rope"):
+        return (apply_rotary(params.rope, q, positions),
+                apply_rotary(params.rope, k, positions))
+
+
+def _gate(params, weights, x_in, attn):
+    """attn (b, s, h, dv) under the per-head gate sigmoid(x wg)."""
+    if not params.head_gate:
+        return attn
+    with _scope(params, "gate"):
+        g = jax.nn.sigmoid(jnp.einsum(
+            "bse,eh->bsh", x_in, weights["wg"].astype(x_in.dtype),
+            preferred_element_type=jnp.float32))
+        return (attn.astype(jnp.float32) * g[..., None]).astype(attn.dtype)
+
+
+def _band(q_pos, k_pos, window: int):
+    """The causal mask, under a window a band: key at k_pos visible to the
+    query at q_pos. Shapes broadcast; a key position below 0 is no key."""
+    m = (k_pos <= q_pos) & (k_pos >= 0)
+    return m & (k_pos > q_pos - window) if window else m
 
 
 def _qk_norm(params: MultiHeadAttentionParams, weights, q, k, layout="bshd"):
@@ -270,6 +433,7 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     if (impl in ("auto", "flash")
             and on_tpu
             and params.group == 1
+            and not params.marked
             and (not use_dropout or flash_dropout_ok)
             and flash_supported(seq_len, kv_len)
             and data_degree * model_degree * seq_degree * expert_degree
@@ -311,6 +475,13 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
     v = jnp.einsum("bse,ehd->bshd", v_in, wv, preferred_element_type=jnp.float32)
     q, k = _qk_norm(params, weights, q.astype(q_in.dtype),
                     k.astype(q_in.dtype))
+    if params.rope is not None:
+        if kv_len != seq_len:
+            raise AttentionConfigError(
+                "a rotary embedding needs self-attention: "
+                f"{seq_len} queries, {kv_len} keys")
+        q, k = _rope(params, q, k, jnp.arange(seq_len))
+    window = params.window
     v = v.astype(q_in.dtype)
     if params.group > 1:
         # every kernel below indexes K and V by the query's head: a group's
@@ -391,109 +562,177 @@ def _forward(params: MultiHeadAttentionParams, weights, inputs, ctx):
         else:
             reason = "sp_shape"
         _dropout_fallback(impl, ctx.op_name, reason)
-    if use_ring or use_ulysses:
-        import functools
+    if window and (use_ring or use_ulysses or impl in ("ring", "ulysses")):
+        raise AttentionConfigError(
+            f"{ctx.op_name or 'attention'}: a window of {window} under "
+            f"{'ulysses' if use_ulysses or impl == 'ulysses' else 'ring'} "
+            "sequence parallelism is not computed (ROADMAP B-M4)")
+    with _scope(params, params.kind):
+        if use_ring or use_ulysses:
+            import functools
 
-        from jax.sharding import PartitionSpec as P
+            from jax.sharding import PartitionSpec as P
 
-        from ..kernels.attention import ring_attention, ulysses_attention
+            from ..kernels.attention import ring_attention, ulysses_attention
 
-        if use_ulysses:
-            fn = functools.partial(
-                ulysses_attention, axis_name="seq", causal=params.causal
-            )
-        else:
-            fn = functools.partial(
-                ring_attention, axis_name="seq", causal=params.causal
-            )
-        spec = P("data", "seq", "model", None)
-        attn = jax.shard_map(
-            fn,
-            mesh=ctx.mesh,
-            in_specs=(spec, spec, spec),
-            out_specs=spec,
-        )(q, k, v)
-    elif use_streaming:
-        # Long sequences: O(seq) memory kernels instead of the s×s score
-        # tensor — Pallas flash attention on TPU, chunked scan elsewhere
-        # (kernels/attention.py; replaces cuDNN MHA's internal algorithm).
-        import functools
-
-        from ..kernels.attention import chunked_attention, local_attention
-
-        if impl == "flash" and not flash_supported(seq_len, kv_len):
-            warnings.warn(
-                "FF_ATTENTION_IMPL=flash ignored: "
-                f"{seq_len}x{kv_len} scores exceed the fused kernel's "
-                "VMEM tile — using chunked attention"
-            )
-        if impl == "chunked":
-            attn = chunked_attention(q, k, v, causal=params.causal)
-        elif mesh_nontrivial:
-            # On a sharded mesh the Pallas kernel can only run on per-chip
-            # shards: shard_map over batch (data) and heads (model) — each
-            # (batch, head) program is independent, so no collectives. When
-            # those dims don't divide the mesh, chunked attention (plain
-            # jnp, GSPMD-partitionable) is the safe path.
-            if flash_shardable:
-                from jax.sharding import PartitionSpec as P
-
-                spec = P("data", None, "model", None)
-                attn = jax.shard_map(
-                    functools.partial(local_attention, causal=params.causal),
-                    mesh=ctx.mesh,
-                    in_specs=(spec, spec, spec),
-                    out_specs=spec,
-                )(q, k, v)
+            if use_ulysses:
+                fn = functools.partial(
+                    ulysses_attention, axis_name="seq", causal=params.causal
+                )
             else:
-                if impl == "flash":
-                    warnings.warn(
-                        "FF_ATTENTION_IMPL=flash ignored: batch/heads don't "
-                        "divide the data/model mesh axes (or the seq axis is "
-                        "sharded) — using chunked attention"
-                    )
-                attn = chunked_attention(q, k, v, causal=params.causal)
-        else:
-            attn = local_attention(q, k, v, causal=params.causal)
-    else:
-        scale = 1.0 / jnp.sqrt(jnp.asarray(params.head_dim, jnp.float32))
-        scores = jnp.einsum(
-            "bshd,bthd->bhst", q, k, preferred_element_type=jnp.float32
-        )
-        scores = scores * scale
-        if params.causal:
-            s_len, t_len = scores.shape[-2], scores.shape[-1]
-            mask = jnp.tril(jnp.ones((s_len, t_len), bool))
-            scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        if use_dropout:
-            # same counter-based mask the flash kernels regenerate
-            # blockwise in VMEM — the two paths draw IDENTICAL masks from
-            # the same rng, so flash-with-dropout is testable against
-            # dense-with-dropout (and switching paths between compiles
-            # doesn't change the dropout stream)
-            from ..kernels.attention import (
-                attention_dropout_mask,
-                dropout_seeds,
-            )
+                fn = functools.partial(
+                    ring_attention, axis_name="seq", causal=params.causal
+                )
+            spec = P("data", "seq", "model", None)
+            attn = jax.shard_map(
+                fn,
+                mesh=ctx.mesh,
+                in_specs=(spec, spec, spec),
+                out_specs=spec,
+            )(q, k, v)
+        elif use_streaming:
+            # Long sequences: O(seq) memory kernels instead of the s×s score
+            # tensor — Pallas flash attention on TPU, chunked scan elsewhere
+            # (kernels/attention.py; replaces cuDNN MHA's internal algorithm).
+            import functools
 
-            keep = attention_dropout_mask(
-                dropout_seeds(ctx.rng), params.dropout,
-                probs.shape[0] * probs.shape[1],
-                probs.shape[2], probs.shape[3],
-            ).reshape(probs.shape)
-            probs = jnp.where(
-                keep, probs * (1.0 / (1.0 - params.dropout)), 0
-            ).astype(probs.dtype)
-        attn = jnp.einsum(
-            "bhst,bthd->bshd", probs, v, preferred_element_type=jnp.float32
-        )
-        attn = attn.astype(q.dtype)
+            from ..kernels.attention import chunked_attention, local_attention
+
+            if impl == "flash" and not flash_supported(seq_len, kv_len):
+                warnings.warn(
+                    "FF_ATTENTION_IMPL=flash ignored: "
+                    f"{seq_len}x{kv_len} scores exceed the fused kernel's "
+                    "VMEM tile — using chunked attention"
+                )
+            if impl == "chunked":
+                attn = chunked_attention(q, k, v, causal=params.causal,
+                                         window=window)
+            elif mesh_nontrivial:
+                # On a sharded mesh the Pallas kernel can only run on per-chip
+                # shards: shard_map over batch (data) and heads (model) — each
+                # (batch, head) program is independent, so no collectives. When
+                # those dims don't divide the mesh, chunked attention (plain
+                # jnp, GSPMD-partitionable) is the safe path.
+                if flash_shardable:
+                    from jax.sharding import PartitionSpec as P
+
+                    spec = P("data", None, "model", None)
+                    attn = jax.shard_map(
+                        functools.partial(local_attention, causal=params.causal,
+                                          window=window),
+                        mesh=ctx.mesh,
+                        in_specs=(spec, spec, spec),
+                        out_specs=spec,
+                    )(q, k, v)
+                else:
+                    if impl == "flash":
+                        warnings.warn(
+                            "FF_ATTENTION_IMPL=flash ignored: batch/heads don't "
+                            "divide the data/model mesh axes (or the seq axis is "
+                            "sharded) — using chunked attention"
+                        )
+                    attn = chunked_attention(q, k, v, causal=params.causal,
+                                             window=window)
+            else:
+                attn = local_attention(q, k, v, causal=params.causal,
+                                       window=window)
+        else:
+            scale = 1.0 / jnp.sqrt(jnp.asarray(params.head_dim, jnp.float32))
+            scores = jnp.einsum(
+                "bshd,bthd->bhst", q, k, preferred_element_type=jnp.float32
+            )
+            scores = scores * scale
+            if params.causal:
+                s_len, t_len = scores.shape[-2], scores.shape[-1]
+                mask = _band(jnp.arange(s_len)[:, None],
+                             jnp.arange(t_len)[None, :], window) if window \
+                    else jnp.tril(jnp.ones((s_len, t_len), bool))
+                scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            if use_dropout:
+                # same counter-based mask the flash kernels regenerate
+                # blockwise in VMEM — the two paths draw IDENTICAL masks from
+                # the same rng, so flash-with-dropout is testable against
+                # dense-with-dropout (and switching paths between compiles
+                # doesn't change the dropout stream)
+                from ..kernels.attention import (
+                    attention_dropout_mask,
+                    dropout_seeds,
+                )
+
+                keep = attention_dropout_mask(
+                    dropout_seeds(ctx.rng), params.dropout,
+                    probs.shape[0] * probs.shape[1],
+                    probs.shape[2], probs.shape[3],
+                ).reshape(probs.shape)
+                probs = jnp.where(
+                    keep, probs * (1.0 / (1.0 - params.dropout)), 0
+                ).astype(probs.dtype)
+            attn = jnp.einsum(
+                "bhst,bthd->bshd", probs, v, preferred_element_type=jnp.float32
+            )
+            attn = attn.astype(q.dtype)
+    attn = _gate(params, weights, q_in, attn)
     out = jnp.einsum("bshd,hde->bse", attn, wo, preferred_element_type=jnp.float32)
     out = out.astype(q_in.dtype)
     if params.bias:
         out = out + weights["bias_o"].astype(out.dtype)
     return [out]
+
+
+def _ring_positions(last, ring: int):
+    """The position each slot of a ring holds once position `last` is
+    written (scalar -> (ring,), per row (b,) -> (b, ring)): the largest
+    p <= last with p % ring == slot; below 0, nothing yet."""
+    last = jnp.asarray(last, jnp.int32)[..., None]
+    return last - jnp.mod(last - jnp.arange(ring), ring)
+
+
+def _ring_then_block(caches, news, t):
+    """What a block at positions t.. of a window layer attends: each ring
+    of `caches` rolled into the order of its positions, t - ring .. t - 1,
+    then the block's own rows of `news`; and those keys' positions
+    ((keys,), or (b, keys) under per-row t). A position below 0 is no key
+    (_band)."""
+    ring, s0 = caches[0].shape[1], news[0].shape[1]
+    shift = -jnp.mod(t, ring)
+    if getattr(t, "ndim", 0) == 1:
+        roll = jax.vmap(lambda c, n: jnp.roll(c, n, axis=0))
+        first = t[:, None] - ring
+    else:
+        roll = lambda c, n: jnp.roll(c, n, axis=1)  # noqa: E731
+        first = t - ring
+    k_att, v_att = (jnp.concatenate([roll(c, shift), n.astype(c.dtype)], 1)
+                    for c, n in zip(caches, news))
+    return k_att, v_att, first + jnp.arange(ring + s0)
+
+
+def _ring_append(caches, news, t, valid):
+    """The rings of a window layer after a block at positions t..: one
+    token goes to slot t % ring; of a longer block the last min(valid,
+    ring) REAL rows go each to its position's slot (`valid`: a padded
+    block's count of real tokens, None = all of them), and the padded tail
+    goes nowhere: in a ring it would lie over real keys."""
+    ring, s0 = caches[0].shape[1], news[0].shape[1]
+    per_row = getattr(t, "ndim", 0) == 1
+    if s0 == 1:
+        slot = jnp.mod(t, ring)
+        if per_row:
+            put = jax.vmap(
+                lambda c, n, at: jax.lax.dynamic_update_slice(c, n, (at, 0)))
+            return tuple(put(c, n, slot) for c, n in zip(caches, news))
+        return tuple(jax.lax.dynamic_update_slice(c, n, (0, slot, 0))
+                     for c, n in zip(caches, news))
+    # row by row: each has its own first position and count of real rows
+    rows = (caches[0].shape[0],)
+    first = jnp.broadcast_to(jnp.asarray(t, jnp.int32), rows)[:, None]
+    real = jnp.broadcast_to(
+        jnp.asarray(s0 if valid is None else valid, jnp.int32), rows)
+    holds = _ring_positions(first[:, 0] + real - 1, ring)  # after the block
+    at = jnp.clip(holds - first, 0, s0 - 1)
+    take = jax.vmap(lambda n, i: jnp.take(n, i, axis=0))
+    return tuple(jnp.where((holds >= first)[..., None], take(n, at), c)
+                 for c, n in zip(caches, news))
 
 
 def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
@@ -519,8 +758,18 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
     through its own sequence). The vector path appends each row's K/V at
     its own offset (a vmapped per-row update) and masks each row's
     attention against its own position. `valid` (a padded block's count
-    of real tokens) is not used: what a block writes beyond it lies
-    behind the causal mask until a later token overwrites it."""
+    of real tokens) matters to a window layer alone: elsewhere what a
+    block writes beyond it lies behind the causal mask until a later token
+    overwrites it, but a window layer's cache is a ring of
+    window_ring(window, max_len) positions (init_decode_cache), in which
+    the tail would lie over real keys, so its block writes real rows only
+    (_ring_append). A decode step of such a layer appends at t % ring and
+    reads min(t + 1, ring) positions; keys are rotated before they are
+    stored, so the order they lie in does not matter. Where the ring is
+    longer than the window (a window that is no whole number of pages, or
+    a max_len that never wraps), entries the window has left are masked
+    by the position each slot holds (_ring_positions), on the dense
+    branch."""
     q_in, k_in, v_in = inputs
     cdt = ctx.compute_dtype
     if cdt is not None:
@@ -541,19 +790,40 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
     b, s0, h = q.shape[:3]
     group = params.group
     max_len = k_cache.shape[1]
+    window = params.window
+    per_row_t = getattr(t, "ndim", 0) == 1
+    if params.marked:
+        # the rows' true positions, (s0,) or per row (b, s0)
+        q_pos = (t[:, None] if per_row_t else t) + jnp.arange(s0)
+        # rotated before they are stored: the order keys lie in does not
+        # matter
+        q, k_new = _rope(params, q, k_new, q_pos)
 
     def by_query_head(cache):
-        """The folded cache as (b, max_len, h, d): each key-value head
+        """The folded cache as (b, positions, h, d): each key-value head
         repeated for the query heads of its group."""
-        c = cache.astype(q.dtype).reshape(b, max_len, params.kv_heads, -1)
+        c = cache.astype(q.dtype).reshape(b, cache.shape[1],
+                                          params.kv_heads, -1)
         return c if group == 1 else jnp.repeat(c, group, axis=2)
 
     # the cache keeps a position's heads folded into one row (b, max_len,
     # kv_heads*d): the new rows fold the same way
     k_new = k_new.reshape(b, s0, -1).astype(k_cache.dtype)
     v_new = v_new.reshape(b, s0, -1).astype(v_cache.dtype)
-    per_row_t = getattr(t, "ndim", 0) == 1
-    if per_row_t:
+    # what the queries attend, where that is not the cache after the
+    # append: a window layer's block reads the ring as it was, in the order
+    # of its positions, and then itself (the ring cannot hold a block
+    # longer than itself, and need not)
+    k_att = v_att = key_pos = None
+    if window and s0 > 1:
+        k_att, v_att, key_pos = _ring_then_block(
+            (k_cache, v_cache), (k_new, v_new), t)
+    if window:
+        k_cache, v_cache = _ring_append(
+            (k_cache, v_cache), (k_new, v_new), t, valid)
+        if s0 == 1:
+            key_pos = _ring_positions(t, max_len)
+    elif per_row_t:
         row_update = jax.vmap(
             lambda c, n, tt: jax.lax.dynamic_update_slice(c, n, (tt, 0))
         )
@@ -562,6 +832,13 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
     else:
         k_cache = jax.lax.dynamic_update_slice(k_cache, k_new, (0, t, 0))
         v_cache = jax.lax.dynamic_update_slice(v_cache, v_new, (0, t, 0))
+    if k_att is None:
+        k_att, v_att = k_cache, v_cache
+    if params.marked and s0 == 1:
+        # positions whose keys this step reads, over the rows
+        seen = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (b,)) + 1
+        ctx.count(f"attn_{params.kind}_positions_read", jnp.sum(
+            jnp.minimum(seen, window) if window else seen, dtype=jnp.int32))
     # FF_DECODE_IMPL ∈ {auto, dense, paged}: "paged" routes single-token
     # steps through the Pallas paged flash-decode kernel
     # (kernels/decode.py): the cache strips, as they lie, are the paged
@@ -587,6 +864,11 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
             use_paged, interpret = True, not pallas_compiled()
     elif impl == "auto":  # interpret mode on CPU would lose to XLA dense
         use_paged = s0 == 1 and pallas_compiled()
+    if use_paged and window and max_len > window:
+        # a ring longer than its window (a window that is no whole number
+        # of pages) holds entries the window has left: the kernel masks by
+        # length alone, the dense branch below by position
+        use_paged = False
     if use_paged:
         from ..kernels.decode import (
             decode_block_pages,
@@ -601,88 +883,102 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
                 q.dtype) is None:
             _dropout_fallback(impl, ctx.op_name, "paged_untileable")
             use_paged = False
-    if use_paged:
-        kp, vp, table = paged_view_of_cache(
-            k_cache.astype(q.dtype), v_cache.astype(q.dtype), page_size)
-        lengths = (t.astype(jnp.int32) if per_row_t
-                   else jnp.full((b,), t, jnp.int32)) + 1
-        attn = paged_flash_decode(
-            q[:, 0], kp, vp, table, lengths, interpret=interpret,
-        )[:, None]                     # (b, 1, h, dv)
-    elif (s0 > 1 and not per_row_t
-          and 4 * b * h * s0 * max_len > _DENSE_SCORE_BYTES):
-        # a long prompt's block (serving's prefill from position t): the
-        # dense branch below would hold (b, h, s0, max_len) float32 scores,
-        # 2 GB at 30 heads and 4,096 x 4,096; the chunked scan of
-        # kernels/attention.py walks the cache 256 positions at a time
-        # under the same mask (cache position <= t + row)
-        from ..kernels.attention import _chunk_scan
+    with _scope(params, params.kind):
+        if use_paged:
+            kp, vp, table = paged_view_of_cache(
+                k_cache.astype(q.dtype), v_cache.astype(q.dtype), page_size)
+            lengths = (t.astype(jnp.int32) if per_row_t
+                       else jnp.full((b,), t, jnp.int32)) + 1
+            if window:  # a full ring is read whole, in whatever order it lies
+                lengths = jnp.minimum(lengths, max_len)
+            attn = paged_flash_decode(
+                q[:, 0], kp, vp, table, lengths, interpret=interpret,
+            )[:, None]                     # (b, 1, h, dv)
+        elif (s0 > 1 and not per_row_t
+              and 4 * b * h * s0 * k_att.shape[1] > _DENSE_SCORE_BYTES):
+            # a long prompt's block (serving's prefill from position t): the
+            # dense branch below would hold (b, h, s0, max_len) float32 scores,
+            # 2 GB at 30 heads and 4,096 x 4,096; the chunked scan of
+            # kernels/attention.py walks the cache 256 positions at a time
+            # under the same mask (cache position <= t + row)
+            from ..kernels.attention import _chunk_scan
 
-        attn, _, _ = _chunk_scan(
-            q, by_query_head(k_cache), by_query_head(v_cache),
-            causal=True, chunk_size=min(256, max_len), q_offset=t)
-    else:
-        k_all, v_all = k_cache.astype(q.dtype), v_cache.astype(q.dtype)
-        scale = 1.0 / jnp.sqrt(jnp.asarray(params.qk_head_dim, jnp.float32))
-        if s0 == 1:
-            # one token a slot: every head's scores in one product over
-            # the folded rows, as the kernel does it. Row h of `q_bd`
-            # holds head h's values on head h's lanes, so no 4-D view of
-            # the cache is made: on the TPU that view is a whole-cache
-            # relayout (init_decode_cache), while heads times the MXU work
-            # is nothing beside the cache's bytes.
-            # (Grouped-query heads: row h holds head h's values on the
-            # lanes of ITS GROUP's key-value head, so the folded row is
-            # kv_heads*d wide and a group's rows share their lanes.)
-            def own_lanes(per_head):
-                lanes = jnp.arange(params.kv_heads * per_head)[None, :] \
-                    // per_head
-                rows = jnp.arange(h)[:, None]
-                return lanes == (rows if group == 1 else rows // group)
-            q_bd = jnp.where(
-                own_lanes(params.qk_head_dim),
-                q.reshape(b, 1, -1) if group == 1
-                else jnp.tile(q[:, 0], (1, 1, params.kv_heads)),
-                0)                                         # (b, h, kv*d)
-            scores = jnp.einsum(
-                "bhk,btk->bht", q_bd, k_all,
-                preferred_element_type=jnp.float32,
-            )[:, :, None] * scale
+            attn, _, _ = _chunk_scan(
+                q, by_query_head(k_att), by_query_head(v_att),
+                causal=True, chunk_size=min(256, max_len), q_offset=t,
+                **({"kv_offset": t - max_len, "window": window} if window
+                   else {}))
         else:
-            scores = jnp.einsum(
-                "bshd,bthd->bhst", q, by_query_head(k_all),
-                preferred_element_type=jnp.float32,
-            ) * scale                  # (b, h, s0, max_len)
-        pos = jnp.arange(max_len)               # cache positions
-        if per_row_t:
-            q_pos = t[:, None] + jnp.arange(s0)[None, :]          # (b, s0)
-            scores = jnp.where(
-                pos[None, None, None, :] <= q_pos[:, None, :, None],
-                scores, jnp.finfo(jnp.float32).min,
-            )
-        else:
-            q_pos = t + jnp.arange(s0)          # this block's positions
-            scores = jnp.where(
-                pos[None, None, None, :] <= q_pos[None, None, :, None],
-                scores, jnp.finfo(jnp.float32).min,
-            )
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        if s0 == 1:
-            attn = jnp.einsum(
-                "bht,btk->bhk", probs[:, :, 0], v_all,
-                preferred_element_type=jnp.float32,
-            )                          # (b, h, kv*dv): row h's own lanes
-            attn = jnp.where(own_lanes(params.v_head_dim), attn, 0)
-            if group == 1:
-                attn = attn.sum(1)
-            else:  # a group's rows share lanes: fold the lanes, not the rows
-                attn = attn.reshape(b, h, params.kv_heads, -1).sum(2)
-            attn = attn.reshape(b, 1, h, -1).astype(q.dtype)
-        else:
-            attn = jnp.einsum(
-                "bhst,bthd->bshd", probs, by_query_head(v_all),
-                preferred_element_type=jnp.float32,
-            ).astype(q.dtype)
+            k_all, v_all = k_att.astype(q.dtype), v_att.astype(q.dtype)
+            scale = 1.0 / jnp.sqrt(jnp.asarray(params.qk_head_dim, jnp.float32))
+            if s0 == 1:
+                # one token a slot: every head's scores in one product over
+                # the folded rows, as the kernel does it. Row h of `q_bd`
+                # holds head h's values on head h's lanes, so no 4-D view of
+                # the cache is made: on the TPU that view is a whole-cache
+                # relayout (init_decode_cache), while heads times the MXU work
+                # is nothing beside the cache's bytes.
+                # (Grouped-query heads: row h holds head h's values on the
+                # lanes of ITS GROUP's key-value head, so the folded row is
+                # kv_heads*d wide and a group's rows share their lanes.)
+                def own_lanes(per_head):
+                    lanes = jnp.arange(params.kv_heads * per_head)[None, :] \
+                        // per_head
+                    rows = jnp.arange(h)[:, None]
+                    return lanes == (rows if group == 1 else rows // group)
+                q_bd = jnp.where(
+                    own_lanes(params.qk_head_dim),
+                    q.reshape(b, 1, -1) if group == 1
+                    else jnp.tile(q[:, 0], (1, 1, params.kv_heads)),
+                    0)                                         # (b, h, kv*d)
+                scores = jnp.einsum(
+                    "bhk,btk->bht", q_bd, k_all,
+                    preferred_element_type=jnp.float32,
+                )[:, :, None] * scale
+            else:
+                scores = jnp.einsum(
+                    "bshd,bthd->bhst", q, by_query_head(k_all),
+                    preferred_element_type=jnp.float32,
+                ) * scale                  # (b, h, s0, max_len)
+            pos = jnp.arange(max_len)               # cache positions
+            if window:
+                # by position: key_pos (keys,) or per row (b, keys)
+                qp = q_pos[:, None, :, None] if per_row_t \
+                    else q_pos[None, None, :, None]
+                kp = key_pos[:, None, None, :] if key_pos.ndim == 2 \
+                    else key_pos[None, None, None, :]
+                scores = jnp.where(_band(qp, kp, window), scores,
+                                   jnp.finfo(jnp.float32).min)
+            elif per_row_t:
+                q_pos = t[:, None] + jnp.arange(s0)[None, :]          # (b, s0)
+                scores = jnp.where(
+                    pos[None, None, None, :] <= q_pos[:, None, :, None],
+                    scores, jnp.finfo(jnp.float32).min,
+                )
+            else:
+                q_pos = t + jnp.arange(s0)          # this block's positions
+                scores = jnp.where(
+                    pos[None, None, None, :] <= q_pos[None, None, :, None],
+                    scores, jnp.finfo(jnp.float32).min,
+                )
+            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            if s0 == 1:
+                attn = jnp.einsum(
+                    "bht,btk->bhk", probs[:, :, 0], v_all,
+                    preferred_element_type=jnp.float32,
+                )                          # (b, h, kv*dv): row h's own lanes
+                attn = jnp.where(own_lanes(params.v_head_dim), attn, 0)
+                if group == 1:
+                    attn = attn.sum(1)
+                else:  # a group's rows share lanes: fold the lanes, not the rows
+                    attn = attn.reshape(b, h, params.kv_heads, -1).sum(2)
+                attn = attn.reshape(b, 1, h, -1).astype(q.dtype)
+            else:
+                attn = jnp.einsum(
+                    "bhst,bthd->bshd", probs, by_query_head(v_all),
+                    preferred_element_type=jnp.float32,
+                ).astype(q.dtype)
+    attn = _gate(params, weights, q_in, attn)
     out = jnp.einsum("bshd,hde->bse", attn, wo,
                      preferred_element_type=jnp.float32)
     out = out.astype(q_in.dtype)  # post-cast dtype, same as _forward
@@ -762,6 +1058,9 @@ def init_decode_cache(params: MultiHeadAttentionParams, batch: int,
     with heads*d innermost a position is one contiguous row and a page a
     contiguous run of them."""
     h, dqk, dv = params.kv_heads, params.qk_head_dim, params.v_head_dim
+    if params.window:
+        # a window layer keeps a ring of its last positions, not max_len
+        max_len = window_ring(params.window, max_len)
     return (
         jnp.zeros((batch, max_len, h * dqk), dtype),
         jnp.zeros((batch, max_len, h * dv), dtype),
@@ -778,6 +1077,9 @@ register_op(
     forward_decode=_forward_decode,
     init_decode_state=init_decode_cache,
     decode_section="mha",
+    # a marked op counts the positions its decode steps read, by its kind
+    decode_counters=lambda p: (
+        (f"attn_{p.kind}_positions_read",) if p.marked else ()),
     init_decode_static=cross_decode_kv,
     forward_decode_static=_forward_decode_cross,
 )

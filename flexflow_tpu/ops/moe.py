@@ -245,8 +245,10 @@ class ExpertBankParams:
     """One expert layer (DeepSeek-V3-style routing): `experts` routed
     experts of which this op HOLDS `held_count` from `held_from` on (expert
     parallelism's share of a layer: the rest live on other chips), `top_k`
-    of all `experts` chosen a token by sigmoid scores, ungated two-matrix
-    experts of `width`, one shared expert of `shared_width` (0: none)."""
+    of all `experts` chosen a token by `router` scores ("sigmoid", or
+    "softmax" over all the experts), two-matrix experts of `width` (`gated`:
+    three matrices, act(x w_gate) * (x w_up) into w_down), one shared
+    expert of `shared_width` of the same form (0: none)."""
 
     experts: int
     held_from: int
@@ -257,8 +259,13 @@ class ExpertBankParams:
     scale: float = 1.0            # routed_scaling_factor
     norm_topk: bool = True        # weights divided by their sum
     activation: ActiMode = ActiMode.AC_MODE_RELU2
+    router: str = "sigmoid"
+    gated: bool = False
 
     def __post_init__(self):
+        if self.router not in ("sigmoid", "softmax"):
+            raise ValueError(f"router {self.router!r}: expected "
+                             "sigmoid|softmax")
         if not (0 <= self.held_from
                 and self.held_from + self.held_count <= self.experts
                 and 0 < self.held_count and 0 < self.top_k <= self.experts):
@@ -284,21 +291,26 @@ def _bank_weights(params: ExpertBankParams, in_shapes, in_dtypes):
         WeightSpec("w_up", (n, e, f), dt),
         WeightSpec("w_down", (n, f, e), dt),
     ]
+    if params.gated:
+        ws.append(WeightSpec("w_gate", (n, e, f), dt))
     if params.shared_width:
         ws += [WeightSpec("shared_up", (e, params.shared_width), dt),
                WeightSpec("shared_down", (params.shared_width, e), dt)]
+        if params.gated:
+            ws.append(WeightSpec("shared_gate", (e, params.shared_width), dt))
     return ws
 
 
 def route(params: ExpertBankParams, router, b_corr, x):
     """The router on tokens x (T, e), in float32: sigmoid scores over ALL
-    experts, the `top_k` largest of score + b_corr chosen, the chosen
-    scores (without b_corr) normalised and scaled. Returns (ids (T, k)
-    int32, weights (T, k) float32)."""
+    experts (or their softmax, `params.router`), the `top_k` largest of
+    score + b_corr chosen, the chosen scores (without b_corr) normalised
+    and scaled. Returns (ids (T, k) int32, weights (T, k) float32)."""
     f32 = jnp.float32
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(f32), router.astype(f32),
-        precision=jax.lax.Precision.HIGHEST))
+    logits = jnp.dot(x.astype(f32), router.astype(f32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1) if params.router == "softmax" \
+        else jax.nn.sigmoid(logits)
     _, ids = jax.lax.top_k(scores + b_corr.astype(f32), params.top_k)
     w = jnp.take_along_axis(scores, ids, axis=-1)
     if params.norm_topk:
@@ -357,11 +369,16 @@ def _bank_forward(params: ExpertBankParams, weights, inputs, ctx):
         ctx.count("moe_experts_touched", jnp.sum(load > 0, dtype=jnp.int32))
         ctx.count("moe_expert_load_max", jnp.max(load))
 
-    def group(up, down, scale):
-        """The experts `up` (g, e, f), `down` (g, f, e) on every token."""
+    def group(up, down, scale, gate_w=None):
+        """The experts `up` (g, e, f), `down` (g, f, e) on every token;
+        gated ones act on `gate_w`'s product and scale `up`'s."""
         with jax.named_scope("ff.moe.experts"):
             h = jnp.einsum("te,gef->tgf", xt, up, preferred_element_type=f32)
-            h = apply_activation(params.activation, h)
+            if gate_w is None:
+                h = apply_activation(params.activation, h)
+            else:
+                h = h * apply_activation(params.activation, jnp.einsum(
+                    "te,gef->tgf", xt, gate_w, preferred_element_type=f32))
         with jax.named_scope("ff.moe.combine"):
             h = (h * scale[:, :, None]).astype(x.dtype)
             return jnp.dot(h.reshape(T, -1), down.reshape(-1, e),
@@ -370,20 +387,26 @@ def _bank_forward(params: ExpertBankParams, weights, inputs, ctx):
     g = n
     while g > 1 and (4 * T * g * f > _BANK_HIDDEN_BYTES or n % g):
         g -= 1
+    gates = (w["w_gate"],) if params.gated else ()
     if g == n:
-        out = group(w["w_up"], w["w_down"], scale)
+        out = group(w["w_up"], w["w_down"], scale, *gates)
     else:
         def body(acc, part):
             return acc + group(*part), None
         out, _ = jax.lax.scan(body, jnp.zeros((T, e), f32), (
             w["w_up"].reshape(n // g, g, e, f),
             w["w_down"].reshape(n // g, g, f, e),
-            jnp.moveaxis(scale.reshape(T, n // g, g), 1, 0)))
+            jnp.moveaxis(scale.reshape(T, n // g, g), 1, 0),
+            *(a.reshape(n // g, g, e, f) for a in gates)))
     if params.shared_width:
         with jax.named_scope("ff.moe.shared"):
             s = jnp.dot(xt, w["shared_up"], preferred_element_type=f32)
-            s = apply_activation(params.activation, s).astype(x.dtype)
-            out = out + jnp.dot(s, w["shared_down"],
+            if params.gated:
+                s = s * apply_activation(params.activation, jnp.dot(
+                    xt, w["shared_gate"], preferred_element_type=f32))
+            else:
+                s = apply_activation(params.activation, s)
+            out = out + jnp.dot(s.astype(x.dtype), w["shared_down"],
                                 preferred_element_type=f32)
     return [out.astype(x.dtype).reshape(lead + (e,))]
 

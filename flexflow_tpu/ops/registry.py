@@ -75,7 +75,9 @@ class OpDef:
     # FwdCtx.count while a decode step is traced (parallel/decode.py keeps
     # them in the caches' "counters" section, one scalar a name for the
     # whole step; a name that ends in "_max" is a maximum, any other a sum).
-    decode_counters: Tuple[str, ...] = ()
+    # Either the names, or a callable params -> names for an op whose
+    # params decide what it counts (`counters_of`).
+    decode_counters: object = ()
     # Cross-batch mutable buffers (reference: cuDNN BN running stats,
     # Cache op's CACHE_UPDATE_TASK). state_spec declares them like
     # weights; forward_stateful(params, weights, state, inputs, ctx) ->
@@ -84,6 +86,11 @@ class OpDef:
     # read-only to eval/forward.
     state_spec: Optional[Callable] = None
     forward_stateful: Optional[Callable] = None
+
+    def counters_of(self, params) -> Tuple[str, ...]:
+        if callable(self.decode_counters):
+            return tuple(self.decode_counters(params))
+        return tuple(self.decode_counters)
 
     def is_seq_pointwise(self, params, op) -> bool:
         if callable(self.seq_pointwise):
@@ -108,7 +115,7 @@ def register_op(
     decode_section: Optional[str] = None,
     init_decode_static: Optional[Callable] = None,
     forward_decode_static: Optional[Callable] = None,
-    decode_counters: Tuple[str, ...] = (),
+    decode_counters: object = (),
     state_spec: Optional[Callable] = None,
     forward_stateful: Optional[Callable] = None,
 ) -> OpDef:
@@ -125,7 +132,8 @@ def register_op(
         decode_section=decode_section,
         init_decode_static=init_decode_static,
         forward_decode_static=forward_decode_static,
-        decode_counters=tuple(decode_counters),
+        decode_counters=decode_counters if callable(decode_counters)
+        else tuple(decode_counters),
         state_spec=state_spec,
         forward_stateful=forward_stateful,
     )

@@ -932,6 +932,16 @@ def state_bytes(caches) -> Dict[str, int]:
             for kind, sections in STATE_KINDS.items()}
 
 
+def kv_bytes_by_kind(caches, max_len: int) -> Dict[str, int]:
+    """The keys and values the fused attention ops hold, split by what
+    their leaves are: "window" for a ring shorter than `max_len` (a window
+    layer keeps its last positions alone), "full" for the rest."""
+    out = {"window": 0, "full": 0}
+    for leaf in jax.tree_util.tree_leaves(caches["mha"]):
+        out["window" if leaf.shape[1] < max_len else "full"] += leaf.nbytes
+    return out
+
+
 def declared_state_bytes(topo, kind: str, max_len: int, dtype) -> int:
     """Bytes ONE slot of `max_len` positions holds of `kind` across the
     ops of `topo`, by what each op's definition says it keeps
@@ -1137,7 +1147,7 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
     static_kept = _kept_statics(plan, static_keyed)
     counter_names = sorted({
         name for op in plan.live_ops if not op.is_parallel_op
-        for name in get_op_def(op.op_type).decode_counters})
+        for name in get_op_def(op.op_type).counters_of(op.params)})
 
     def init_caches(params=None, static_inputs=()):
         assert len(static_inputs) == len(static_pts), (

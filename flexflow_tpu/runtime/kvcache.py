@@ -866,17 +866,24 @@ def kv_page_bytes(model, page_size: int,
     Returns None when the graph has no fused-MHA self-attention (e.g.
     primitive-op imports, where the cache cost lives in prefix tensors).
     Which ops keep keys and values, and in what shape, is their own
-    definitions' answer (parallel/decode.py declared_state_bytes)."""
+    definitions' answer (parallel/decode.py declared_state_bytes): an op
+    whose leaf stops growing (a ring) pays this for its first pages only,
+    which `slot_reservation_bytes` accounts for."""
+    return _kv_bytes(model, page_size, kv_dtype) or None
+
+
+def _kv_bytes(model, tokens: int, kv_dtype: Optional[str] = None) -> int:
+    """Bytes of keys and values one slot of `tokens` positions holds: the
+    sum over the graph's ops of each op's own leaf at that length."""
     import numpy as np
 
     from ..parallel.decode import declared_state_bytes
 
     ex = getattr(model, "executor", None)
     if ex is None:
-        return None
+        return 0
     dtype = kv_dtype or getattr(ex, "compute_dtype", None) or np.float32
-    return declared_state_bytes(
-        ex.topo, "kv", page_size, np.dtype(dtype)) or None
+    return declared_state_bytes(ex.topo, "kv", tokens, np.dtype(dtype))
 
 
 def recurrent_slot_bytes(model) -> int:
@@ -899,10 +906,12 @@ def recurrent_slot_bytes(model) -> int:
 def slot_reservation_bytes(model, config: KVCacheConfig,
                            tokens: int) -> int:
     """What a slot that reserves `tokens` positions takes of the device's
-    memory, both kinds of state counted: its pages of keys and values
-    and its recurrent state."""
-    page = kv_page_bytes(model, config.page_size, config.kv_dtype) or 0
-    return config.pages_for(tokens) * page + recurrent_slot_bytes(model)
+    memory, both kinds of state counted: its pages of keys and values,
+    each op's own leaf at that many whole pages (an op that keeps a ring
+    stops at its ring), and its recurrent state."""
+    held = config.pages_for(tokens) * config.page_size
+    return _kv_bytes(model, held, config.kv_dtype) \
+        + recurrent_slot_bytes(model)
 
 
 # ----------------------------------------------------------------------

@@ -1370,6 +1370,15 @@ class ContinuousBatcher:
             obs.gauge_set("ff_serving_" + kind, self.stats[kind],
                           help="bytes the decode slots hold of this kind "
                                "of per-slot state", replica=self.name)
+        # the keys and values again, by what their leaves are: rings of a
+        # window's positions, and leaves of max_len
+        for kind, held in decode.kv_bytes_by_kind(
+                self._caches, self.config.max_len).items():
+            self.stats[f"kv_cache_bytes_{kind}"] = held
+            obs.gauge_set("ff_serving_kv_cache_bytes", held,
+                          help="bytes the decode slots hold of this kind "
+                               "of per-slot state", replica=self.name,
+                          kind=kind)
         obs.gauge_set("ff_serving_decode_caches_donated",
                       self.stats["decode_caches_donated"],
                       help="1 where the decode step owns these caches and "

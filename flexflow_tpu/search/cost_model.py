@@ -110,10 +110,12 @@ def op_flops(op: PCGOp) -> float:
         # q, and k and v at the key-value heads (fewer under grouped-query
         # attention)
         proj = 2.0 * bq * sq * eq * d * (h + 2 * p.kv_heads)
+        sk = _window_keys(p, sk)
         scores = 2.0 * bq * h * sq * sk * d
         av = 2.0 * bq * h * sq * sk * p.v_head_dim
         out = 2.0 * bq * sq * h * p.v_head_dim * p.embed_dim
-        return proj + scores + av + out
+        gate = 2.0 * bq * sq * eq * h if p.head_gate else 0.0
+        return proj + scores + av + out + gate
     if t == OperatorType.OP_GATED_DELTA_NET:
         (x,) = in_shapes
         p = op.params
@@ -141,9 +143,11 @@ def op_flops(op: PCGOp) -> float:
         p = op.params
         tokens, e = _vol(x[:-1]), x[-1]
         # the router over all experts, the shared expert, and every held
-        # expert on every token (ops/moe.py: the router's weights pick)
-        return 2.0 * tokens * e * (p.experts + 2 * p.shared_width
-                                   + 2 * p.held_count * p.width)
+        # expert on every token (ops/moe.py: the router's weights pick);
+        # a gated expert has three matrices
+        mats = 3 if p.gated else 2
+        return 2.0 * tokens * e * (p.experts + mats * p.shared_width
+                                   + mats * p.held_count * p.width)
     if t == OperatorType.OP_LAYERNORM and op.params.rms:
         # square, mean, normalise, scale: four operations an element
         return 4.0 * _vol(out_shapes[0])
@@ -166,6 +170,12 @@ def op_flops(op: PCGOp) -> float:
 # which shard extents pad.
 MXU_LANES = 128
 MXU_SUBLANES = 8
+
+
+def _window_keys(p, keys: int) -> int:
+    """Keys a query of the attention op `p` meets among `keys`: under a
+    window no more than the window's."""
+    return min(keys, p.window) if p.window else keys
 
 
 def _pad(v, q: int) -> float:
@@ -229,7 +239,7 @@ def op_padded_flops(op: PCGOp, parts: int = 1) -> float:
         qm = [d.size for d in q.dims if not d.is_replica_dim]
         km = [d.size for d in k.dims if not d.is_replica_dim]
         sq, eq = qm[1], qm[2]
-        sk = km[1]
+        sk = _window_keys(p, km[1])
         # head-sharded MHA (weight-only degrees) keeps its full-h price —
         # the DP grants it single-part views, so charging one shard here
         # would let a TP candidate undercut without paying its devices
@@ -239,7 +249,9 @@ def op_padded_flops(op: PCGOp, parts: int = 1) -> float:
         scores = 2.0 * bq * h * _pad(sq, MXU_SUBLANES) * _pad(d, MXU_LANES) * _pad(sk, MXU_LANES)
         av = 2.0 * bq * h * _pad(sq, MXU_SUBLANES) * _pad(sk, MXU_LANES) * _pad(p.v_head_dim, MXU_LANES)
         out = 2.0 * _pad(bq * sq, MXU_SUBLANES) * _pad(h * p.v_head_dim, MXU_LANES) * _pad(p.embed_dim, MXU_LANES)
-        return proj + scores + av + out
+        gate = 2.0 * _pad(bq * sq, MXU_SUBLANES) * _pad(eq, MXU_LANES) \
+            * _pad(h, MXU_LANES) if p.head_gate else 0.0
+        return proj + scores + av + out + gate
     return op_flops(op) / max(1, parts)
 
 
@@ -271,6 +283,18 @@ def _seq_extent(t) -> int:
     return int(s[1]) if len(s) >= 3 else 1
 
 
+def _kv_cache_bytes(p, x) -> float:
+    """Bytes of the cache a decode step of attention op `p` reads for its
+    key (or value) input `x`, at as many heads as `x` has (the callers
+    divide by the group): under a window, the window's positions of the
+    sequence's."""
+    n = _vol(x.material_shape()) * x.effective_itemsize()
+    if p.window:
+        seq = max(1, _seq_extent(x))
+        n = n * _window_keys(p, seq) / seq
+    return n
+
+
 def op_decode_bytes(op: PCGOp) -> float:
     """HBM bytes ONE single-token decode step streams for this op,
     unsharded (the decode-objective analog of op_bytes): every weight is
@@ -287,9 +311,9 @@ def op_decode_bytes(op: PCGOp) -> float:
         # the persistent (b, max_len, h*d) K/V pair the step attends
         # over — byte-equivalent to the full k/v inputs; the cache is
         # materialized at the compute width (bf16 under AMP)
+        # (a window layer's cache is a ring of its window's positions)
         for x in op.inputs[1:3]:
-            n += _vol(x.material_shape()) * x.effective_itemsize() \
-                / op.params.group
+            n += _kv_cache_bytes(op.params, x) / op.params.group
     if op.op_type in _RECURRENT_OPS:
         n += _recurrent_state_traffic(op)
     for x in list(op.inputs) + list(op.outputs):
@@ -601,8 +625,7 @@ class CostModel:
             head_deg = max(
                 [max(1, w.get_total_degree()) for w in op.weights] or [1]
             )
-            kv = sum(_vol(x.material_shape()) * x.effective_itemsize()
-                     for x in op.inputs[1:3])
+            kv = sum(_kv_cache_bytes(op.params, x) for x in op.inputs[1:3])
             membytes += kv / max(1, batch_deg * head_deg) / op.params.group
         if op.op_type in _RECURRENT_OPS:
             # over the batch alone: the op's weights are not head-sharded

@@ -260,3 +260,126 @@ def test_both_searches_price_the_op(lm):
     # grouped-query: half the keys and values of an ungrouped op
     assert op_decode_bytes(attn) == op_weight_bytes(attn) \
         + 2 * (4 * 32 * E * 4) / 2 + 4 * (4 * E * 4)
+
+
+# -- softmax routing and gated experts (three matrices) --------------------------
+def gated_params(lo=0, hi=N, **kw):
+    return params(lo, hi, router="softmax", gated=True,
+                  activation=ActiMode.AC_MODE_SILU, **kw)
+
+
+def gated_weights(seed=0):
+    rng = np.random.RandomState(seed)
+    w = weights(None, seed)
+    w["w_gate"] = 0.4 * rng.randn(N, E, F).astype(np.float32)
+    w["shared_gate"] = 0.4 * rng.randn(E, FS).astype(np.float32)
+    return w
+
+
+def gated_held(w, p):
+    lo, hi = p.held_from, p.held_from + p.held_count
+    out = held(w, p)
+    out["w_gate"] = out["w_gate"][lo:hi]
+    return out
+
+
+def gated_naive(w, x, experts=range(N), shared=True):
+    """A loop over tokens and experts in numpy float64: softmax over all
+    experts, the K largest chosen, weights normalised x 2.5, every chosen
+    expert among `experts` as down(silu(gate x) * up x)."""
+    x = np.asarray(x, np.float64).reshape(-1, E)
+    w = {k: np.asarray(v, np.float64) for k, v in w.items()}
+
+    def glu(v, gate, up, down):
+        a = v @ gate
+        return (a / (1.0 + np.exp(-a)) * (v @ up)) @ down
+
+    out = np.zeros_like(x)
+    for t, v in enumerate(x):
+        logits = v @ w["router"]
+        s = np.exp(logits - logits.max())
+        s /= s.sum()
+        chosen = np.argsort(-s, kind="stable")[:K]
+        gate = s[chosen] / (s[chosen].sum() + 1e-20) * 2.5
+        for e, g in zip(chosen, gate):
+            if e in experts:
+                out[t] += g * glu(v, w["w_gate"][e], w["w_up"][e],
+                                  w["w_down"][e])
+        if shared:
+            out[t] += glu(v, w["shared_gate"], w["shared_up"],
+                          w["shared_down"])
+    return out
+
+
+def gated_run(p, w, x):
+    (y,) = op().forward(p, gated_held(w, p), [jnp.asarray(x)],
+                        FwdCtx(training=False))
+    return np.asarray(y, np.float64).reshape(-1, E)
+
+
+def test_softmax_routing_and_gated_experts_are_the_loop_over_experts():
+    p, w = gated_params(), gated_weights()
+    names = [s.name for s in op().weights(p, [(2, 3, E)], [DataType.DT_FLOAT])]
+    assert names == ["router", "b_corr", "w_up", "w_down", "w_gate",
+                     "shared_up", "shared_down", "shared_gate"]
+    x = np.random.RandomState(1).randn(3, 7, E).astype(np.float32)
+    assert np.abs(gated_run(p, w, x) - gated_naive(w, x)).max() < 1e-4
+    ids, gate = route(p, jnp.asarray(w["router"]), jnp.asarray(w["b_corr"]),
+                      jnp.asarray(x.reshape(-1, E)))
+    assert np.allclose(np.asarray(gate).sum(-1), 2.5, atol=1e-5)
+    # the sigmoid router over the same logits chooses the same experts (both
+    # are monotone) and weighs them otherwise
+    _, sig = route(params(), jnp.asarray(w["router"]),
+                   jnp.asarray(w["b_corr"]), jnp.asarray(x.reshape(-1, E)))
+    assert not np.allclose(np.asarray(sig), np.asarray(gate), atol=1e-3)
+    with pytest.raises(ValueError):
+        params(router="tanh")
+
+
+def test_a_long_gated_block_takes_the_experts_a_group_at_a_time(monkeypatch):
+    from flexflow_tpu.ops import moe
+
+    p, w = gated_params(), gated_weights()
+    x = np.random.RandomState(5).randn(2, 9, E).astype(np.float32)
+    whole = gated_run(p, w, x)
+    monkeypatch.setattr(moe, "_BANK_HIDDEN_BYTES", 4 * 18 * 2 * F)
+    assert np.abs(gated_run(p, w, x) - whole).max() < 1e-5
+
+
+def test_the_gated_shares_add_up_to_the_uncut_layer():
+    """The guide's share test for the softmax-routed gated layer: 4 chips
+    hold 2 of the 8 experts each (the cell's 32 shares of 8 at a small
+    size); their partial results, the shared expert counted once, add up to
+    what the uncut layer gives."""
+    w = gated_weights()
+    x = np.random.RandomState(6).randn(4, 5, E).astype(np.float32)
+    whole = gated_run(gated_params(), w, x)
+    shares = [gated_run(gated_params(lo, lo + 2, shared=FS if lo == 0 else 0),
+                        w, x) for lo in range(0, N, 2)]
+    assert np.abs(sum(shares) - whole).max() < 1e-4
+    assert np.abs(whole - gated_naive(w, x)).max() < 1e-4
+    # one share is not the layer
+    assert np.abs(shares[0] - whole).max() > 1e-2
+
+
+def test_the_builder_spells_the_gated_softmax_bank():
+    m = FFModel(FFConfig())
+    x = m.create_tensor((2, 4, E), DataType.DT_FLOAT)
+    m.expert_bank(x, N, K, F, held=(0, 4), shared_width=FS, scale=2.5,
+                  act="silu_gated", router="softmax", name="g")
+    m.expert_bank(x, N, K, F, held=(0, 4), shared_width=FS, scale=2.5,
+                  name="u")
+    g, u = (layer.params for layer in m.layers[-2:])
+    assert g.gated and g.router == "softmax" \
+        and g.activation == ActiMode.AC_MODE_SILU
+    assert not u.gated and u.router == "sigmoid" \
+        and u.activation == ActiMode.AC_MODE_RELU2
+    from flexflow_tpu.search.cost_model import op_flops
+    # three matrices an expert and the shared one, where the ungated has two
+    m.compile(optimizer=SGDOptimizer(lr=0.0),
+              loss_type=LossType.LOSS_MEAN_SQUARED_ERROR_AVG_REDUCE,
+              metrics=[])
+    ops = {o.name: o for o in m.executor.topo}
+    tokens = 2 * 4
+    assert op_flops(ops["g"]) == 2.0 * tokens * E * (N + 3 * FS + 3 * 4 * F)
+    assert op_flops(ops["u"]) == 2.0 * tokens * E * (N + 2 * FS + 2 * 4 * F)
