@@ -104,6 +104,94 @@ def _band_scan(q, k, v, *, window: int, block: int, q_offset, kv_offset):
     return out[:, :sq], m, l
 
 
+def _online_update(carry, s, mask, pv):
+    """One key tile into a running (m, l, acc) softmax: `s` the tile's
+    float32 scores (..., q, k), `mask` which of them count, `pv(p)` the
+    tile's product of probabilities and values. A tile whose every score
+    is masked leaves a row that has seen a key as it was (p = 0, and the
+    rescale is exp(0))."""
+    m_prev, l_prev, acc_prev = carry
+    s = jnp.where(mask, s, NEG_INF)
+    m_cur = jnp.max(s, axis=-1)
+    m_new = jnp.maximum(m_prev, m_cur)
+    p = jnp.exp(s - m_new[..., None])
+    l_new = l_prev * jnp.exp(m_prev - m_new) + jnp.sum(p, axis=-1)
+    acc_new = acc_prev * jnp.exp(m_prev - m_new)[..., None] + pv(p)
+    return m_new, l_new, acc_new
+
+
+def causal_tiles(sq: int, sk: int, *, block: int, chunk: int, q_offset,
+                 valid):
+    """How many key chunks each query block of a causal block walk meets
+    (int32 (ceil(sq / block),), traced where q_offset or `valid` is):
+    chunks 0 .. the one holding the block's last real query, where the
+    block's real queries are those before q_offset + valid; none for a
+    block wholly past them."""
+    starts = jnp.arange(-(-sq // block), dtype=jnp.int32) * block
+    ends = q_offset + jnp.minimum(starts + block, valid)
+    reach = jnp.minimum(-(-ends // chunk), -(-sk // chunk))
+    return jnp.where(starts < valid, reach, 0)
+
+
+def _causal_scan(q, k, v, *, block: int, chunk: int, q_offset, valid=None):
+    """_chunk_scan's causal walk for a prefill block against a cache,
+    forward only: q (b, sq, h, d) at positions q_offset.., k and v the
+    cache as stored, (b, sk, kv_heads * d) and (b, sk, kv_heads * dv) at
+    positions 0... Query blocks of `block` rows go under lax.map;
+    block i walks key chunks 0 .. n_i (causal_tiles, traced) and no
+    further, so no chunk above its last real query, nor at or past the
+    prompt's end (q_offset + valid), is computed. Each chunk's (m, l, acc)
+    update is _chunk_scan's, in its order, so a real row comes out as
+    there. A block wholly in the padded tail computes nothing: its rows
+    are zeros. Grouped-query heads are one product per key-value head,
+    the cache never repeated. Returns (out (b, sq, h, dv), tiles computed,
+    tiles skipped); a traced trip count has no reverse-mode derivative."""
+    b, sq, h, d = q.shape
+    sk = k.shape[1]
+    n = k.shape[-1] // d                      # key-value heads
+    g, dv = h // n, v.shape[-1] // n
+    valid = sq if valid is None else valid
+    n_blocks, n_chunks = -(-sq // block), -(-sk // chunk)
+    if n_blocks * block != sq:
+        q = jnp.pad(q, ((0, 0), (0, n_blocks * block - sq), (0, 0), (0, 0)))
+    if n_chunks * chunk != sk:  # the padding mask below keeps it out
+        k, v = (jnp.pad(a, ((0, 0), (0, n_chunks * chunk - sk), (0, 0)))
+                for a in (k, v))
+    scale = 1.0 / math.sqrt(d)
+    reach = causal_tiles(sq, sk, block=block, chunk=chunk, q_offset=q_offset,
+                         valid=valid)
+    qb = q.reshape(b, n_blocks, block, n, g, d).transpose(1, 0, 2, 3, 4, 5)
+
+    def one_block(inputs):
+        i, q_blk, n_i = inputs
+        q_pos = q_offset + i * block + jnp.arange(block)
+
+        def chunk_step(ci, carry):
+            k_blk, v_blk = (
+                lax.dynamic_slice_in_dim(a, ci * chunk, chunk, axis=1)
+                .reshape(b, chunk, n, -1) for a in (k, v))
+            s = jnp.einsum("bqngd,bknd->bngqk", q_blk, k_blk.astype(q.dtype),
+                           preferred_element_type=jnp.float32) * scale
+            kv_pos = ci * chunk + jnp.arange(chunk)
+            mask = (kv_pos[None, :] <= sk - 1) \
+                & (kv_pos[None, :] <= q_pos[:, None])
+            return _online_update(carry, s, mask, lambda p: jnp.einsum(
+                "bngqk,bknd->bngqd", p, v_blk.astype(jnp.float32),
+                preferred_element_type=jnp.float32))
+
+        m0 = jnp.full((b, n, g, block), NEG_INF, jnp.float32)
+        m, l, acc = lax.fori_loop(0, n_i, chunk_step, (
+            m0, jnp.zeros_like(m0), jnp.zeros(m0.shape + (dv,), jnp.float32)))
+        out = acc / jnp.maximum(l[..., None], 1e-30)
+        return out.transpose(0, 3, 1, 2, 4).reshape(b, block, h, dv)
+
+    out = lax.map(one_block, (jnp.arange(n_blocks), qb, reach))
+    out = out.transpose(1, 0, 2, 3, 4).reshape(b, n_blocks * block, h, dv)
+    computed = jnp.sum(reach)
+    return out[:, :sq].astype(q.dtype), computed, \
+        n_blocks * n_chunks - computed
+
+
 def _chunk_scan(q, k, v, *, causal: bool, chunk_size: int, q_offset=0,
                 kv_offset=0, window: int = 0):
     """Online-softmax accumulation over KV chunks. q: (b, sq, h, d). With
@@ -127,7 +215,6 @@ def _chunk_scan(q, k, v, *, causal: bool, chunk_size: int, q_offset=0,
     q_pos = q_offset + jnp.arange(sq)
 
     def body(carry, inputs):
-        m_prev, l_prev, acc_prev = carry
         ci, k_blk, v_blk = inputs
         s = jnp.einsum("bqhd,bkhd->bhqk", q, k_blk,
                        preferred_element_type=jnp.float32) * scale
@@ -135,16 +222,10 @@ def _chunk_scan(q, k, v, *, causal: bool, chunk_size: int, q_offset=0,
         mask = kv_pos[None, :] <= (sk + kv_offset - 1)  # padding mask
         if causal:
             mask = mask & (kv_pos[None, :] <= q_pos[:, None])
-        s = jnp.where(mask[None, None, :, :], s, NEG_INF)
-        m_cur = jnp.max(s, axis=-1)  # (b,h,q)
-        m_new = jnp.maximum(m_prev, m_cur)
-        p = jnp.exp(s - m_new[..., None])
-        l_new = l_prev * jnp.exp(m_prev - m_new) + jnp.sum(p, axis=-1)
-        acc_new = acc_prev * jnp.exp(m_prev - m_new)[..., None] + jnp.einsum(
+        return _online_update(carry, s, mask, lambda p: jnp.einsum(
             "bhqk,bkhd->bhqd", p, v_blk.astype(jnp.float32),
             preferred_element_type=jnp.float32,
-        )
-        return (m_new, l_new, acc_new), None
+        )), None
 
     # Derive carries from q so they inherit q's varying manual axes when
     # running inside shard_map (fresh zeros would be unvarying and scan

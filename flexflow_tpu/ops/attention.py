@@ -101,6 +101,12 @@ def _dropout_fallback(impl: str, op_name: str, reason: str) -> None:
 # past this many bytes of float32 scores a block of decode queries takes the
 # chunked scan (the same budget _forward streams from)
 _DENSE_SCORE_BYTES = 256 * 1024 * 1024
+# what a full layer's long prefill block counts of its causal walk's
+# (query block, key chunk) tiles: those computed, and those skipped
+PREFILL_TILE_COUNTERS = ("attn_prefill_tiles_computed",
+                         "attn_prefill_tiles_skipped")
+# the walk's tile: queries a block, cache positions a key chunk (at most)
+_PREFILL_BLOCK, _KEY_CHUNK = 512, 256
 
 
 @dataclasses.dataclass(frozen=True)
@@ -758,9 +764,10 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
     through its own sequence). The vector path appends each row's K/V at
     its own offset (a vmapped per-row update) and masks each row's
     attention against its own position. `valid` (a padded block's count
-    of real tokens) matters to a window layer alone: elsewhere what a
-    block writes beyond it lies behind the causal mask until a later token
-    overwrites it, but a window layer's cache is a ring of
+    of real tokens) lets a long block's causal walk leave the padded tail
+    uncomputed (its rows come out as zeros, and nothing reads them); what
+    a block writes beyond it lies behind the causal mask until a later
+    token overwrites it, but a window layer's cache is a ring of
     window_ring(window, max_len) positions (init_decode_cache), in which
     the tail would lie over real keys, so its block writes real rows only
     (_ring_append). A decode step of such a layer appends at t % ring and
@@ -898,16 +905,24 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
               and 4 * b * h * s0 * k_att.shape[1] > _DENSE_SCORE_BYTES):
             # a long prompt's block (serving's prefill from position t): the
             # dense branch below would hold (b, h, s0, max_len) float32 scores,
-            # 2 GB at 30 heads and 4,096 x 4,096; the chunked scan of
-            # kernels/attention.py walks the cache 256 positions at a time
-            # under the same mask (cache position <= t + row)
-            from ..kernels.attention import _chunk_scan
+            # 2 GB at 30 heads and 4,096 x 4,096; kernels/attention.py walks
+            # the cache 256 positions at a time under the same mask (cache
+            # position <= t + row): a window layer's band, else blocks of
+            # queries that meet only the chunks a real query of theirs sees
+            from ..kernels.attention import _causal_scan, _chunk_scan
 
-            attn, _, _ = _chunk_scan(
-                q, by_query_head(k_att), by_query_head(v_att),
-                causal=True, chunk_size=min(256, max_len), q_offset=t,
-                **({"kv_offset": t - max_len, "window": window} if window
-                   else {}))
+            if window:
+                attn, _, _ = _chunk_scan(
+                    q, by_query_head(k_att), by_query_head(v_att),
+                    causal=True, chunk_size=min(_KEY_CHUNK, max_len),
+                    q_offset=t, kv_offset=t - max_len, window=window)
+            else:
+                attn, computed, skipped = _causal_scan(
+                    q, k_att, v_att, block=min(_PREFILL_BLOCK, s0),
+                    chunk=min(_KEY_CHUNK, max_len), q_offset=t,
+                    valid=None if valid is None else jnp.max(valid))
+                ctx.count("attn_prefill_tiles_computed", computed)
+                ctx.count("attn_prefill_tiles_skipped", skipped)
         else:
             k_all, v_all = k_att.astype(q.dtype), v_att.astype(q.dtype)
             scale = 1.0 / jnp.sqrt(jnp.asarray(params.qk_head_dim, jnp.float32))
@@ -1080,6 +1095,10 @@ register_op(
     # a marked op counts the positions its decode steps read, by its kind
     decode_counters=lambda p: (
         (f"attn_{p.kind}_positions_read",) if p.marked else ()),
+    # and a marked full one the tiles its long prefill blocks' causal walk
+    # computes and skips (kernels/attention.py _causal_scan)
+    prefill_counters=lambda p: (
+        PREFILL_TILE_COUNTERS if p.marked and not p.window else ()),
     init_decode_static=cross_decode_kv,
     forward_decode_static=_forward_decode_cross,
 )

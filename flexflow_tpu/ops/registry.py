@@ -78,6 +78,10 @@ class OpDef:
     # Either the names, or a callable params -> names for an op whose
     # params decide what it counts (`counters_of`).
     decode_counters: object = ()
+    # The same for what it counts only in a block of several positions (a
+    # prefill): kept in the caches' "prefill_counters" section, which a
+    # decode iteration does not fetch (`counters_of(params, "prefill")`).
+    prefill_counters: object = ()
     # Cross-batch mutable buffers (reference: cuDNN BN running stats,
     # Cache op's CACHE_UPDATE_TASK). state_spec declares them like
     # weights; forward_stateful(params, weights, state, inputs, ctx) ->
@@ -87,10 +91,10 @@ class OpDef:
     state_spec: Optional[Callable] = None
     forward_stateful: Optional[Callable] = None
 
-    def counters_of(self, params) -> Tuple[str, ...]:
-        if callable(self.decode_counters):
-            return tuple(self.decode_counters(params))
-        return tuple(self.decode_counters)
+    def counters_of(self, params, which: str = "decode") -> Tuple[str, ...]:
+        names = self.decode_counters if which == "decode" \
+            else self.prefill_counters
+        return tuple(names(params) if callable(names) else names)
 
     def is_seq_pointwise(self, params, op) -> bool:
         if callable(self.seq_pointwise):
@@ -116,6 +120,7 @@ def register_op(
     init_decode_static: Optional[Callable] = None,
     forward_decode_static: Optional[Callable] = None,
     decode_counters: object = (),
+    prefill_counters: object = (),
     state_spec: Optional[Callable] = None,
     forward_stateful: Optional[Callable] = None,
 ) -> OpDef:
@@ -134,6 +139,8 @@ def register_op(
         forward_decode_static=forward_decode_static,
         decode_counters=decode_counters if callable(decode_counters)
         else tuple(decode_counters),
+        prefill_counters=prefill_counters if callable(prefill_counters)
+        else tuple(prefill_counters),
         state_spec=state_spec,
         forward_stateful=forward_stateful,
     )

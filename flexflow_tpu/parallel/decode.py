@@ -862,8 +862,10 @@ def _static_alignment(shape, out_rank, out_info: AxisInfo, live_len):
 #     "mha_static"        op name -> a static-keyed attention's (k, v)
 SLOT_SECTIONS = ("prefix", "mha", "recurrent")
 # "counters": one integer scalar a name, what the ops of the LAST step
-# counted (OpDef.decode_counters); a step replaces them, nothing else does
-SHARED_SECTIONS = ("static", "mha_static", "counters")
+# counted (OpDef.decode_counters); a step replaces them, nothing else does.
+# "prefill_counters" the same for what ops count only in a block of several
+# positions (OpDef.prefill_counters), out of a decode iteration's fetch
+SHARED_SECTIONS = ("static", "mha_static", "counters", "prefill_counters")
 # what a slot pays for, by kind: keys and values, which grow with a
 # sequence up to max_len (pages), and state of fixed size, which does not
 STATE_KINDS = {"kv": ("prefix", "mha"), "fixed": ("recurrent",)}
@@ -1145,9 +1147,11 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
         op.weights for op in plan.static_ops if not op.is_parallel_op
     )
     static_kept = _kept_statics(plan, static_keyed)
-    counter_names = sorted({
+    counter_names = {sec: sorted({
         name for op in plan.live_ops if not op.is_parallel_op
-        for name in get_op_def(op.op_type).counters_of(op.params)})
+        for name in get_op_def(op.op_type).counters_of(op.params, which)})
+        for which, sec in (("decode", "counters"),
+                           ("prefill", "prefill_counters"))}
 
     def init_caches(params=None, static_inputs=()):
         assert len(static_inputs) == len(static_pts), (
@@ -1184,8 +1188,9 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
             d = get_op_def(op.op_type)
             caches[d.decode_section][op.name] = d.init_decode_state(
                 op.params, batch, max_len, cdt)
-        for name in counter_names:
-            caches["counters"][name] = jnp.zeros((), jnp.int32)
+        for sec, names in counter_names.items():
+            for name in names:
+                caches[sec][name] = jnp.zeros((), jnp.int32)
         for op in static_keyed:
             caches["mha_static"][op.name] = get_op_def(
                 op.op_type).init_decode_static(
@@ -1231,8 +1236,8 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
         vals = {plan.decode_pt.guid: tok}
         new_caches = _stepped(caches)
         # what the ops count is of THIS trace's step
-        sctx = dataclasses.replace(ctx, counters={}) if counter_names \
-            else ctx
+        sctx = dataclasses.replace(ctx, counters={}) \
+            if any(counter_names.values()) else ctx
 
         def get_static(g):
             if g in statics:
@@ -1358,10 +1363,11 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                                 r, n, 1, axis=_ax - 1)
                         )(v, at)
                     t, s0 = t + jnp.asarray(row, jnp.int32), 1
-        if counter_names:
-            new_caches["counters"] = {
-                name: jnp.asarray(sctx.counters.get(name, 0), jnp.int32)
-                for name in counter_names}
+        for sec, names in counter_names.items():
+            if names:
+                new_caches[sec] = {
+                    name: jnp.asarray(sctx.counters.get(name, 0), jnp.int32)
+                    for name in names}
         if donate:
             _check_donated(caches, new_caches)
         return vals[logits_pt.guid], new_caches

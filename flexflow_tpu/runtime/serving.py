@@ -1330,8 +1330,13 @@ class ContinuousBatcher:
                 [jnp.asarray(padded)], jnp.int32(plen), jnp.int32(plen - 1),
             )
             # the step put out the one row needed, the last real token's,
-            # not bucket x vocabulary of them; its best id is one number
-            first = int(jax.device_get(_best_ids(logits))[0])
+            # not bucket x vocabulary of them; its best id is one number,
+            # fetched with what the prefill counted (summed into `stats`)
+            ids, counted = jax.device_get(
+                (_best_ids(logits), caches1["prefill_counters"]))
+            first = int(ids[0])
+        for name, value in counted.items():
+            self.stats[name] = self.stats.get(name, 0) + int(value)
         return first, caches1
 
     def _insert_slot(self, slot_idx: int, caches1) -> None:
