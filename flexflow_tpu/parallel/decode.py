@@ -926,6 +926,13 @@ def take_rows(caches, idx):
     return out
 
 
+def slot_leaves(caches) -> int:
+    """The per-slot leaves of `caches`: what insert_row writes, one eager
+    update each."""
+    return sum(len(jax.tree_util.tree_leaves(caches[sec]))
+               for sec in SLOT_SECTIONS)
+
+
 def state_bytes(caches) -> Dict[str, int]:
     """Bytes the caches hold of each kind of per-slot state
     (STATE_KINDS)."""

@@ -1050,7 +1050,13 @@ class ContinuousBatcher:
                       # always-on phase counters: seconds inside each
                       # ff.serve.* span (obs.mark), and the prompt tokens
                       # prefilled against the bucket they were padded to
-                      "admit_s": 0.0, "prefill_s": 0.0, "insert_s": 0.0,
+                      "admit_s": 0.0, "admit_reserve_s": 0.0,
+                      "prefill_s": 0.0, "prefill_init_s": 0.0,
+                      "prefill_dispatch_s": 0.0, "prefill_wait_s": 0.0,
+                      "prefill_fetch_s": 0.0, "insert_s": 0.0,
+                      # the per-slot leaves the inserts wrote, one eager
+                      # program each, summed over admissions
+                      "insert_programs": 0,
                       "decode_s": 0.0, "decode_prepare_s": 0.0,
                       "decode_dispatch_s": 0.0, "decode_wait_s": 0.0,
                       "decode_fetch_s": 0.0, "decode_sample_s": 0.0,
@@ -1209,56 +1215,38 @@ class ContinuousBatcher:
         seq_key = f"{req.id}:{generation}:{self.name}:{self._admit_seq}"
         share = self.config.share_prefixes
         prompt_tokens = req.prompt.tolist() if share else None
-        try:
-            # with the prompt given, reserve() attaches published prefix
-            # pages refcounted and only charges the unshared remainder —
-            # the dedup that lets N same-prefix sessions share one pool
-            reserved = self._reserve_tokens(plen, req.max_new_tokens)
-            rr = self.pool.reserve(seq_key, reserved, tokens=prompt_tokens)
-        except KVCacheExhaustedError as e:
-            if e.never_fits:
-                _shed("kv_exhausted")
-                req.trace.shed("kv_exhausted", stage="admit",
-                               replica=self.name,
-                               pages_needed=e.pages_needed)
-                # a can-NEVER-fit request is a sizing bug, not
-                # backpressure — worth a forensics bundle (deduped per
-                # exception; backpressure requeues below stay silent)
-                obs.record_failure(e, replica=self.name,
-                                   request=req.id,
-                                   kv_snapshot=self.pool.snapshot())
-                req._finish(error=RequestShedError(
-                    f"request {req.id} can never fit the KV page pool: "
-                    f"{e}", reason="kv_exhausted",
-                ))
-                return True
-            # backpressure: put it back and wait for retirements
-            self.queue.requeue(req)
-            req.trace.event("kv_backpressure", replica=self.name,
-                            pages_needed=e.pages_needed,
-                            pages_free=e.pages_free)
-            obs.event("serving_kv_backpressure", cat="serving",
-                      replica=self.name, request=req.id,
-                      pages_needed=e.pages_needed, pages_free=e.pages_free)
-            return False
-        slot_idx = self.slots.index(None)
-        bucket = self._bucket(plen)
-        req.admitted_t = time.monotonic()
-        req.trace.admitted(self.name, generation=generation,
-                           slot=slot_idx, prompt_len=plen)
-        if rr.shared_pages:
-            self.stats["prefix_hits"] += 1
-        if req.trace.sampled:
-            req.trace.event("kv_reserve", replica=self.name,
-                            pages=rr.pages, shared=rr.shared_pages,
-                            bytes=slot_reservation_bytes(
-                                self.model, self.pool.config, reserved),
-                            **self.pool.snapshot())
-        cache_key = ((bucket, req.prompt.astype(self._id_dt).tobytes())
-                     if share and self.config.prefix_cache_entries > 0
-                     else None)
-        cached = (self._prefix_cache.get(cache_key)
-                  if cache_key is not None else None)
+        # the host's bookkeeping before any device work: the page reserve,
+        # the slot, and the lookup of a prefilled strip to replay
+        with obs.mark("ff.serve.admit.reserve", cat="serving",
+                      into=(self.stats, "admit_reserve_s")):
+            try:
+                # with the prompt given, reserve() attaches published
+                # prefix pages refcounted and only charges the unshared
+                # remainder — the dedup that lets N same-prefix sessions
+                # share one pool
+                reserved = self._reserve_tokens(plen, req.max_new_tokens)
+                rr = self.pool.reserve(seq_key, reserved,
+                                       tokens=prompt_tokens)
+            except KVCacheExhaustedError as e:
+                return self._kv_exhausted(req, e)
+            slot_idx = self.slots.index(None)
+            bucket = self._bucket(plen)
+            req.admitted_t = time.monotonic()
+            req.trace.admitted(self.name, generation=generation,
+                               slot=slot_idx, prompt_len=plen)
+            if rr.shared_pages:
+                self.stats["prefix_hits"] += 1
+            if req.trace.sampled:
+                req.trace.event("kv_reserve", replica=self.name,
+                                pages=rr.pages, shared=rr.shared_pages,
+                                bytes=slot_reservation_bytes(
+                                    self.model, self.pool.config, reserved),
+                                **self.pool.snapshot())
+            cache_key = ((bucket, req.prompt.astype(self._id_dt).tobytes())
+                         if share and self.config.prefix_cache_entries > 0
+                         else None)
+            cached = (self._prefix_cache.get(cache_key)
+                      if cache_key is not None else None)
         span.set(slot=slot_idx, bucket=bucket, skipped=cached is not None)
         prefill_span = req.trace.span("prefill", replica=self.name,
                                       bucket=bucket, prompt_len=plen,
@@ -1284,7 +1272,7 @@ class ContinuousBatcher:
         except BaseException:
             self.pool.release(seq_key)
             raise
-        self._insert_slot(slot_idx, caches1)
+        self._insert_slot(slot_idx, caches1, request=req.id)
         prefill_span.done()
         req.first_token_t = time.monotonic()
         req.token_t = [req.first_token_t]
@@ -1306,6 +1294,36 @@ class ContinuousBatcher:
         self._maybe_retire(slot_idx)
         return True
 
+    def _kv_exhausted(self, req: GenerationRequest,
+                      e: KVCacheExhaustedError) -> bool:
+        """An admission the page pool cannot cover now: shed the request
+        typed when it never could (True), else put it back to wait for
+        retirements (False)."""
+        from .. import obs
+
+        if e.never_fits:
+            _shed("kv_exhausted")
+            req.trace.shed("kv_exhausted", stage="admit", replica=self.name,
+                           pages_needed=e.pages_needed)
+            # a can-NEVER-fit request is a sizing bug, not backpressure —
+            # worth a forensics bundle (deduped per exception; backpressure
+            # requeues below stay silent)
+            obs.record_failure(e, replica=self.name, request=req.id,
+                               kv_snapshot=self.pool.snapshot())
+            req._finish(error=RequestShedError(
+                f"request {req.id} can never fit the KV page pool: {e}",
+                reason="kv_exhausted",
+            ))
+            return True
+        # backpressure: put it back and wait for retirements
+        self.queue.requeue(req)
+        req.trace.event("kv_backpressure", replica=self.name,
+                        pages_needed=e.pages_needed, pages_free=e.pages_free)
+        obs.event("serving_kv_backpressure", cat="serving",
+                  replica=self.name, request=req.id,
+                  pages_needed=e.pages_needed, pages_free=e.pages_free)
+        return False
+
     def _prefill(self, req: GenerationRequest, plen: int):
         """Run the prompt through the batch-1 decode step, padded to a
         power-of-two bucket (bounds distinct jit shapes to log2(max_len)).
@@ -1320,34 +1338,50 @@ class ContinuousBatcher:
         bucket = self._bucket(plen)
         padded = np.zeros((1, bucket), self._id_dt)
         padded[0, :plen] = req.prompt.astype(self._id_dt)
+        stats, params = self.stats, self.model.state.params
         with self._device_lock, obs.mark(
                 "ff.serve.prefill", cat="serving",
-                into=(self.stats, "prefill_s"), request=req.id,
-                bucket=bucket):
-            caches1 = self._init1(self.model.state.params, ())
-            logits, caches1 = self._step1(
-                self.model.state.params, caches1, jnp.int32(0),
-                [jnp.asarray(padded)], jnp.int32(plen), jnp.int32(plen - 1),
-            )
-            # the step put out the one row needed, the last real token's,
-            # not bucket x vocabulary of them; its best id is one number,
-            # fetched with what the prefill counted (summed into `stats`)
-            ids, counted = jax.device_get(
-                (_best_ids(logits), caches1["prefill_counters"]))
+                into=(stats, "prefill_s"), request=req.id, bucket=bucket):
+            # the batch-1 cache the step fills, made anew each prefill
+            with obs.mark("ff.serve.prefill.init", cat="serving",
+                          into=(stats, "prefill_init_s")):
+                caches1 = self._init1(params, ())
+            with obs.mark("ff.serve.prefill.dispatch", cat="serving",
+                          into=(stats, "prefill_dispatch_s")):
+                logits, caches1 = self._step1(
+                    params, caches1, jnp.int32(0), [jnp.asarray(padded)],
+                    jnp.int32(plen), jnp.int32(plen - 1),
+                )
+                # the step put out the one row needed, the last real
+                # token's, not bucket x vocabulary of them; its best id is
+                # one number, picked behind it on the device
+                ids = _best_ids(logits)
+            # ONE sync, split in two as a decode iteration's: the wait for
+            # the device, then the copy of the id and of what the prefill
+            # counted (summed into `stats`)
+            with obs.mark("ff.serve.prefill.wait", cat="serving",
+                          into=(stats, "prefill_wait_s")):
+                jax.block_until_ready(ids)
+            with obs.mark("ff.serve.prefill.fetch", cat="serving",
+                          into=(stats, "prefill_fetch_s")):
+                ids, counted = jax.device_get(
+                    (ids, caches1["prefill_counters"]))
             first = int(ids[0])
         for name, value in counted.items():
-            self.stats[name] = self.stats.get(name, 0) + int(value)
+            stats[name] = stats.get(name, 0) + int(value)
         return first, caches1
 
-    def _insert_slot(self, slot_idx: int, caches1) -> None:
+    def _insert_slot(self, slot_idx: int, caches1, *, request) -> None:
         """Swap a prefilled batch-1 cache strip into the running batch:
         every per-slot cache leaf is written wholesale at `slot_idx`, so
-        whatever a previous occupant left there is fully replaced."""
+        whatever a previous occupant left there is fully replaced. The
+        writes are dispatched, not waited for."""
         from .. import obs
 
         with self._device_lock, obs.mark(
                 "ff.serve.insert", cat="serving",
-                into=(self.stats, "insert_s"), slot=slot_idx):
+                into=(self.stats, "insert_s"), request=request,
+                slot=slot_idx):
             self._insert_slot_locked(slot_idx, caches1)
 
     def _insert_slot_locked(self, slot_idx: int, caches1) -> None:
@@ -1360,6 +1394,8 @@ class ContinuousBatcher:
         # holds the old tree while its successor is made
         caches, self._caches = self._caches, None
         self._caches = decode.insert_row(caches, caches1, slot_idx)
+        # insert_row writes each per-slot leaf by its own eager update
+        self.stats["insert_programs"] += decode.slot_leaves(self._caches)
 
     def _note_state_bytes(self) -> None:
         """What the slots hold of each kind of per-slot state, as gauges

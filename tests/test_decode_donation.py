@@ -245,7 +245,7 @@ def test_an_insert_never_holds_a_second_generation_of_the_caches(
     _, strip = b._step1(params, b._init1(params, ()), jnp.int32(0),
                         [jnp.zeros((1, 4), jnp.int32)], jnp.int32(4),
                         jnp.int32(3))
-    b._insert_slot(0, strip)
+    b._insert_slot(0, strip, request="r0")
     old = [weakref.ref(leaf) for leaf in _leaves(
         b._caches, "prefix", "mha", "recurrent")]
     assert len(old) == 4  # (k, v) and (S, conv_tail)
@@ -258,7 +258,7 @@ def test_an_insert_never_holds_a_second_generation_of_the_caches(
         return update(*a, **kw)
 
     monkeypatch.setattr(jax.lax, "dynamic_update_slice_in_dim", spy)
-    b._insert_slot(1, strip)
+    b._insert_slot(1, strip, request="r1")
     monkeypatch.undo()
     assert alive == [4, 3, 2, 1]
     assert all(ref() is None for ref in old)

@@ -131,6 +131,24 @@ def test_serve_loop_phase_counters_and_token_stamps(lm):
         assert r.token_t[-1] <= r.finished_t
 
 
+def test_admission_parts_add_up_to_the_admission(lm):
+    b, reqs = serve(lm, [[1, 2, 3], [4, 5, 6, 7, 8], [9, 10]], max_new=3)
+    s = b.stats
+    parts = [s[k] for k in ("prefill_init_s", "prefill_dispatch_s",
+                            "prefill_wait_s", "prefill_fetch_s")]
+    assert all(p > 0 for p in parts) and s["prefill_s"] >= sum(parts)
+    assert s["admit_reserve_s"] > 0
+    assert s["admit_s"] >= s["admit_reserve_s"] + s["prefill_s"] + s["insert_s"]
+
+
+def test_insert_programs_count_the_per_slot_leaves_written(lm):
+    b, reqs = serve(lm, [[1, 2, 3], [4, 5, 6, 7, 8], [9, 10]], max_new=3)
+    leaves = sum(len(jax.tree_util.tree_leaves(b._caches[sec]))
+                 for sec in ("prefix", "mha", "recurrent"))
+    assert leaves > 0 and b.stats["admitted"] == 3
+    assert b.stats["insert_programs"] == 3 * leaves
+
+
 def test_serve_loop_spans_are_on_the_serve_threads_line(lm, tmp_path):
     jax.profiler.start_trace(str(tmp_path))
     try:
@@ -138,7 +156,10 @@ def test_serve_loop_spans_are_on_the_serve_threads_line(lm, tmp_path):
     finally:
         jax.profiler.stop_trace()
     names = host_span_names(str(tmp_path))
-    for name in ("ff.serve.admit", "ff.serve.prefill", "ff.serve.insert",
+    for name in ("ff.serve.admit", "ff.serve.admit.reserve",
+                 "ff.serve.prefill", "ff.serve.prefill.init",
+                 "ff.serve.prefill.dispatch", "ff.serve.prefill.wait",
+                 "ff.serve.prefill.fetch", "ff.serve.insert",
                  "ff.serve.decode", "ff.serve.decode.prepare",
                  "ff.serve.decode.dispatch", "ff.serve.decode.wait",
                  "ff.serve.decode.fetch", "ff.serve.decode.sample",
@@ -149,6 +170,10 @@ def test_serve_loop_spans_are_on_the_serve_threads_line(lm, tmp_path):
             admit["bucket"]) == (reqs[0].id, 3, 0, 4)
     assert str(admit["skipped"]) in ("False", "0")
     assert names["ff.serve.prefill"][0]["request"] == reqs[0].id
+    # every span of one admission carries the request's id
+    assert names["ff.serve.insert"] == [{"request": reqs[0].id, "slot": 0}]
+    for part in ("init", "dispatch", "wait", "fetch"):
+        assert len(names[f"ff.serve.prefill.{part}"]) == 1, part
     assert [d["iteration"] for d in names["ff.serve.decode"]] == [0, 1]
     # each part once an iteration, with the pick on the device too
     for part in ("prepare", "dispatch", "wait", "fetch", "sample"):
