@@ -879,27 +879,22 @@ def _stepped(caches):
     return new
 
 
-def insert_row(caches, row_caches, slot: int):
-    """A running batch's caches with slot `slot` replaced, in every
-    per-slot leaf, by the one row of `row_caches` (a prefilled batch-1
-    tree): whatever a previous occupant left there is gone. CONSUMES
-    `caches`: each old leaf is let go as its successor is made, so the
-    insert holds one spare leaf, never a second generation of the caches
-    beside the first."""
+def _write_rows(slotted, rows, slot):
+    """The traced body of insert_row: every per-slot leaf of `slotted`
+    with the one row of `rows` written at `slot` (a traced int32, so one
+    program serves every slot)."""
     from ..runtime.verify import ServingConfigError
+    from .executor import _count_trace
 
-    def put(old, row):
-        return jax.lax.dynamic_update_slice_in_dim(
-            old, row.astype(old.dtype), slot, axis=0)
-
-    out = {sec: caches[sec] for sec in SHARED_SECTIONS}
+    _count_trace("insert")
+    out = {}
     for sec in SLOT_SECTIONS:
         out[sec] = {}
-        for key in list(caches[sec]):
-            olds, treedef = jax.tree_util.tree_flatten(caches[sec].pop(key))
-            rows = jax.tree_util.tree_leaves(row_caches[sec][key])
+        for key, batch in slotted[sec].items():
+            olds, treedef = jax.tree_util.tree_flatten(batch)
+            news = jax.tree_util.tree_leaves(rows[sec][key])
             shapes = next(
-                ((tuple(o.shape), tuple(r.shape)) for o, r in zip(olds, rows)
+                ((tuple(o.shape), tuple(r.shape)) for o, r in zip(olds, news)
                  if r.shape[0] != 1 or o.shape[1:] != r.shape[1:]), None)
             if shapes is not None:
                 raise ServingConfigError(
@@ -908,9 +903,47 @@ def insert_row(caches, row_caches, slot: int):
                     "graph folds batch with another axis and cannot be "
                     "continuously batched"
                 )
-            out[sec][key] = treedef.unflatten(
-                [put(olds.pop(0), row) for row in rows])
+            out[sec][key] = treedef.unflatten([
+                jax.lax.dynamic_update_slice_in_dim(
+                    o, r.astype(o.dtype), slot, axis=0)
+                for o, r in zip(olds, news)])
     return out
+
+
+# one program a cache tree's shapes (and a batch's placement): the batch is
+# donated where the step donates its caches, the row never is (the batcher
+# keeps prefilled rows to replay them)
+_INSERT = {donate: jax.jit(_write_rows, donate_argnums=(0,) if donate else ())
+           for donate in (False, True)}
+
+
+def insert_row(caches, row_caches, slot: int, *, donate: bool = False):
+    """A running batch's caches with slot `slot` replaced, in every
+    per-slot leaf, by the one row of `row_caches` (a prefilled batch-1
+    tree): whatever a previous occupant left there is gone. One compiled
+    program writes every leaf; the shared sections pass around it as they
+    are. CONSUMES `caches`: with `donate` (the step's rule,
+    executor.donates_buffers) each leaf is written in place and the old
+    ones are deleted, so the insert never holds a second generation of
+    the caches; `row_caches` is only read."""
+    out = {sec: caches[sec] for sec in SHARED_SECTIONS}
+    out.update(_INSERT[donate](
+        {sec: caches[sec] for sec in SLOT_SECTIONS},
+        {sec: row_caches[sec] for sec in SLOT_SECTIONS}, np.int32(slot)))
+    return out
+
+
+def compiled_init(init):
+    """`init` (build_step's init_caches) as one compiled program: a fresh
+    tree from one dispatch, not one eager zero-fill a leaf. Every call
+    still hands out buffers the caches alone own."""
+    def init_caches(params=None, static_inputs=()):
+        from .executor import _count_trace
+
+        _count_trace("init_caches")
+        return init(params, static_inputs)
+
+    return jax.jit(init_caches)
 
 
 def take_rows(caches, idx):
@@ -924,13 +957,6 @@ def take_rows(caches, idx):
         {sec: caches[sec] for sec in SLOT_SECTIONS})
     out.update((sec, caches[sec]) for sec in SHARED_SECTIONS)
     return out
-
-
-def slot_leaves(caches) -> int:
-    """The per-slot leaves of `caches`: what insert_row writes, one eager
-    update each."""
-    return sum(len(jax.tree_util.tree_leaves(caches[sec]))
-               for sec in SLOT_SECTIONS)
 
 
 def state_bytes(caches) -> Dict[str, int]:

@@ -975,14 +975,18 @@ class ContinuousBatcher:
         # prefill ALWAYS lowers from the train-searched (compute-bound)
         # strategy: a prompt is a full-sequence forward, exactly the
         # shape the training objective priced
-        self._init1, self._step1 = ex.build_decode(
+        init1, self._step1 = ex.build_decode(
             1, config.max_len, assume_causal=config.assume_causal
         )
+        # the batch-1 cache each prefill fills, made by one program
+        self._init1 = decode.compiled_init(init1)
+        # the steps and the insert consume the caches where this holds
+        self._donates = ex.donates_buffers()
         # batched decode prefers the decode-searched strategy (HBM
         # roofline objective) when one exists / is configured AND its
         # cache pytree is splice-compatible with the prefill lowering —
-        # _insert_slot_locked copies prefill caches leaf-by-leaf into
-        # the running batch, so the two lowerings must agree on cache
+        # _insert_slot_locked writes prefill caches into the running
+        # batch leaf for leaf, so the two lowerings must agree on cache
         # structure. Anything else falls back to the training executor
         # (counted, warned once).
         self.decode_strategy_active = False
@@ -1054,9 +1058,10 @@ class ContinuousBatcher:
                       "prefill_s": 0.0, "prefill_init_s": 0.0,
                       "prefill_dispatch_s": 0.0, "prefill_wait_s": 0.0,
                       "prefill_fetch_s": 0.0, "insert_s": 0.0,
-                      # the per-slot leaves the inserts wrote, one eager
-                      # program each, summed over admissions
-                      "insert_programs": 0,
+                      # programs the inserts and the prefills' batch-1
+                      # caches dispatched, summed: one an admission, one
+                      # a computed prefill
+                      "insert_programs": 0, "prefill_init_programs": 0,
                       "decode_s": 0.0, "decode_prepare_s": 0.0,
                       "decode_dispatch_s": 0.0, "decode_wait_s": 0.0,
                       "decode_fetch_s": 0.0, "decode_sample_s": 0.0,
@@ -1075,7 +1080,7 @@ class ContinuousBatcher:
                       # append in place (executor.build_decode donates them
                       # on an accelerator: one rule for every executor of
                       # this process), set when the steps are built
-                      "decode_caches_donated": int(ex.donates_buffers())}
+                      "decode_caches_donated": int(self._donates)}
 
     def _decode_executor_mismatch(self, dex, initB_d) -> Optional[str]:
         """None if the decode-searched lowering can serve the batched
@@ -1346,6 +1351,7 @@ class ContinuousBatcher:
             with obs.mark("ff.serve.prefill.init", cat="serving",
                           into=(stats, "prefill_init_s")):
                 caches1 = self._init1(params, ())
+                stats["prefill_init_programs"] += 1
             with obs.mark("ff.serve.prefill.dispatch", cat="serving",
                           into=(stats, "prefill_dispatch_s")):
                 logits, caches1 = self._step1(
@@ -1393,9 +1399,9 @@ class ContinuousBatcher:
         # the insert consumes the caches as a step does: nothing here
         # holds the old tree while its successor is made
         caches, self._caches = self._caches, None
-        self._caches = decode.insert_row(caches, caches1, slot_idx)
-        # insert_row writes each per-slot leaf by its own eager update
-        self.stats["insert_programs"] += decode.slot_leaves(self._caches)
+        self._caches = decode.insert_row(caches, caches1, slot_idx,
+                                         donate=self._donates)
+        self.stats["insert_programs"] += 1
 
     def _note_state_bytes(self) -> None:
         """What the slots hold of each kind of per-slot state, as gauges
@@ -1599,11 +1605,11 @@ class ContinuousBatcher:
 
     def _warmup_compiles(self) -> None:
         """Compile the batched decode step, the pick of its rows' best
-        ids and every prefill bucket on throwaway caches before taking
-        traffic. Runs on the serve thread
-        under the HealthMonitor's compile grace window; the running batch
-        then never waits on XLA mid-request."""
-        params = self.model.state.params
+        ids, every prefill bucket, the batch-1 cache's program and the
+        insert on throwaway caches before taking traffic. Runs on the serve
+        thread under the HealthMonitor's compile grace window; the running
+        batch then never waits on XLA mid-request."""
+        params, donate = self.model.state.params, self._donates
         with self._device_lock:
             b = 1
             while True:
@@ -1623,10 +1629,16 @@ class ContinuousBatcher:
             # are done, as an admission makes it, so that the device never
             # holds their temporaries beside both generations of a leaf
             jax.block_until_ready(caches1)
-            caches = decode.insert_row(self._initB(params, ()), caches1, 0)
+            caches = decode.insert_row(self._initB(params, ()), caches1, 0,
+                                       donate=donate)
             t_vec = jnp.zeros((self.config.slots,), jnp.int32)
             toks = jnp.zeros((self.config.slots, 1), self._id_dt)
-            _best_ids(self._stepB(params, caches, t_vec, [toks])[0])
+            logits, caches = self._stepB(params, caches, t_vec, [toks])
+            _best_ids(logits)
+            # the insert again, into a stepped batch: a fresh batch (the
+            # first admission's) is not yet placed as the step's output is,
+            # and the program is built for each placement
+            decode.insert_row(caches, caches1, 0, donate=donate)
 
     def _strand_slots(self) -> int:
         """Hand every occupied slot back to the shared queue (or shed it
@@ -1804,11 +1816,14 @@ class ContinuousBatcher:
                 if self.monitor is not None:
                     self.monitor.step_finished(it)
                 # each active sequence gains one token per iteration, so
-                # the iteration wall time IS the per-token service time
+                # the iteration wall time IS the per-token service time. A
+                # sample counts for at most twice the estimate: one stalled
+                # iteration (a profiler starting, a host hiccup) would
+                # otherwise shed the long requests admitted after it, while
+                # a lasting slowdown still lifts it by a fifth an iteration
+                e = self._token_ewma_s
                 self._token_ewma_s = (
-                    dt if self._token_ewma_s is None
-                    else 0.8 * self._token_ewma_s + 0.2 * dt
-                )
+                    dt if e is None else 0.8 * e + 0.2 * min(dt, 2.0 * e))
                 self._iteration += 1
                 # ONE update: whoever a finished request wakes reads
                 # `stats` of whole iterations, never a step's counters

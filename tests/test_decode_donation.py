@@ -15,6 +15,7 @@ import pytest
 
 from flexflow_tpu import (AggrMode, DataType, FFConfig, FFModel, LossType,
                           MetricsType, SGDOptimizer)
+from flexflow_tpu.parallel import decode
 from flexflow_tpu.parallel.executor import PCGExecutor
 from flexflow_tpu.runtime.serving import (AdmissionQueue, ContinuousBatcher,
                                           GenerationRequest,
@@ -57,6 +58,13 @@ def donating(monkeypatch):
     yield
     jax.config.update("jax_enable_compilation_cache", True)
     cc.reset_cache()
+
+
+def _buffers(tree):
+    """Where every leaf of `tree` lies: one address a device's shard."""
+    return [shard.data.unsafe_buffer_pointer()
+            for leaf in jax.tree_util.tree_leaves(tree)
+            for shard in leaf.addressable_shards]
 
 
 def _leaves(caches, *sections):
@@ -117,7 +125,9 @@ def test_a_donated_and_an_undonated_build_are_two_builds(lm, monkeypatch):
 def test_init_caches_hands_out_nothing_of_the_callers(donating):
     """A static input that a decoder-side op reads as it came lies in
     caches["static"]: the step consumes the caches, not the caller's
-    array, which serves the next init_caches too."""
+    array, which serves the next init_caches too. So does the compiled
+    init (decode.compiled_init, the batcher's): no leaf it hands out lies
+    in a buffer of the weights' or the inputs', nor in another leaf's."""
     vocab, dec_len, hidden, bs = 24, 8, 16, 2
     cfg = FFConfig()
     cfg.batch_size = bs
@@ -136,9 +146,13 @@ def test_init_caches_hands_out_nothing_of_the_callers(donating):
     xb = jnp.asarray(rng.randn(bs, dec_len, hidden).astype(np.float32))
     full = np.asarray(m.executor.build_forward()(
         m.state.params, [jnp.asarray(xd), xb]))
-    init, step = m.executor.build_decode(bs, dec_len, decode_input=0)
-    for _ in range(2):  # the second round reads xb again
+    eager, step = m.executor.build_decode(bs, dec_len, decode_input=0)
+    theirs = set(_buffers((m.state.params, xb)))
+    # the second and third rounds read xb again
+    for init in (eager, decode.compiled_init(eager), eager):
         caches = init(m.state.params, [xb])
+        ours = _buffers(caches)
+        assert len(set(ours)) == len(ours) and not theirs & set(ours)
         assert _leaves(caches, "static")
         for t_ in range(3):
             logits, caches = step(m.state.params, caches, jnp.int32(t_),
@@ -233,54 +247,147 @@ def test_the_batcher_serves_the_same_tokens_from_donated_caches(
 
 
 def test_an_insert_never_holds_a_second_generation_of_the_caches(
-        hybrid, monkeypatch):
-    """`_insert_slot` writes a prefilled strip into every per-slot leaf.
-    Each old leaf is let go as its successor is made, so what the insert
-    holds beside the caches is one leaf, not a copy of them all (on the
-    chip that copy was the peak: PERF.md section 6, PR 29)."""
-    import weakref
-
+        hybrid, donating):
+    """`_insert_slot` writes a prefilled strip into every per-slot leaf with
+    one program. Under donation (the chip's rule) it is handed the running
+    batch: each old leaf is written in place and deleted, so what the
+    insert holds beside the caches is nothing, not a copy of them all (on
+    the chip that copy was the peak of the device's memory). The strip is
+    only read: the prefix memo keeps it to replay."""
     b = ContinuousBatcher(hybrid, _serve_cfg(), AdmissionQueue(max_depth=4))
     params = hybrid.state.params
     _, strip = b._step1(params, b._init1(params, ()), jnp.int32(0),
                         [jnp.zeros((1, 4), jnp.int32)], jnp.int32(4),
                         jnp.int32(3))
     b._insert_slot(0, strip, request="r0")
-    old = [weakref.ref(leaf) for leaf in _leaves(
-        b._caches, "prefix", "mha", "recurrent")]
+    old = _leaves(b._caches, "prefix", "mha", "recurrent")
     assert len(old) == 4  # (k, v) and (S, conv_tail)
-    alive = []
-
-    update = jax.lax.dynamic_update_slice_in_dim
-
-    def spy(*a, **kw):
-        alive.append(sum(ref() is not None for ref in old))
-        return update(*a, **kw)
-
-    monkeypatch.setattr(jax.lax, "dynamic_update_slice_in_dim", spy)
+    shared = _leaves(b._caches, "counters", "prefill_counters")
     b._insert_slot(1, strip, request="r1")
-    monkeypatch.undo()
-    assert alive == [4, 3, 2, 1]
-    assert all(ref() is None for ref in old)
-    assert len(_leaves(b._caches, "mha", "recurrent")) == 4
+    assert all(leaf.is_deleted() for leaf in old)
+    new = _leaves(b._caches, "mha", "recurrent")
+    assert len(new) == 4 and not any(leaf.is_deleted() for leaf in new)
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(strip))
+    # the shared sections pass around the program as they are
+    assert _leaves(b._caches, "counters", "prefill_counters") == shared
+    assert b.stats["insert_programs"] == 2
+
+
+def test_a_memoised_strip_outlives_the_inserts_that_replay_it(lm, donating):
+    """One prompt three times through one slot: the second and third are
+    the memo's strip replayed into a batch that donates; the strip is never
+    consumed, and every replay serves the tokens of the prefill."""
+    rng = np.random.RandomState(8)
+    prompt = rng.randint(0, VOCAB, 5).astype(np.int32)
+    outs, b = _serve(lm, [prompt] * 3, [6] * 3, VOCAB, slots=1)
+    assert b.stats["prefill_skips"] == 2 and b.stats["insert_programs"] == 3
+    strips = [strip for _, strip in b._prefix_cache.values()]
+    assert len(strips) == 1
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(strips))
+    want = incremental_generate(lm, prompt[None], max_new_tokens=6)[0]
+    for out in outs:
+        np.testing.assert_array_equal(out, want)
+
+
+def _op_tree(kind, batch, seed):
+    """A tree of caches as the ops declare them (init_decode_state), every
+    leaf noise: fused attention with as many key-value heads as query
+    heads, grouped-query, a window's ring beside a full layer, and the
+    gated delta-rule's state and convolution tail beside attention."""
+    from flexflow_tpu.ff_types import OperatorType
+    from flexflow_tpu.ops.attention import MultiHeadAttentionParams
+    from flexflow_tpu.ops.linear_attention import GatedDeltaNetParams
+    from flexflow_tpu.ops.registry import get_op_def
+
+    def mha(**kw):
+        return get_op_def(OperatorType.OP_MULTIHEAD_ATTENTION) \
+            .init_decode_state(MultiHeadAttentionParams(
+                embed_dim=24, num_heads=6, kdim=8, vdim=8, bias=False,
+                causal=True, **kw), batch, 64, jnp.bfloat16)
+
+    tree = {sec: {} for sec in decode.SHARED_SECTIONS + decode.SLOT_SECTIONS}
+    if kind == "attention":
+        tree["mha"]["a"] = mha()
+        tree["prefix"][7] = jnp.zeros((batch, 2, 64, 8), jnp.float32)
+    elif kind == "grouped_query":
+        tree["mha"]["a"] = mha(num_kv_heads=2)
+    elif kind == "ring":
+        tree["mha"]["w"] = mha(num_kv_heads=2, window=16)
+        tree["mha"]["f"] = mha(num_kv_heads=2)
+    else:
+        tree["mha"]["a"] = mha()
+        tree["recurrent"]["g"] = get_op_def(
+            OperatorType.OP_GATED_DELTA_NET).init_decode_state(
+                GatedDeltaNetParams(24, 2, 8, 16), batch, 64, jnp.bfloat16)
+    tree["counters"]["n"] = jnp.zeros((), jnp.int32)
+    rng = np.random.RandomState(seed)
+    for sec in decode.SLOT_SECTIONS:
+        tree[sec] = jax.tree_util.tree_map(
+            lambda x: jnp.asarray(rng.randn(*x.shape), x.dtype), tree[sec])
+    return tree
+
+
+@pytest.mark.parametrize("kind",
+                         ["attention", "grouped_query", "ring", "hybrid"])
+def test_the_compiled_insert_writes_what_the_eager_one_wrote(kind):
+    """insert_row's one program against the parent's eager update of each
+    per-slot leaf: the same tensors, bit for bit, in every slot, and the
+    donated batch is consumed while the row is not."""
+    batch, row = _op_tree(kind, 3, 0), _op_tree(kind, 1, 1)
+    counters = batch["counters"]
+    for slot in (2, 0):
+        want = {sec: jax.tree_util.tree_map(
+            lambda o, r: np.asarray(jax.lax.dynamic_update_slice_in_dim(
+                o, r.astype(o.dtype), slot, axis=0)), batch[sec], row[sec])
+            for sec in decode.SLOT_SECTIONS}
+        old = _leaves(batch, *decode.SLOT_SECTIONS)
+        batch = decode.insert_row(batch, row, slot, donate=True)
+        assert all(leaf.is_deleted() for leaf in old)
+        for sec in decode.SLOT_SECTIONS:
+            got = jax.tree_util.tree_leaves(batch[sec])
+            exp = jax.tree_util.tree_leaves(want[sec])
+            assert len(got) == len(exp)
+            for g, e in zip(got, exp):
+                assert (g.dtype, g.shape) == (e.dtype, e.shape)
+                assert np.asarray(g).tobytes() == e.tobytes()
+    assert not any(leaf.is_deleted()
+                   for leaf in jax.tree_util.tree_leaves(row))
+    assert batch["counters"] is counters  # passed around the program
 
 
 def test_once_served_no_step_is_built_again(donating):
     """Warm-up and the first requests go through the callables the loop
-    keeps using, `_step1` and `_stepB`, so the donated programs are the
-    ones compiled before traffic counts: a second round of requests of
-    the same buckets traces nothing (the benchmark's `compiles_in_window`
-    rests on this)."""
+    keeps using, `_init1`, `_step1`, the insert and `_stepB`, so the
+    donated programs are the ones compiled before traffic counts: a second
+    round of requests of the same buckets traces nothing, and nothing
+    served after `_warmup_compiles` compiles a program, the insert into
+    the first admission's fresh batch and into a stepped one included (the
+    benchmark's `compiles_in_window` rests on this)."""
+    import jax.monitoring
+
     from flexflow_tpu.parallel import executor as ex
 
     m = build_lm()  # its own model: no step built by an earlier test
+    for program in decode._INSERT.values():  # nor an insert
+        program.clear_cache()
     rng = np.random.RandomState(5)
     q = AdmissionQueue(max_depth=8)
     b = ContinuousBatcher(m, _serve_cfg(precompile=True), q)
     traces, real = [], ex._count_trace
+    compiled = []
+
+    def on_event(name, *_args, **kw):
+        if name.startswith(("/jax/core/compile", "/jax/compilation_cache")):
+            compiled.append((name, kw.get("fun_name")))
+
     ex._count_trace = lambda p: (traces.append(p), real(p))[1]
     try:
-        b.start()
+        b._warmup_compiles()
+        warm = list(traces)
+        jax.monitoring.register_event_duration_secs_listener(on_event)
+        b.start()  # warms again, from what is built
         built = []
         for _ in range(2):
             reqs = [GenerationRequest(rng.randint(0, VOCAB, n).astype(
@@ -291,10 +398,13 @@ def test_once_served_no_step_is_built_again(donating):
                 r.result(timeout=300.0)
             built.append(list(traces))
     finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
         ex._count_trace = real
         b.stop()
-    assert "decode_step" in built[0] and "prefill" in built[0]
-    assert built[1] == built[0]
+    for program in ("decode_step", "prefill", "insert", "init_caches"):
+        assert program in warm, program
+    assert built[1] == built[0] == warm
+    assert compiled == []
     assert b.stats["decode_caches_donated"] == 1 and b.stats["finished"] == 4
 
 

@@ -441,6 +441,35 @@ def test_lone_batcher_death_reaches_the_caller(lm):
     assert b.pool.pages_in_use == 0
 
 
+def test_one_stalled_iteration_sheds_no_request_that_fits_its_deadline(lm):
+    """The early deadline shed reads a per-token service estimate. One
+    decode iteration stalled for a while (a profiler's start or stop, a
+    host hiccup) is no proof that a request admitted right after it
+    cannot meet its deadline: it is served."""
+    fi = FaultInjector()
+    fi.inject("slow_worker", at_step=3, replica="replica0", delay_s=1.5)
+    q = AdmissionQueue(max_depth=8)
+    b = ContinuousBatcher(lm, _serve_cfg(slots=2, precompile=True), q,
+                          fault_injector=fi).start()
+    try:
+        running = GenerationRequest(np.arange(2, dtype=np.int32), 13,
+                                    deadline_s=600.0)
+        q.offer(running)
+        t0 = time.monotonic()
+        while not fi.fired.get("slow_worker") and time.monotonic() - t0 < 120:
+            time.sleep(0.002)
+        # offered inside the stall: admitted once it ends, 12 tokens of a
+        # few ms each due within 3 s
+        late = GenerationRequest(np.arange(2, dtype=np.int32) + 3, 12,
+                                 deadline_s=3.0)
+        q.offer(late)
+        assert len(late.result(timeout=60.0)) == 14
+        assert len(running.result(timeout=60.0)) == 15
+    finally:
+        b.stop()
+    assert fi.fired["slow_worker"] == 1 and b.stats["shed_decode"] == 0
+
+
 # ---------------------------------------------------------------------------
 # replica failover + rate limiting
 # ---------------------------------------------------------------------------
