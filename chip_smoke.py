@@ -684,6 +684,94 @@ def phase_server(ctx):
 
 
 # ---------------------------------------------------------------------------
+# phase: a loop region at the looped serving cell's sizes
+# ---------------------------------------------------------------------------
+LOOP_CELL = "serve-ouro2.6b-chat-saturated"
+LOOP_PROMPT = 300 if not REHEARSAL else 21   # in the bucket of 512 (32)
+LOOP_DECODES = 4
+
+
+def phase_loop(ctx):
+    """The looped serving cell's programs at its own sizes (the whole model:
+    48 layers, 4 steps): the paged kernel on the stacked pool read through
+    each step's table against the dense reference on that step's strip, then
+    one prefill bucket inserted into the batch and decode steps through
+    every step's cache, their logits judged by the plain reference as the
+    cell's `correct` judges them (the reference logit of the program's
+    token against the reference's best, under the cell's limit)."""
+    from flexflow_tpu.kernels.decode import (paged_decode_reference,
+                                             paged_flash_decode,
+                                             paged_view_of_cache)
+    from flexflow_tpu.parallel import decode
+    from perfbench.harness import runctx, serve, spec
+
+    cell = spec.cell(LOOP_CELL, rehearsal=REHEARSAL)
+    builder, ref = spec.family(cell.config)
+    z, sv = ref.sizes(cell.config), cell.params["serving"]
+    slots, steps, max_len = sv["slots"], z["steps"], sv["max_len"]
+    kv, hd = z["kv_heads"], z["head_dim"]
+    rng = np.random.RandomState(5)
+    k, v = (jnp.asarray(rng.randn(slots, steps, max_len, kv * hd),
+                        jnp.bfloat16) for _ in range(2))
+    q = jnp.asarray(rng.randn(slots, z["heads"], hd), jnp.bfloat16)
+    lengths = jnp.asarray(rng.randint(1, max_len + 1, slots), jnp.int32)
+    worst = 0.0
+    for u in range(steps):
+        kp, vp, table = paged_view_of_cache(k, v, PAGE, step=jnp.int32(u))
+        got = jax.jit(lambda *a: paged_flash_decode(
+            *a, interpret=REHEARSAL))(q, kp, vp, table, lengths)
+        k1, v1, t1 = paged_view_of_cache(k[:, u], v[:, u], PAGE)
+        want = paged_decode_reference(
+            q, k1.reshape(-1, PAGE, kv, hd), v1.reshape(-1, PAGE, kv, hd),
+            t1, lengths)
+        worst = max(worst, rel_err(got, want))
+    log(f"  paged kernel on the stacked pool {list(kp.shape)}, each of "
+        f"{steps} steps: worst rel err {worst:.2e} (tol {KERNEL_TOL})")
+    assert worst < KERNEL_TOL
+
+    sc = serve.ServeCell(cell, builder, ref, runctx.Spans())
+    sc.build()
+    sc.load_seed(41)
+    ex, params = sc.model.executor, sc.model.state.params
+    bucket = 1 << (LOOP_PROMPT - 1).bit_length()
+    prompt = rng.randint(0, z["vocab"], LOOP_PROMPT).astype(np.int32)
+    init1, step1 = ex.build_decode(1, max_len)
+    initB, stepB = sc.model.decode_executor.build_decode(slots, max_len)
+    padded = np.zeros((1, bucket), np.int32)
+    padded[0, :LOOP_PROMPT] = prompt
+    logits, strip = step1(params, decode.compiled_init(init1)(params, ()),
+                          jnp.int32(0), [jnp.asarray(padded)],
+                          jnp.int32(LOOP_PROMPT), jnp.int32(LOOP_PROMPT - 1))
+    tokens = list(prompt) + [int(np.argmax(np.asarray(logits)[0, -1]))]
+    passes = int(strip["prefill_counters"]["loop_prefill_passes"])
+    caches = decode.insert_row(initB(params, ()), strip, 0,
+                               donate=ex.donates_buffers())
+    del strip
+    for i in range(LOOP_DECODES):
+        t = np.full((slots,), len(tokens) - 1, np.int32)
+        toks = np.zeros((slots, 1), np.int32)
+        toks[0, 0] = tokens[-1]
+        logits, caches = stepB(params, caches, jnp.asarray(t),
+                               [jnp.asarray(toks)])
+        tokens.append(int(np.argmax(np.asarray(logits)[0, 0])))
+    loop_passes = int(caches["counters"]["loop_passes"])
+    log(f"  prefill of {LOOP_PROMPT} tokens in a bucket of {bucket} "
+        f"({passes} loop passes), {LOOP_DECODES} decode steps "
+        f"({loop_passes} loop passes each), tokens {tokens[-5:]}")
+    assert passes == loop_passes == steps
+    del caches, logits
+    sc.free()
+    row = {"prompt": prompt, "tokens": np.asarray(tokens, np.int32)}
+    gap = float(serve.logit_gaps(ref, cell.config, 41, [row])[0].max())
+    limit = cell.params["limits"]["worst_logit_gap"]
+    log(f"  against the plain reference: worst logit gap {gap:.4f} "
+        f"(the cell's limit {limit})")
+    assert gap < limit
+    ctx["loop"] = {"paged_stacked_rel_err": worst, "worst_logit_gap": gap,
+                   "loop_passes": loop_passes}
+
+
+# ---------------------------------------------------------------------------
 # driver
 # ---------------------------------------------------------------------------
 def main() -> int:
@@ -727,7 +815,7 @@ def main() -> int:
                            "chiprun_out", "chip_smoke")
     tel = obs.start(obs.TelemetryConfig(dir=os.path.join(out_dir,
                                                          "telemetry")))
-    ctx = {"kernels": [], "trainer": {}, "server": None}
+    ctx = {"kernels": [], "trainer": {}, "server": None, "loop": None}
     phases = [
         ("kernels", lambda: phase_kernels(ctx)),
         ("train one chip, 1 step per dispatch",
@@ -736,6 +824,7 @@ def main() -> int:
          lambda: run_trainer(ctx, "one_chip_scan", chips=1,
                              steps_per_dispatch=STEPS_PER_DISPATCH)),
         ("serve one chip", lambda: phase_server(ctx)),
+        ("serve a loop region", lambda: phase_loop(ctx)),
     ]
     if device["count"] >= 4:
         def four(name, **kw):
@@ -794,6 +883,7 @@ def main() -> int:
         "kernels_checked": len(ctx["kernels"]),
         "trainer": ctx["trainer"],
         "server": ctx["server"],
+        "loop": ctx["loop"],
         "fallback_counters": fallbacks,
         "compile_cache": {"dir": cache_dir, "before": entries_before,
                           "after": entries_after},

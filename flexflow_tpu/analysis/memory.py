@@ -59,7 +59,9 @@ def estimate_per_device_bytes(
     Sharded weights divide by their degree via ``_shard_bytes``: an
     FSDP/ZeRO weight (parallel/weight_sharding.py) therefore charges
     ``bytes/degree x (1 + grad + slots)`` per device — the gradient
-    buffer and the optimizer state shard with the parameter."""
+    buffer and the optimizer state shard with the parameter. An op inside
+    a loop region (FFModel.loop) holds its weights once and, in training,
+    its activations once a step."""
     views = views or {}
     wmul: Optional[float] = None
     per_dev: Dict[int, int] = {}
@@ -67,6 +69,10 @@ def estimate_per_device_bytes(
     for op in graph.ops:
         act = sum(_shard_bytes(t) for t in op.inputs)
         act += sum(_shard_bytes(t) for t in op.outputs)
+        if train and getattr(op, "loop", None) is not None:
+            # a loop region keeps its ops' activations a step for the
+            # backward; its weights are held once
+            act *= op.loop.steps
         wb = 0
         if op.weights:
             if wmul is None:

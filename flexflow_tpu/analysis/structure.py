@@ -9,7 +9,7 @@ tensor is produced at most once, shapes are valid, and the graph is
 acyclic.
 
 Codes: FFA001 dangling input, FFA002 invalid dims, FFA003 cycle,
-FFA004 duplicate producer.
+FFA004 duplicate producer, FFA005 a loop region that is not whole.
 """
 from __future__ import annotations
 
@@ -62,6 +62,10 @@ def structural_diagnostics(graph) -> AnalysisReport:
                 )
             # owner None -> true graph input: fine
     _check_acyclic(graph, producers, rep)
+    for op, msg in getattr(graph, "loop_problems", list)():
+        rep.add(Severity.ERROR, "FFA005", msg, op=op,
+                fix_hint="a rewrite must keep a loop region whole "
+                         "(FFModel.loop)")
     return rep
 
 

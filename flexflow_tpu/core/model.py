@@ -148,6 +148,8 @@ class FFModel:
         # Tensor.guid -> scalar fill value OR baked np.ndarray contents
         self._constant_values: Dict[int, Union[float, np.ndarray]] = {}
         self._rng = jax.random.PRNGKey(self.config.seed)
+        # the loop region layers are added to (FFModel.loop), or None
+        self._loop = None
 
     # ------------------------------------------------------------------
     # Graph building (reference: FFModel::create_tensor, model.cc)
@@ -177,6 +179,8 @@ class FFModel:
         if not name:
             name = f"{op_type.name.lower()}_{len(self.layers)}"
         layer = Layer(op_type, params, inputs, name=name)
+        if self._loop is not None:
+            layer.loop = self._loop.mark
         if initializers:
             layer.initializers.update(
                 {k: v for k, v in initializers.items() if v is not None}
@@ -435,6 +439,38 @@ class FFModel:
             num_microbatches=self.config.num_microbatches,
         )
         return self._add_layer(OperatorType.OP_BLOCK_STACK, p, [input], name)
+
+    @contextlib.contextmanager
+    def loop(self, steps: int, name: str = "loop"):
+        """A loop region: the layers added inside run `steps` times over ONE
+        copy of their weights, each step on what the last one gave. In it,
+        `enter(x)` takes the tensor the first step reads and returns the one
+        the body reads (every later step reads the exit there); `exit(y)`
+        names the body's result, which after the region holds the last
+        step's. Lowered as one loop body in training, forward, prefill and
+        decode; an op that keeps decode state keeps one copy a step
+        (docs/models.md).
+
+            with model.loop(4, name="ut") as ut:
+                h = ut.enter(x)
+                h = block(h)
+                y = ut.exit(h)
+        """
+        if self._loop is not None:
+            raise ValueError(f"loop {name!r} inside loop "
+                             f"{self._loop.mark.name!r}: regions do not nest")
+        if int(steps) < 1:
+            raise ValueError(f"loop {name!r}: steps must be >= 1, got {steps}")
+        if any(layer.loop is not None and layer.loop.name == name
+               for layer in self.layers):
+            raise ValueError(f"a loop named {name!r} exists already")
+        region = _LoopBuilder(self, int(steps), name)
+        self._loop = region
+        try:
+            yield region
+        finally:
+            self._loop = None
+        region.close()
 
     # elementwise binary
     def _binary(self, t: OperatorType, x: Tensor, y: Tensor, name: str) -> Tensor:
@@ -3241,6 +3277,46 @@ class FFModel:
         dl = SingleDataLoader(self, batch_tensor, full_array)
         self._dataloaders.append(dl)
         return dl
+
+
+class _LoopBuilder:
+    """What `FFModel.loop` yields: marks the region's entry and exit."""
+
+    def __init__(self, model: "FFModel", steps: int, name: str):
+        from ..pcg.graph import LoopMark
+
+        self.model, self.name = model, name
+        self.mark = LoopMark(name, steps)
+        self.entry: Optional[Layer] = None
+        self.exit_layer: Optional[Layer] = None
+
+    def _role(self, layer: Layer, role: str) -> None:
+        layer.loop = dataclasses.replace(self.mark, role=role)
+
+    def enter(self, x: Tensor) -> Tensor:
+        if self.entry is not None:
+            raise ValueError(f"loop {self.name!r} has an entry already")
+        h = self.model.identity(x, name=f"{self.name}.entry")
+        self.entry = h.owner_layer
+        self._role(self.entry, "entry")
+        return h
+
+    def exit(self, y: Tensor) -> Tensor:
+        layer = y.owner_layer
+        if self.entry is None or layer is None or layer is self.entry \
+                or layer.loop is None or layer.loop.name != self.name:
+            raise ValueError(f"loop {self.name!r}: exit() takes a tensor "
+                             "the body made after enter()")
+        if y.owner_idx != 0 or y.dims != self.entry.inputs[0].dims:
+            raise ValueError(f"loop {self.name!r}: the exit must be its "
+                             "op's first output, shaped as the entry's input")
+        self.exit_layer = layer
+        self._role(layer, "exit")
+        return y
+
+    def close(self) -> None:
+        if self.entry is None or self.exit_layer is None:
+            raise ValueError(f"loop {self.name!r} needs enter() and exit()")
 
 
 def _unwrap_loaders(x, y):

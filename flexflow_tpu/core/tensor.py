@@ -129,6 +129,8 @@ class Layer:
         self.name = name or f"{op_type.name.lower()}_{self.guid}"
         # per-weight initializer overrides: weight name -> Initializer
         self.initializers: Dict[str, object] = {}
+        # the loop region the layer belongs to (FFModel.loop), or None
+        self.loop = None
 
     def get_output_tensor(self, idx: int = 0) -> Tensor:
         return self.outputs[idx]

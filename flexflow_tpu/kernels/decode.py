@@ -339,18 +339,26 @@ def paged_decode_reference(q, k_pages, v_pages, page_table, lengths):
     return out.astype(q.dtype)
 
 
-def paged_view_of_cache(k_cache, v_cache, page_size: int):
+def paged_view_of_cache(k_cache, v_cache, page_size: int, step=None):
     """View the batcher's dense per-slot caches (slots, max_len, heads*d)
     as a paged pool (slots*pages_per_slot, page_size, heads*d): slot b's
     logical page i is physical page ``b * pages_per_slot + i``. A pure
     reshape of the strips as they lie; nothing moves. Requires
-    page_size | max_len."""
-    b, max_len, _ = k_cache.shape
+    page_size | max_len.
+
+    The caches of an op inside a loop region hold every step's strip,
+    (slots, steps, max_len, heads*d): the pool is then all of them, and the
+    table of `step` (a traced int) points each slot at its step's pages,
+    ``(b * steps + step) * pages_per_slot + i``, so one step is read in
+    place and no slice of the pool is made."""
+    b, max_len = k_cache.shape[0], k_cache.shape[-2]
     if page_size <= 0 or max_len % page_size:
         raise ValueError(
             f"page_size {page_size} must divide the cache length {max_len}")
     pp = max_len // page_size
-    table = (jnp.arange(b)[:, None] * pp + jnp.arange(pp)[None, :]) \
-        .astype(jnp.int32)
-    return (k_cache.reshape(b * pp, page_size, -1),
-            v_cache.reshape(b * pp, page_size, -1), table)
+    strips = b if step is None else b * k_cache.shape[1]
+    first = jnp.arange(b) if step is None \
+        else jnp.arange(b) * k_cache.shape[1] + step
+    table = (first[:, None] * pp + jnp.arange(pp)[None, :]).astype(jnp.int32)
+    return (k_cache.reshape(strips * pp, page_size, -1),
+            v_cache.reshape(strips * pp, page_size, -1), table)

@@ -796,8 +796,16 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
     k_cache, v_cache = cache
     b, s0, h = q.shape[:3]
     group = params.group
-    max_len = k_cache.shape[1]
+    # inside a loop region the cache holds every step's keys and values,
+    # (b, steps, max_len, kv_heads*d), and this call is step `u`'s: it
+    # writes its slice in place and reads that slice alone
+    u = ctx.loop_step
+    max_len = k_cache.shape[-2]
     window = params.window
+    if u is not None and window:
+        raise NotImplementedError(
+            f"{ctx.op_name}: a window layer's ring has no copy a step, so "
+            "it cannot decode inside a loop region")
     per_row_t = getattr(t, "ndim", 0) == 1
     if params.marked:
         # the rows' true positions, (s0,) or per row (b, s0)
@@ -833,14 +841,22 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
     elif per_row_t:
         row_update = jax.vmap(
             lambda c, n, tt: jax.lax.dynamic_update_slice(c, n, (tt, 0))
+            if u is None else
+            jax.lax.dynamic_update_slice(c, n[None], (u, tt, 0))
         )
         k_cache = row_update(k_cache, k_new, t)
         v_cache = row_update(v_cache, v_new, t)
-    else:
+    elif u is None:
         k_cache = jax.lax.dynamic_update_slice(k_cache, k_new, (0, t, 0))
         v_cache = jax.lax.dynamic_update_slice(v_cache, v_new, (0, t, 0))
+    else:
+        k_cache = jax.lax.dynamic_update_slice(
+            k_cache, k_new[:, None], (0, u, t, 0))
+        v_cache = jax.lax.dynamic_update_slice(
+            v_cache, v_new[:, None], (0, u, t, 0))
     if k_att is None:
-        k_att, v_att = k_cache, v_cache
+        k_att, v_att = (c if u is None else jax.lax.dynamic_index_in_dim(
+            c, u, axis=1, keepdims=False) for c in (k_cache, v_cache))
     if params.marked and s0 == 1:
         # positions whose keys this step reads, over the rows
         seen = jnp.broadcast_to(jnp.asarray(t, jnp.int32), (b,)) + 1
@@ -892,8 +908,10 @@ def _forward_decode(params, weights, inputs, ctx, cache, t, valid=None):
             use_paged = False
     with _scope(params, params.kind):
         if use_paged:
+            # the whole cache as the pool, step u's pages by the table
             kp, vp, table = paged_view_of_cache(
-                k_cache.astype(q.dtype), v_cache.astype(q.dtype), page_size)
+                k_cache.astype(q.dtype), v_cache.astype(q.dtype), page_size,
+                step=u)
             lengths = (t.astype(jnp.int32) if per_row_t
                        else jnp.full((b,), t, jnp.int32)) + 1
             if window:  # a full ring is read whole, in whatever order it lies
@@ -1092,6 +1110,8 @@ register_op(
     forward_decode=_forward_decode,
     init_decode_state=init_decode_cache,
     decode_section="mha",
+    # in a loop region the cache holds every step's keys and values
+    loop_state=True,
     # a marked op counts the positions its decode steps read, by its kind
     decode_counters=lambda p: (
         (f"attn_{p.kind}_positions_read",) if p.marked else ()),

@@ -90,6 +90,12 @@ class OpDef:
     # read-only to eval/forward.
     state_spec: Optional[Callable] = None
     forward_stateful: Optional[Callable] = None
+    # Whether forward_decode takes a state stacked over a loop region's
+    # steps (FFModel.loop): every leaf with the steps on its axis 1, after
+    # the slot axis, and FwdCtx.loop_step the step whose slice the call
+    # reads and writes in place. A stateful op without it cannot decode
+    # inside a region.
+    loop_state: bool = False
 
     def counters_of(self, params, which: str = "decode") -> Tuple[str, ...]:
         names = self.decode_counters if which == "decode" \
@@ -123,6 +129,7 @@ def register_op(
     prefill_counters: object = (),
     state_spec: Optional[Callable] = None,
     forward_stateful: Optional[Callable] = None,
+    loop_state: bool = False,
 ) -> OpDef:
     d = OpDef(
         op_type=op_type,
@@ -143,6 +150,7 @@ def register_op(
         else tuple(prefill_counters),
         state_spec=state_spec,
         forward_stateful=forward_stateful,
+        loop_state=loop_state,
     )
     _REGISTRY[op_type] = d
     return d
@@ -188,6 +196,10 @@ class FwdCtx:
     # Integer counters of one traced decode step, by name (OpDef.
     # decode_counters); None wherever nobody collects them.
     counters: Optional[dict] = None
+    # The step of a loop region a decode call runs (a traced int32), whose
+    # slice of a stacked state it reads and writes (OpDef.loop_state); None
+    # outside a region.
+    loop_step: Optional[object] = None
 
     def add_aux_loss(self, value):
         if self.aux_losses is not None:

@@ -62,6 +62,7 @@ import numpy as np
 
 from ..ff_types import AggrMode, OperatorType
 from ..ops.registry import FwdCtx, get_op_def, has_op_def
+from ..pcg.graph import LoopRegion
 
 NEG_INF = -1e30
 
@@ -143,6 +144,9 @@ class DecodePlan:
     live_len: int  # compiled decoder length L
     decode_pt: object  # the decode-driving input ParallelTensor
     requires_cap_le_live_len: bool  # static slicing present
+    # loop regions (pcg/graph.py LoopRegion), each run as one body over its
+    # steps; their ops stand in live_ops once
+    loops: List = dataclasses.field(default_factory=list)
 
     def cached_pt(self, guid):
         """The tensor behind one of cached_guids."""
@@ -648,7 +652,7 @@ def _prove_causal(softmax_op, prop: "_Propagator", live_ops, static_ops,
 
 
 def build_plan(topo, input_pts, constants, decode_input: Optional[int] = None,
-               assume_causal: bool = False):
+               assume_causal: bool = False, loops=()):
     """Classify ops/tensors and validate decodability.
 
     decode_input: index into input_pts of the decode-driven input; default
@@ -657,6 +661,9 @@ def build_plan(topo, input_pts, constants, decode_input: Optional[int] = None,
     (graphs whose masks are computed rather than baked can't be verified
     at build time — the caller vouches that decoder self-attention is
     causal).
+    loops: the graph's loop regions (Graph.loops). A region's body is
+    proven once, on the axes of its source, and its exit must come out on
+    the same axes, as every later step reads it there.
     """
     inputs = list(input_pts)
     if decode_input is None:
@@ -711,6 +718,23 @@ def build_plan(topo, input_pts, constants, decode_input: Optional[int] = None,
                     "graphs. Pass assume_causal=True to vouch that "
                     "decoder self-attention is causal."
                 )
+    for reg in loops:
+        where = f"loop {reg.name!r}"
+        if not prop.get(reg.entry.outputs[0].guid).is_live:
+            raise DecodeExactnessError(
+                f"{where}: its source does not depend on the decode input")
+        if prop.get(reg.exit.guid) != prop.get(reg.source.guid):
+            raise DecodeExactnessError(
+                f"{where}: the exit's positions lie on other axes "
+                f"({prop.get(reg.exit.guid)}) than the source's "
+                f"({prop.get(reg.source.guid)}) it feeds back")
+        inside = {op.guid for op in reg.ops}
+        if any(op.guid in inside for op in prop.static_keyed) or any(
+                op.guid in inside for op in live_ops
+                if any(x.guid in prop.cached for x in op.outputs)):
+            raise DecodeExactnessError(
+                f"{where}: attention from primitive ops or over a static "
+                "side has no decode rule inside a loop region")
     return DecodePlan(
         live_ops=live_ops,
         static_ops=static_ops,
@@ -721,6 +745,7 @@ def build_plan(topo, input_pts, constants, decode_input: Optional[int] = None,
         live_len=live_len,
         decode_pt=decode_pt,
         requires_cap_le_live_len=prop.saw_static_slicing,
+        loops=list(loops),
     )
 
 
@@ -967,22 +992,34 @@ def state_bytes(caches) -> Dict[str, int]:
             for kind, sections in STATE_KINDS.items()}
 
 
-def kv_bytes_by_kind(caches, max_len: int) -> Dict[str, int]:
+def kv_bytes_by_kind(caches, max_len: int, looped=()) -> Dict[str, int]:
     """The keys and values the fused attention ops hold, split by what
     their leaves are: "window" for a ring shorter than `max_len` (a window
-    layer keeps its last positions alone), "full" for the rest."""
+    layer keeps its last positions alone), "full" for the rest; and again,
+    as "loop", those of the ops named in `looped` (the ops of loop regions,
+    looped_ops), every step's."""
     out = {"window": 0, "full": 0}
     for leaf in jax.tree_util.tree_leaves(caches["mha"]):
-        out["window" if leaf.shape[1] < max_len else "full"] += leaf.nbytes
+        out["window" if leaf.shape[-2] < max_len else "full"] += leaf.nbytes
+    if looped:
+        out["loop"] = int(sum(
+            leaf.nbytes for name, state in caches["mha"].items()
+            if name in looped for leaf in jax.tree_util.tree_leaves(state)))
     return out
+
+
+def looped_ops(topo) -> frozenset:
+    """The names of the ops of `topo` inside a loop region."""
+    return frozenset(op.name for op in topo if op.loop is not None)
 
 
 def declared_state_bytes(topo, kind: str, max_len: int, dtype) -> int:
     """Bytes ONE slot of `max_len` positions holds of `kind` across the
     ops of `topo`, by what each op's definition says it keeps
-    (init_decode_state, its shapes alone: nothing is allocated). Counts
-    every op of the graph, as the sizing formula does (docs/serving.md),
-    not only those a decode plan finds live."""
+    (init_decode_state, its shapes alone: nothing is allocated), an op in a
+    loop region once a step. Counts every op of the graph, as the sizing
+    formula does (docs/serving.md), not only those a decode plan finds
+    live."""
     total = 0
     for op in topo:
         if not has_op_def(op.op_type):
@@ -992,8 +1029,10 @@ def declared_state_bytes(topo, kind: str, max_len: int, dtype) -> int:
             continue
         leaves = jax.tree_util.tree_leaves(jax.eval_shape(functools.partial(
             d.init_decode_state, op.params, 1, max_len, dtype)))
-        total += sum(int(np.prod(leaf.shape, dtype=np.int64))
-                     * leaf.dtype.itemsize for leaf in leaves)
+        # an op in a loop region keeps one copy a step
+        steps = op.loop.steps if op.loop is not None else 1
+        total += steps * sum(int(np.prod(leaf.shape, dtype=np.int64))
+                             * leaf.dtype.itemsize for leaf in leaves)
     return total
 
 
@@ -1146,16 +1185,29 @@ def _check_donated(caches, new_caches) -> None:
                     f"{new.dtype}{list(new.shape)}")
 
 
+# what a loop region's body counts each time it runs, in a decode step and
+# in a prefill block
+LOOP_PASSES = {"decode": "loop_passes", "prefill": "loop_prefill_passes"}
+
+
 def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                batch: int, max_len: int, cache_dtype=None,
                decode_input: Optional[int] = None,
-               assume_causal: bool = False, donate: bool = False):
+               assume_causal: bool = False, donate: bool = False,
+               loops=()):
     """(init_caches, step) over the graph `topo`: the contract is
     PCGExecutor.build_decode's, which memoises this by its arguments.
     Decode is device-local: parallel ops are the identity and the weights
-    are read where the training executor placed them."""
+    are read where the training executor placed them.
+
+    A loop region (`loops`, Graph.loops) runs as ONE fori_loop body over
+    its steps in every step and prefill block. A stateful op inside it
+    keeps one state a step, stacked on axis 1 after the slot axis (so
+    insert_row and the rest take it as any per-slot leaf); the loop carries
+    the stacked leaves, donated as the rest, and step u reads and writes
+    its own slice in place (OpDef.loop_state, FwdCtx.loop_step)."""
     plan = build_plan(topo, input_pts, constants, decode_input,
-                      assume_causal=assume_causal)
+                      assume_causal=assume_causal, loops=loops)
     _check_plan(plan, logits_pt, max_len)
     cdt = cache_dtype or compute_dtype or jnp.float32
     static_pts = [pt for pt in input_pts if pt.guid != plan.decode_pt.guid]
@@ -1176,6 +1228,12 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                 and get_op_def(op.op_type).decode_section is not None
                 and id(op) not in static_keyed_set]
     stateful_set = {id(op) for op in stateful}
+    loop_of = {op.guid: reg for reg in plan.loops for op in reg.ops}
+    for op in stateful:
+        if op.guid in loop_of and not get_op_def(op.op_type).loop_state:
+            raise DecodeExactnessError(
+                f"{op.name} ({op.op_type.name}): its decode state has no "
+                "copy a step, so it cannot decode inside a loop region")
     needs_params = bool(static_keyed) or any(
         op.weights for op in plan.static_ops if not op.is_parallel_op
     )
@@ -1185,6 +1243,9 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
         for name in get_op_def(op.op_type).counters_of(op.params, which)})
         for which, sec in (("decode", "counters"),
                            ("prefill", "prefill_counters"))}
+    if plan.loops:
+        counter_names["counters"].append(LOOP_PASSES["decode"])
+        counter_names["prefill_counters"].append(LOOP_PASSES["prefill"])
 
     def init_caches(params=None, static_inputs=()):
         assert len(static_inputs) == len(static_pts), (
@@ -1219,8 +1280,13 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
             caches["prefix"][g] = jnp.zeros(shape, pt.data_type.jnp_dtype)
         for op in stateful:
             d = get_op_def(op.op_type)
-            caches[d.decode_section][op.name] = d.init_decode_state(
-                op.params, batch, max_len, cdt)
+            state = d.init_decode_state(op.params, batch, max_len, cdt)
+            reg = loop_of.get(op.guid)
+            if reg is not None:  # one copy a step, on axis 1
+                state = jax.tree_util.tree_map(
+                    lambda a, n=reg.steps: jnp.broadcast_to(
+                        a[:, None], a.shape[:1] + (n,) + a.shape[1:]), state)
+            caches[d.decode_section][op.name] = state
         for sec, names in counter_names.items():
             for name in names:
                 caches[sec][name] = jnp.zeros((), jnp.int32)
@@ -1242,6 +1308,24 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                                OperatorType.OP_SOFTMAX)
              or any(x.guid in cached_set for x in op.outputs))),
         default=-1)
+    # what the step runs: each live op, and each loop region (its live ops)
+    # where its last live op stands; `cut` is the item after which a block
+    # is cut to one row, never inside a region, whose later steps need the
+    # whole block
+    schedule, cut, region_ops = [], -1, {}
+    for reg in plan.loops:
+        region_ops[reg.name] = [op for op in plan.live_ops
+                                if loop_of.get(op.guid) is reg]
+    for i, op in enumerate(plan.live_ops):
+        reg = loop_of.get(op.guid)
+        if reg is None:
+            schedule.append(op)
+        elif op is region_ops[reg.name][-1]:
+            schedule.append(reg)
+        else:
+            continue
+        if last_mixing >= 0 and i >= last_mixing and cut < 0:
+            cut = len(schedule) - 1
 
     def step(params, caches, t, batch_inputs, valid=None, row=None):
         from .executor import _count_trace
@@ -1277,7 +1361,7 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                 return statics[g]
             return consts[g]
 
-        def aligned_input(x, out_rank, out_info, site=""):
+        def aligned_input(vals, x, out_rank, out_info, site=""):
             """A live op's input value: live tensors yield their
             current slice; static/constant operands are sliced where
             their full-length axes align with the live/prefix axes."""
@@ -1293,7 +1377,9 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
             return _slice_aligned(full, amap, t, s0, max_len,
                                   out_rank=out_rank, site=site)
 
-        def run_op(op):
+        def run_op(op, vals, sctx, held):
+            """One op on the values of `vals`, its state read from and its
+            successor written to `held` (per-slot sections)."""
             if op.is_parallel_op:
                 vals[op.outputs[0].guid] = vals[op.inputs[0].guid]
                 return
@@ -1304,10 +1390,10 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
 
             if id(op) in stateful_set:
                 ins = [vals[x.guid] for x in op.inputs]
-                outs, new_caches[d.decode_section][op.name] = \
+                outs, held[d.decode_section][op.name] = \
                     d.forward_decode(
                         op.params, w, ins, sctx,
-                        caches[d.decode_section][op.name], t, valid=valid)
+                        held[d.decode_section][op.name], t, valid=valid)
             elif id(op) in static_keyed_set:
                 outs = d.forward_decode_static(
                     op.params, w, [vals[op.inputs[0].guid]], sctx,
@@ -1320,7 +1406,7 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                      else get_static(a_pt.guid))
                 b_info = info.get(b_pt.guid, AxisInfo())
                 if b_pt.guid in cached_set:
-                    b = new_caches["prefix"][b_pt.guid]
+                    b = held["prefix"][b_pt.guid]
                 elif b_info.is_live:
                     b = vals[b_pt.guid]
                 else:
@@ -1358,24 +1444,71 @@ def build_step(topo, input_pts, constants, logits_pt, compute_dtype, *,
                 outs = [jnp.reshape(x, target)]
             else:
                 out_rank = len(op.outputs[0].material_shape())
-                ins = [aligned_input(x, out_rank, out_info, op.name)
+                ins = [aligned_input(vals, x, out_rank, out_info, op.name)
                        for x in op.inputs]
                 outs = d.forward(op.params, w, ins, sctx)
 
             for x, v in zip(op.outputs, outs):
                 vals[x.guid] = v
                 if x.guid in cached_set:
-                    new_caches["prefix"][x.guid] = _append(
-                        caches["prefix"][x.guid], v, t, info[x.guid].live,
+                    held["prefix"][x.guid] = _append(
+                        held["prefix"][x.guid], v, t, info[x.guid].live,
                         x.guid)
+
+        def run_loop(reg):
+            """The region's live ops as ONE fori_loop body over its steps:
+            the value fed back and the stacked states of its stateful ops
+            are the carry, and what the body counts is summed over the
+            steps (a name that ends in "_max" keeps the largest)."""
+            body = [op for op in region_ops[reg.name] if op is not reg.entry]
+            mine = [op for op in body if id(op) in stateful_set]
+            which = "decode" if s0 == 1 else "prefill"
+            names = sorted({LOOP_PASSES[which]} | {
+                name for op in body if not op.is_parallel_op
+                for name in get_op_def(op.op_type).counters_of(
+                    op.params, which)})
+            held = {sec: {} for sec in SLOT_SECTIONS}
+            for op in mine:
+                sec = get_op_def(op.op_type).decode_section
+                held[sec][op.name] = new_caches[sec][op.name]
+
+            def one_step(u, carry):
+                x, held, counts = carry
+                bctx = dataclasses.replace(sctx, counters={}, loop_step=u)
+                bvals = dict(vals)
+                bvals[reg.entry.outputs[0].guid] = x
+                held = {sec: dict(v) for sec, v in held.items()}
+                for op in body:
+                    with jax.named_scope(op.name):
+                        run_op(op, bvals, bctx, held)
+                bctx.count(LOOP_PASSES[which], jnp.int32(1))
+                got = {n: jnp.asarray(bctx.counters.get(n, 0), jnp.int32)
+                       for n in names}
+                counts = {n: jnp.maximum(c, got[n]) if n.endswith("_max")
+                          else c + got[n] for n, c in counts.items()}
+                return bvals[reg.exit.guid].astype(x.dtype), held, counts
+
+            with jax.named_scope("ff.loop"):
+                x, held, counts = jax.lax.fori_loop(
+                    0, reg.steps, one_step,
+                    (vals[reg.source.guid], held,
+                     {n: jnp.zeros((), jnp.int32) for n in names}))
+            vals[reg.exit.guid] = x
+            for sec, states in held.items():
+                new_caches[sec].update(states)
+            for name, value in counts.items():
+                sctx.count(name, value)
 
         # the same scopes as the train step's forward: ff.decode, then
         # one per PCG operator
         with jax.named_scope("ff.decode"):
-            for i, op in enumerate(plan.live_ops):
-                with jax.named_scope(op.name):
-                    run_op(op)
-                if i == last_mixing and row is not None and s0 > 1:
+            for i, item in enumerate(schedule):
+                if isinstance(item, LoopRegion):
+                    run_loop(item)
+                else:
+                    with jax.named_scope(item.name):
+                        run_op(item, vals, sctx, new_caches)
+                if i == cut and row is not None and s0 > 1:
                     # from here on one position a row: every live
                     # value is cut to it, and static operands are
                     # sliced at that position (aligned_input reads

@@ -176,6 +176,19 @@ class PCGExecutor:
         self.grad_dtype = grad_dtype
         self.seed = seed
         self.topo = graph.topo_order()
+        # loop regions (FFModel.loop): each runs as one body over its steps
+        self.loops = graph.loops()
+        self._loop_of = {op.guid: reg for reg in self.loops for op in reg.ops}
+        for op in (o for o in self.topo if o.guid in self._loop_of):
+            if not op.is_parallel_op \
+                    and get_op_def(op.op_type).forward_stateful is not None:
+                raise NotImplementedError(
+                    f"{op.name}: an op with cross-batch buffers cannot run "
+                    "inside a loop region")
+        # an op's index among COMPUTE ops, which its rng folds in
+        self._compute_idx = {
+            op.guid: i for i, op in enumerate(
+                o for o in self.topo if not o.is_parallel_op)}
         # User-facing input order is tensor *creation* order (the order of
         # FFModel.create_tensor calls), not graph consumption order —
         # multi-input models (DLRM dense+sparse, enc-dec) depend on it.
@@ -215,6 +228,9 @@ class PCGExecutor:
         if pipe > 1 and not any(
             op.op_type == OperatorType.OP_BLOCK_STACK for op in self.topo
         ):
+            if self.loops:
+                raise NotImplementedError(
+                    "a loop region cannot be cut into pipeline stages")
             self.pipeline_plan = self._plan_pcg_pipeline(pipe)
 
     # -- generalized pipeline planning --------------------------------------
@@ -544,8 +560,9 @@ class PCGExecutor:
                 vals[guid] = jnp.full(
                     pt.material_shape(), value, pt.data_type.jnp_dtype
                 )
-        compute_idx = 0
-        for op in self.topo:
+        aux = [aux_out]
+
+        def run(op, vals, rng):
             # one scope per PCG operator, parallel operators included:
             # a device operation then names the graph node the search
             # priced, a collective the Repartition / Combine / Reduction
@@ -562,16 +579,15 @@ class PCGExecutor:
                     # raw topo position (the search inserts partition/combine
                     # ops per mesh, which would make masks mesh-dependent)
                     op_rng = (
-                        jax.random.fold_in(rng, compute_idx)
+                        jax.random.fold_in(rng, self._compute_idx[op.guid])
                         if rng is not None else None
                     )
-                    compute_idx += 1
                     ctx = FwdCtx(
                         training=training,
                         rng=op_rng,
                         seq_length=seq_length,
                         compute_dtype=self.compute_dtype,
-                        aux_losses=aux_out,
+                        aux_losses=aux[0],
                         n_devices=self.mesh.size,
                         mesh=self.mesh,
                         op_name=op.name,
@@ -604,6 +620,43 @@ class PCGExecutor:
                         outs = opdef.forward(op.params, w, ins, ctx)
                 for t, o in zip(op.outputs, outs):
                     vals[t.guid] = self._constrain(o, t)
+
+        def run_loop(reg):
+            """The region's body as ONE scan over its steps, the weights
+            closed over (so a weight's gradient is the sum over the steps);
+            an op's rng also folds in the step."""
+            src = vals[reg.source.guid]
+            outer, aux[0] = aux[0], None if aux_out is None else []
+            had_aux = []
+
+            def body(carry, u):
+                bvals = dict(vals)
+                bvals[reg.entry.outputs[0].guid] = self._constrain(
+                    carry, reg.entry.outputs[0])
+                if aux[0] is not None:
+                    aux[0] = []
+                for op in reg.body:
+                    run(op, bvals,
+                        None if rng is None else jax.random.fold_in(rng, u))
+                losses = aux[0] or []
+                had_aux.append(bool(losses))
+                out = bvals[reg.exit.guid].astype(src.dtype)
+                return out, sum(losses, jnp.zeros((), jnp.float32))
+
+            with jax.named_scope("ff.loop"):
+                out, step_aux = jax.lax.scan(
+                    body, src, jnp.arange(reg.steps, dtype=jnp.int32))
+            aux[0] = outer
+            vals[reg.exit.guid] = out
+            if outer is not None and any(had_aux):
+                outer.append(jnp.sum(step_aux))
+
+        for op in self.topo:
+            reg = self._loop_of.get(op.guid)
+            if reg is None:
+                run(op, vals, rng)
+            elif op is reg.ops[-1]:
+                run_loop(reg)
         return vals
 
     # -- step functions -----------------------------------------------------
@@ -1310,7 +1363,8 @@ class PCGExecutor:
                 self.topo, self.input_pts, self.constants, self.logits_pt,
                 self.compute_dtype, batch=batch, max_len=max_len,
                 cache_dtype=cache_dtype, decode_input=decode_input,
-                assume_causal=assume_causal, donate=donate)
+                assume_causal=assume_causal, donate=donate,
+                loops=self.loops)
         return built
 
     # -- data placement -----------------------------------------------------

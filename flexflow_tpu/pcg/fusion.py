@@ -58,8 +58,11 @@ def apply_fusion(graph: Graph) -> Graph:
                 consumers.setdefault(p[0].guid, []).append(op)
 
     def fusable(op: PCGOp) -> bool:
+        # a loop region's ops stay as they are: its body runs as one
+        # program over the steps (FFModel.loop)
         return (
-            op.op_type in _FUSABLE
+            op.loop is None
+            and op.op_type in _FUSABLE
             and len(op.inputs) == 1
             and len(op.outputs) == 1
         )

@@ -18,6 +18,40 @@ from .parallel_tensor import ParallelTensor
 
 
 @dataclasses.dataclass(frozen=True)
+class LoopMark:
+    """An op's place in a loop region (FFModel.loop): the region's name and
+    step count, and its role. The "entry" is an identity whose input is the
+    region's source: the first step reads the source, every later step the
+    value of the "exit", the op whose first output the region hands on."""
+
+    name: str
+    steps: int
+    role: str = "body"  # "entry" | "body" | "exit"
+
+
+@dataclasses.dataclass
+class LoopRegion:
+    """A loop region as the graph holds it: its ops in topological order,
+    entry first, and the tensors at its edge."""
+
+    name: str
+    steps: int
+    ops: List[PCGOp]
+    entry: PCGOp
+    exit: ParallelTensor
+
+    @property
+    def source(self) -> ParallelTensor:
+        """What the first step's entry reads."""
+        return self.entry.inputs[0]
+
+    @property
+    def body(self) -> List[PCGOp]:
+        """The ops a step runs after the entry."""
+        return [op for op in self.ops if op is not self.entry]
+
+
+@dataclasses.dataclass(frozen=True)
 class Edge:
     """reference: graph.h:31 Edge{srcOp,dstOp,srcIdx,dstIdx}"""
 
@@ -37,6 +71,76 @@ class Graph:
         self.ops: List[PCGOp] = list(ops) if ops else []
         # external inputs: ParallelTensors with no producer inside the graph
         self._producer_cache: Optional[Dict[int, Tuple[PCGOp, int]]] = None
+
+    # -- loop regions --------------------------------------------------------
+    def loops(self) -> List[LoopRegion]:
+        """The graph's loop regions, from the marks on their ops; raises
+        ValueError on a region that is not whole (loop_problems)."""
+        problems = self.loop_problems()
+        if problems:
+            raise ValueError("; ".join(msg for _, msg in problems))
+        return self._regions()
+
+    def _regions(self) -> List[LoopRegion]:
+        by_name: Dict[str, List[PCGOp]] = {}
+        for op in self.topo_order():
+            if op.loop is not None:
+                by_name.setdefault(op.loop.name, []).append(op)
+        out = []
+        for name, ops in by_name.items():
+            entries = [op for op in ops if op.loop.role == "entry"]
+            exits = [op for op in ops if op.loop.role == "exit"]
+            if len(entries) == 1 and len(exits) == 1 and exits[0].outputs:
+                out.append(LoopRegion(name, ops[0].loop.steps, ops,
+                                      entries[0], exits[0].outputs[0]))
+        return out
+
+    def loop_problems(self) -> List[Tuple[PCGOp, str]]:
+        """What keeps a loop region from running as one body over its
+        steps: one entry (an identity) and one exit each, one step count,
+        an exit shaped as the source, and no tensor of the body read
+        outside the region but the exit. A rewrite that breaks one fails
+        check_correctness."""
+        marked = [op for op in self.ops if op.loop is not None]
+        if not marked:
+            return []
+        out: List[Tuple[PCGOp, str]] = []
+        by_name: Dict[str, List[PCGOp]] = {}
+        for op in marked:
+            by_name.setdefault(op.loop.name, []).append(op)
+        for name, ops in by_name.items():
+            roles = [op.loop.role for op in ops]
+            if roles.count("entry") != 1 or roles.count("exit") != 1:
+                out.append((ops[0], f"loop {name!r} needs one entry and one "
+                                    f"exit, has {roles.count('entry')} and "
+                                    f"{roles.count('exit')}"))
+                continue
+            if len({op.loop.steps for op in ops}) != 1:
+                out.append((ops[0], f"loop {name!r} has two step counts"))
+            entry = next(op for op in ops if op.loop.role == "entry")
+            exit_op = next(op for op in ops if op.loop.role == "exit")
+            if entry.op_type != OperatorType.OP_IDENTITY \
+                    or len(entry.inputs) != 1:
+                out.append((entry, f"loop {name!r}: its entry is no identity"))
+            elif not exit_op.outputs or exit_op.outputs[0].material_shape() \
+                    != entry.inputs[0].material_shape():
+                out.append((exit_op, f"loop {name!r}: the exit is not shaped "
+                                     "as the source it feeds back"))
+        inside = {op.guid: op.loop.name for op in marked}
+        exits = {op.outputs[0].guid for op in marked
+                 if op.loop.role == "exit" and op.outputs}
+        prod = self.producers()
+        for op in self.ops:
+            for t in op.inputs:
+                src = prod.get(t.guid)
+                if src is None or src[0].guid not in inside:
+                    continue
+                if inside.get(op.guid) != inside[src[0].guid] \
+                        and t.guid not in exits:
+                    out.append((op, f"{op.name} reads {src[0].name}'s output "
+                                    "from outside loop "
+                                    f"{inside[src[0].guid]!r}"))
+        return out
 
     def add_op(self, op: PCGOp) -> PCGOp:
         self.ops.append(op)
@@ -136,6 +240,8 @@ class Graph:
         h = 17
         for op in self.topo_order():
             key = (op.op_type, op.params)
+            if op.loop is not None:
+                key += (op.loop,)
             mv = op.machine_view.hash() if op.machine_view else 0
             h = hash((
                 h, key, mv,

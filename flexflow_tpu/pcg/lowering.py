@@ -50,6 +50,7 @@ def layers_to_pcg(layers: List[Layer]) -> Tuple[Graph, Dict[int, int]]:
             name=layer.name,
             layer_guid=layer.guid,
         )
+        op.loop = layer.loop
         opdef = get_op_def(layer.op_type)
         in_shapes = [pt.material_shape() for pt in in_pts]
         in_dtypes = [pt.data_type for pt in in_pts]

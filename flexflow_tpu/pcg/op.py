@@ -47,6 +47,8 @@ class PCGOp:
         self.layer_guid = layer_guid
         # initializer per weight name (resolved at executor init)
         self.initializers: Dict[str, object] = {}
+        # the loop region the op belongs to (pcg/graph.py LoopMark), or None
+        self.loop = None
 
     @property
     def is_parallel_op(self) -> bool:

@@ -572,7 +572,8 @@ class ServingConfig:
     share_prefixes: bool = True
     # prompts memoized for exact prefill-FLOP skipping (LRU entries of
     # (bucket, prompt) -> prefilled cache strip); 0 disables the skip
-    # while keeping page-level dedup
+    # while keeping page-level dedup, and so does a device that cannot
+    # hold this many strips beside the batch (_memo_entries)
     prefix_cache_entries: int = 8
     max_queue_depth: int = 64
     default_deadline_s: float = 30.0
@@ -892,6 +893,28 @@ class AdmissionQueue:
 # ----------------------------------------------------------------------
 # continuous (in-flight) batching
 # ----------------------------------------------------------------------
+def _device_free_bytes() -> Optional[int]:
+    """Bytes the first device has free now, or None where its backend
+    reports no memory (the CPU)."""
+    stats = jax.devices()[0].memory_stats() or {}
+    if "bytes_limit" not in stats or "bytes_in_use" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats["bytes_in_use"])
+
+
+def _memo_entries(free: Optional[int], strip: int, entries: int) -> int:
+    """How many prefilled strips of `strip` bytes the batcher's memo
+    keeps where the device has `free` bytes beside its weights, the batch's
+    caches and one admission's batch-1 cache: all `entries`, or none where
+    that many do not fit. A memo too large for the device is off, not cut
+    down: its strips would take the room a prefill or a decode step needs,
+    and `prefix_cache_entries` = 0 means the same. No bound but `entries`
+    where the backend reports no memory (`free` None)."""
+    if free is None or entries * strip <= free:
+        return entries
+    return 0
+
+
 @jax.jit
 def _best_ids(logits):
     """The best id of every row of a step's one-position output
@@ -1038,6 +1061,9 @@ class ContinuousBatcher:
         # params reproduce the identical strip, so replaying it is
         # bit-exact; bounded LRU, invalidated on decode retune.
         self._prefix_cache: "OrderedDict" = OrderedDict()
+        # strips the memo keeps: prefix_cache_entries, or 0 where the
+        # device cannot hold them (_size_memo, when the batch is made)
+        self._memo_entries = config.prefix_cache_entries
         # per-token service-time EWMA drives the "cannot meet deadline"
         # early shed; warms up after the first measured iterations
         self._token_ewma_s: Optional[float] = None
@@ -1248,8 +1274,7 @@ class ContinuousBatcher:
                                     self.model, self.pool.config, reserved),
                                 **self.pool.snapshot())
             cache_key = ((bucket, req.prompt.astype(self._id_dt).tobytes())
-                         if share and self.config.prefix_cache_entries > 0
-                         else None)
+                         if share and self._memo_entries > 0 else None)
             cached = (self._prefix_cache.get(cache_key)
                       if cache_key is not None else None)
         span.set(slot=slot_idx, bucket=bucket, skipped=cached is not None)
@@ -1269,15 +1294,12 @@ class ContinuousBatcher:
                 self.stats["prefill_tokens"] += plen
                 self.stats["prefill_bucket_tokens"] += bucket
                 self.stats["prefill_masked_tokens"] += bucket - plen
-                if cache_key is not None:
-                    self._prefix_cache[cache_key] = (first, caches1)
-                    while (len(self._prefix_cache)
-                           > self.config.prefix_cache_entries):
-                        self._prefix_cache.popitem(last=False)
         except BaseException:
             self.pool.release(seq_key)
             raise
         self._insert_slot(slot_idx, caches1, request=req.id)
+        if cache_key is not None and cached is None:
+            self._memo_put(cache_key, first, caches1)
         prefill_span.done()
         req.first_token_t = time.monotonic()
         req.token_t = [req.first_token_t]
@@ -1298,6 +1320,27 @@ class ContinuousBatcher:
         self._note_admitted_plen(plen)
         self._maybe_retire(slot_idx)
         return True
+
+    def _memo_put(self, key, first: int, caches1) -> None:
+        """Keep a prefilled strip for an exact replay, the least recently
+        used going first once the memo holds `_memo_entries`."""
+        memo = self._prefix_cache
+        memo[key] = (first, caches1)
+        while len(memo) > self._memo_entries:
+            memo.popitem(last=False)
+
+    def _size_memo(self, caches1) -> None:
+        """Size the memo when the batch's caches are made: the device's
+        free memory is read then, with the weights, the batch and this
+        admission's batch-1 cache `caches1` live, so that the strips the
+        memo keeps always leave room for the next admission's."""
+        jax.block_until_ready(self._caches)
+        strip = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(
+            {sec: caches1[sec] for sec in decode.SLOT_SECTIONS}))
+        self._memo_entries = _memo_entries(
+            _device_free_bytes(), strip, self.config.prefix_cache_entries)
+        while len(self._prefix_cache) > self._memo_entries:
+            self._prefix_cache.popitem(last=False)
 
     def _kv_exhausted(self, req: GenerationRequest,
                       e: KVCacheExhaustedError) -> bool:
@@ -1393,6 +1436,7 @@ class ContinuousBatcher:
     def _insert_slot_locked(self, slot_idx: int, caches1) -> None:
         if self._caches is None:
             self._caches = self._initB(self.model.state.params, ())
+            self._size_memo(caches1)
             self._note_state_bytes()
             for name in self._caches["counters"]:
                 self.stats.setdefault(name, 0)
@@ -1418,9 +1462,11 @@ class ContinuousBatcher:
                           help="bytes the decode slots hold of this kind "
                                "of per-slot state", replica=self.name)
         # the keys and values again, by what their leaves are: rings of a
-        # window's positions, and leaves of max_len
+        # window's positions, and leaves of max_len; and those of the ops
+        # inside a loop region, every step's
         for kind, held in decode.kv_bytes_by_kind(
-                self._caches, self.config.max_len).items():
+                self._caches, self.config.max_len,
+                looped=decode.looped_ops(self.model.executor.topo)).items():
             self.stats[f"kv_cache_bytes_{kind}"] = held
             obs.gauge_set("ff_serving_kv_cache_bytes", held,
                           help="bytes the decode slots hold of this kind "

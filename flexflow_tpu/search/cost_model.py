@@ -662,6 +662,18 @@ class CostModel:
         return cm
 
     def measure_operator_cost(self, op: PCGOp, view: MachineView) -> CostMetrics:
+        """The op's cost under `view`; an op inside a loop region runs once
+        a step, so its time (and a decode step's cache traffic with it) is
+        the steps' while its weights, and their gradient sync, are held
+        and paid once."""
+        cm = self._operator_cost(op, view)
+        steps = op.loop.steps if getattr(op, "loop", None) is not None else 1
+        if steps == 1:
+            return cm
+        return dataclasses.replace(cm, forward_time=steps * cm.forward_time,
+                                   backward_time=steps * cm.backward_time)
+
+    def _operator_cost(self, op: PCGOp, view: MachineView) -> CostMetrics:
         key = self._key(op, view)
         if key in self._cache:
             return self._cache[key]

@@ -87,6 +87,7 @@ def copy_graph(graph: Graph) -> Tuple[Graph, Dict[int, ParallelTensor]]:
         op2.weight_tags = list(getattr(op, "weight_tags", []))
         op2.initializers = dict(op.initializers)
         op2.machine_view = op.machine_view
+        op2.loop = op.loop
         g2.add_op(op2)
     return g2, tmap
 
@@ -134,7 +135,9 @@ class Substitution:
 
 
 def _find_ops(graph: Graph, op_type: OperatorType) -> List[PCGOp]:
-    return [o for o in graph.ops if o.op_type == op_type]
+    """The ops of `op_type` a rewrite may touch: none inside a loop region,
+    whose body runs as one program over its steps (FFModel.loop)."""
+    return [o for o in graph.ops if o.op_type == op_type and o.loop is None]
 
 
 def _partition_channel_combine(name: str, op_type, degree: int,
@@ -535,7 +538,8 @@ def fsdp_shard_weights(degree: int) -> Substitution:
             # insert_weight_shard rejects with ValueError
             return
         for op in graph.ops:
-            if op.is_parallel_op or not op.weights or not op.outputs:
+            if op.is_parallel_op or not op.weights or not op.outputs \
+                    or op.loop is not None:
                 continue
             out0 = op.outputs[0]
             if not out0.dims or out0.dims[0].is_replica_dim \
@@ -574,6 +578,7 @@ def fsdp_zero_shard(degree: int) -> Substitution:
 
         def eligible(op) -> bool:
             return (not op.is_parallel_op and bool(op.weights)
+                    and op.loop is None
                     and bool(op.outputs) and bool(op.outputs[0].dims)
                     and not op.outputs[0].dims[0].is_replica_dim
                     and op.outputs[0].dims[0].degree in (1, degree)

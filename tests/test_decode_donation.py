@@ -365,6 +365,8 @@ def test_once_served_no_step_is_built_again(donating):
     served after `_warmup_compiles` compiles a program, the insert into
     the first admission's fresh batch and into a stepped one included (the
     benchmark's `compiles_in_window` rests on this)."""
+    import threading
+
     import jax.monitoring
 
     from flexflow_tpu.parallel import executor as ex
@@ -384,7 +386,12 @@ def test_once_served_no_step_is_built_again(donating):
 
     ex._count_trace = lambda p: (traces.append(p), real(p))[1]
     try:
-        b._warmup_compiles()
+        # on a thread of its own, as the serve thread warms up and serves:
+        # JAX keys what it builds on thread-local state as well, which the
+        # test process's main thread may carry from tests run before it
+        warmup = threading.Thread(target=b._warmup_compiles)
+        warmup.start()
+        warmup.join()
         warm = list(traces)
         jax.monitoring.register_event_duration_secs_listener(on_event)
         b.start()  # warms again, from what is built
