@@ -924,6 +924,22 @@ def _best_ids(logits):
     return jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
 
 
+@jax.jit
+def _pick(logits, counters):
+    """A batched decode step's pick (`_best_ids`) and a copy of what the
+    step counted: the caches that hold the step's own counters go, donated,
+    into the step queued behind it, so the host fetches them from here."""
+    return _best_ids(logits), jax.tree_util.tree_map(jnp.copy, counters)
+
+
+@jax.jit
+def _feed(ids, host):
+    """A batched decode step's tokens, (rows, 1): each row's `host` token,
+    or where that is -1 the row's id in `ids`, the pick of the step before,
+    which never leaves the device."""
+    return jnp.where(host >= 0, host, ids.astype(host.dtype))[:, None]
+
+
 @dataclasses.dataclass
 class _Slot:
     req: GenerationRequest
@@ -932,6 +948,15 @@ class _Slot:
     tokens: List[int]
     prompt_len: int
     pos: int  # cache positions written == len(tokens) - 1
+
+
+@dataclasses.dataclass
+class _Queued:
+    """A batched decode step on the device whose ids the host has not
+    booked yet."""
+    rows: Dict[int, _Slot]  # row -> the occupant it was computed for
+    ids: jax.Array  # (slots,) int32: the pick
+    counters: dict  # what the step counted, copied by the pick
 
 
 class ContinuousBatcher:
@@ -947,10 +972,11 @@ class ContinuousBatcher:
          prefilled through a batch-1 decode step bucketed to powers of
          two (bounds recompilation), and the prefilled cache strip
          inserted into the running batch;
-      3. run ONE batched decode step for every active slot and, behind
-         it on the device, pick each row's best id: the iteration's one
+      3. queue the NEXT batched decode step for every slot that goes on,
+         fed this step's ids on the device, then book this step: its one
          sync fetches `slots` ids (and the step's counters), never the
-         slots x vocabulary logits.
+         slots x vocabulary logits, while the next step runs. Each step's
+         best ids are picked behind it on the device.
 
     Decoder-only models only (one graph input): encoder-decoder graphs
     compute per-request encoder statics that a shared running batch
@@ -1042,6 +1068,9 @@ class ContinuousBatcher:
         in_t = model._fit_input_tensors[-1]
         self._id_dt = in_t.data_type.np_dtype
         self._caches = None
+        # the step queued ahead of the host's bookkeeping, or None: nothing
+        # is queued when no slot goes on, or the loop is stopping
+        self._queued: Optional[_Queued] = None
         self.slots: List[Optional[_Slot]] = [None] * config.slots
         self._stop = threading.Event()
         self.dead = False
@@ -1094,6 +1123,10 @@ class ContinuousBatcher:
                       # bytes the decode iterations fetched, summed: over
                       # "iterations" it is 4 x slots and a few scalars
                       "decode_fetch_bytes": 0,
+                      # steps dispatched while the step before them was
+                      # not yet booked, and rows such a step computed for
+                      # a slot gone (EOS, a shed, an abort) when booked
+                      "decode_steps_ahead": 0, "decode_rows_discarded": 0,
                       "idle_s": 0.0, "prefill_tokens": 0,
                       "prefill_bucket_tokens": 0,
                       # bucket - prompt, summed: positions a prefill ran
@@ -1562,69 +1595,117 @@ class ContinuousBatcher:
             self._finish_slot(slot_idx)
 
     # -- the iteration loop ---------------------------------------------
+    def _rows(self, after: Optional[_Queued]) -> Dict[int, tuple]:
+        """The rows of the next batched step: row -> (slot, position, host
+        token), the token -1 where it is the row's id in `after`, the step
+        still on the device. A slot `after` computed goes on from that id,
+        unless the id is its last (`max_new_tokens`: it retires once `after`
+        is booked); any other slot (nothing queued, or admitted since) from
+        the token the host holds. An empty row is position 0, token 0."""
+        rows = {}
+        for i, slot in enumerate(self.slots):
+            if slot is None:
+                continue
+            if after is None or after.rows.get(i) is not slot:
+                rows[i] = (slot, slot.pos, slot.tokens[slot.pos])
+            elif (len(slot.tokens) - slot.prompt_len + 1
+                  < slot.req.max_new_tokens):
+                # a slot that ends by EOS, a shed or an abort once `after`
+                # is booked has this row discarded then; it writes inside
+                # the slot's own strip, which the next insert replaces
+                rows[i] = (slot, min(slot.pos + 1, self.config.max_len - 1),
+                           -1)
+        return rows
+
+    def _enqueue(self, caches, t_vec, host, prev):
+        """Dispatch one batched step over `caches` and its pick behind it:
+        the rows' tokens are `host`'s, or `prev`'s ids (the step before,
+        on the device) where `host` holds -1. Waits for nothing; returns
+        (ids, counters, caches), the caches consumed as the step's are."""
+        toks = _feed(host if prev is None else prev, host)
+        logits, caches = self._stepB(self.model.state.params, caches,
+                                     jnp.asarray(t_vec), [toks])
+        ids, counters = _pick(logits, caches["counters"])
+        return ids, counters, caches
+
+    def _dispatch(self, rows: Dict[int, tuple],
+                  after: Optional[_Queued]) -> _Queued:
+        """Queue the batched step over `rows` (`_rows(after)`)."""
+        from .. import obs
+
+        stats = self.stats
+        with obs.mark("ff.serve.decode.prepare", cat="serving",
+                      into=(stats, "decode_prepare_s")):
+            t_vec = np.zeros(self.config.slots, np.int32)
+            host = np.zeros(self.config.slots, self._id_dt)
+            for i, (slot, t, tok) in rows.items():
+                t_vec[i], host[i] = t, tok
+                if self.config.share_prefixes:
+                    # protocol guard: this step writes K/V at t. Only
+                    # full PROMPT blocks are ever published, and decode
+                    # positions sit strictly past them, so this is a
+                    # no-op in steady state — but if a shared page were
+                    # ever in the write path, the pool copies it private
+                    # here (COW) instead of letting the write leak into
+                    # siblings
+                    self.pool.note_write(slot.seq_key, t)
+        with self._device_lock, obs.mark(
+                "ff.serve.decode.dispatch", cat="serving",
+                into=(stats, "decode_dispatch_s")):
+            ids, counters, self._caches = self._enqueue(
+                self._caches, t_vec, host,
+                None if after is None else after.ids)
+        return _Queued({i: row[0] for i, row in rows.items()}, ids, counters)
+
     def _decode_iteration(self) -> dict:
-        """One batched step for every active slot; returns what it adds
-        to `stats` (`_step_counts`), which the loop folds in with its count
-        of the iteration."""
+        """Book one batched step, with the next one queued behind it on the
+        device first; returns what it adds to `stats` (`_step_counts`),
+        which the loop folds in with its count of the iteration."""
         from .. import obs
 
         stats = self.stats
         with obs.mark("ff.serve.decode", cat="serving",
                       into=(stats, "decode_s"), iteration=self._iteration,
                       occupancy=self.active_slots) as span:
-            with obs.mark("ff.serve.decode.prepare", cat="serving",
-                          into=(stats, "decode_prepare_s")):
-                t_vec = np.zeros(self.config.slots, np.int32)
-                toks = np.zeros((self.config.slots, 1), self._id_dt)
-                active = []
-                for i, slot in enumerate(self.slots):
-                    if slot is None:
-                        continue
-                    active.append(i)
-                    t_vec[i] = slot.pos
-                    toks[i, 0] = slot.tokens[slot.pos]
-                    if self.config.share_prefixes:
-                        # protocol guard: this step writes K/V at
-                        # slot.pos. Only full PROMPT blocks are ever
-                        # published, and decode positions sit strictly
-                        # past them, so this is a no-op in steady state —
-                        # but if a shared page were ever in the write
-                        # path, the pool copies it private here (COW)
-                        # instead of letting the write leak into siblings
-                        self.pool.note_write(slot.seq_key, slot.pos)
+            if self._queued is None:
+                # nothing on the device (the first step after an empty
+                # batch): this iteration's step goes from the host's tokens
+                self._queued = self._dispatch(self._rows(None), None)
+            step, self._queued = self._queued, None
+            # the next step before the wait, its tokens this step's ids
+            # where they lie: the device runs it while the host waits,
+            # fetches and books. Not when the loop is stopping
+            stopping = self._stop.is_set() or self.dead
+            rows = {} if stopping else self._rows(step)
             with self._device_lock:
-                with obs.mark("ff.serve.decode.dispatch", cat="serving",
-                              into=(stats, "decode_dispatch_s")):
-                    logits, self._caches = self._stepB(
-                        self.model.state.params, self._caches,
-                        jnp.asarray(t_vec), [jnp.asarray(toks)],
-                    )
-                    # each row's next token is picked behind the step, on
-                    # the device: both enqueue, neither waits
-                    ids = _best_ids(logits)
+                if rows:
+                    self._queued = self._dispatch(rows, step)
                 # ONE sync, split in two: the wait for the device, then
                 # the copy of `slots` ids to the host. The logits stay
                 # where they were made
                 with obs.mark("ff.serve.decode.wait", cat="serving",
                               into=(stats, "decode_wait_s")):
-                    jax.block_until_ready(ids)
+                    jax.block_until_ready(step.ids)
                 with obs.mark("ff.serve.decode.fetch", cat="serving",
                               into=(stats, "decode_fetch_s")) as fetch:
                     # what the step's ops counted (a few scalars, or
                     # nothing) rides the fetch of its ids
-                    fetched = jax.device_get(
-                        (ids, self._caches["counters"]))
+                    fetched = jax.device_get((step.ids, step.counters))
             ids = fetched[0].tolist()
             # a sampled request's share of the iteration: from the
             # ff.serve.decode span's start to the ids on the host
             span_dur = fetch.t0 + fetch.dur - span.t0
-            occupancy = len(active)
+            occupancy = len(step.rows)
+            discarded = 0
             with obs.mark("ff.serve.decode.sample", cat="serving",
                           into=(stats, "decode_sample_s")):
-                for i in active:
+                for i, was in step.rows.items():
                     slot = self.slots[i]
-                    if slot is None:
-                        continue  # taken by a concurrent teardown sweep mid-step
+                    if slot is not was:
+                        # retired, shed or taken by a teardown sweep since
+                        # the step was queued: the row's id is no one's
+                        discarded += 1
+                        continue
                     slot.tokens.append(ids[i])
                     slot.req.token_t.append(time.monotonic())
                     slot.pos += 1
@@ -1647,14 +1728,30 @@ class ContinuousBatcher:
                                                  pages=len(new_pages),
                                                  pos=slot.pos)
                     self._maybe_retire(i)
-        return self._step_counts(fetched)
+        counts = self._step_counts(fetched)
+        counts["decode_steps_ahead"] = (stats["decode_steps_ahead"]
+                                        + (self._queued is not None))
+        counts["decode_rows_discarded"] = (stats["decode_rows_discarded"]
+                                           + discarded)
+        return counts
+
+    def _settle(self) -> None:
+        """Wait for the step queued ahead, if any, and drop it unbooked: a
+        dying replica's, whose slots go back to the queue. Nothing is
+        queued after this, and no later program reads what it consumed."""
+        step, self._queued = self._queued, None
+        if step is not None:
+            try:
+                jax.block_until_ready(step.ids)
+            except Exception:  # fflint: disable=FFL002 — the replica is already dead
+                pass
 
     def _warmup_compiles(self) -> None:
-        """Compile the batched decode step, the pick of its rows' best
-        ids, every prefill bucket, the batch-1 cache's program and the
-        insert on throwaway caches before taking traffic. Runs on the serve
-        thread under the HealthMonitor's compile grace window; the running
-        batch then never waits on XLA mid-request."""
+        """Compile the batched decode step, the feed of its tokens, the pick
+        of its rows' best ids, every prefill bucket, the batch-1 cache's
+        program and the insert on throwaway caches before taking traffic.
+        Runs on the serve thread under the HealthMonitor's compile grace
+        window; the running batch then never waits on XLA mid-request."""
         params, donate = self.model.state.params, self._donates
         with self._device_lock:
             b = 1
@@ -1668,19 +1765,22 @@ class ContinuousBatcher:
                 if b >= self.config.max_len:
                     break
                 b = min(2 * b, self.config.max_len)
-            # the batched step and its pick, on caches made as the served
-            # ones are (a prefilled strip inserted into a fresh batch): the
-            # first served iteration then finds both programs built. The
-            # prefills above were only enqueued: the batch is made once they
-            # are done, as an admission makes it, so that the device never
-            # holds their temporaries beside both generations of a leaf
+            # the batched step, its feed and its pick as the loop queues
+            # them, on caches made as the served ones are (a prefilled
+            # strip inserted into a fresh batch): from the host's tokens,
+            # then fed the ids of the step before. The served iterations
+            # then find every program built. The prefills above were only
+            # enqueued: the batch is made once they are done, as an
+            # admission makes it, so that the device never holds their
+            # temporaries beside both generations of a leaf
             jax.block_until_ready(caches1)
             caches = decode.insert_row(self._initB(params, ()), caches1, 0,
                                        donate=donate)
-            t_vec = jnp.zeros((self.config.slots,), jnp.int32)
-            toks = jnp.zeros((self.config.slots, 1), self._id_dt)
-            logits, caches = self._stepB(params, caches, t_vec, [toks])
-            _best_ids(logits)
+            t_vec = np.zeros(self.config.slots, np.int32)
+            host = np.zeros(self.config.slots, self._id_dt)
+            ids = None
+            for _ in range(2):
+                ids, _, caches = self._enqueue(caches, t_vec, host, ids)
             # the insert again, into a stepped batch: a fresh batch (the
             # first admission's) is not yet placed as the step's output is,
             # and the program is built for each placement
@@ -1814,6 +1914,47 @@ class ContinuousBatcher:
                   replica=self.name, outcome=outcome,
                   **({"detail": detail[:200]} if detail else {}))
 
+    def _iterate(self) -> None:
+        """One decode iteration inside the health monitor's step window,
+        and its counts folded into `stats`."""
+        from .. import obs
+
+        it = self._iteration
+        if self.monitor is not None:
+            self.monitor.step_started(it)
+        t0 = time.monotonic()
+        if self.fault_injector is not None and not self._stop.is_set():
+            plan = self.fault_injector.fire("slow_worker", it,
+                                            replica=self.name)
+            if plan is not None:
+                # a wedged device/interconnect: the iteration stalls INSIDE
+                # the monitored step window so the HealthMonitor watchdog
+                # sees a hung step
+                time.sleep(float(plan.get("delay_s", 1.0)))
+        counts = self._decode_iteration()
+        dt = time.monotonic() - t0
+        if self.monitor is not None:
+            self.monitor.step_finished(it)
+        # each active sequence gains one token per iteration, so the
+        # iteration wall time IS the per-token service time. A sample
+        # counts for at most twice the estimate: one stalled iteration (a
+        # profiler starting, a host hiccup) would otherwise shed the long
+        # requests admitted after it, while a lasting slowdown still lifts
+        # it by a fifth an iteration
+        e = self._token_ewma_s
+        self._token_ewma_s = (
+            dt if e is None else 0.8 * e + 0.2 * min(dt, 2.0 * e))
+        self._iteration += 1
+        # ONE update: whoever a finished request wakes reads `stats` of
+        # whole iterations, never a step's counters without its count
+        self.stats.update(counts, iterations=self.stats["iterations"] + 1)
+        for name, value in counts.items():
+            obs.gauge_set("ff_serving_" + name, value,
+                          help="what the decode iterations counted under "
+                               "this name", replica=self.name)
+        obs.gauge_set("ff_serving_batch_occupancy", self.active_slots,
+                      help="occupied decode slots", replica=self.name)
+
     def _serve_loop(self) -> None:
         from .. import obs
 
@@ -1834,7 +1975,7 @@ class ContinuousBatcher:
                             f"replica {self.name} death injected at "
                             f"iteration {self._iteration}"
                         )
-                if self.active_slots == 0:
+                if self.active_slots == 0 and self._queued is None:
                     if self.draining:
                         return
                     if self._retune_wanted():
@@ -1845,50 +1986,22 @@ class ContinuousBatcher:
                                   session=False):
                         time.sleep(self.config.idle_wait_s)
                     continue
-                it = self._iteration
-                if self.monitor is not None:
-                    self.monitor.step_started(it)
-                t0 = time.monotonic()
-                if self.fault_injector is not None:
-                    plan = self.fault_injector.fire("slow_worker", it,
-                                                    replica=self.name)
-                    if plan is not None:
-                        # a wedged device/interconnect: the iteration
-                        # stalls INSIDE the monitored step window so the
-                        # HealthMonitor watchdog sees a hung step
-                        time.sleep(float(plan.get("delay_s", 1.0)))
-                counts = self._decode_iteration()
-                dt = time.monotonic() - t0
-                if self.monitor is not None:
-                    self.monitor.step_finished(it)
-                # each active sequence gains one token per iteration, so
-                # the iteration wall time IS the per-token service time. A
-                # sample counts for at most twice the estimate: one stalled
-                # iteration (a profiler starting, a host hiccup) would
-                # otherwise shed the long requests admitted after it, while
-                # a lasting slowdown still lifts it by a fifth an iteration
-                e = self._token_ewma_s
-                self._token_ewma_s = (
-                    dt if e is None else 0.8 * e + 0.2 * min(dt, 2.0 * e))
-                self._iteration += 1
-                # ONE update: whoever a finished request wakes reads
-                # `stats` of whole iterations, never a step's counters
-                # without its count
-                self.stats.update(
-                    counts, iterations=self.stats["iterations"] + 1)
-                for name, value in counts.items():
-                    obs.gauge_set("ff_serving_" + name, value,
-                                  help="what the decode iterations counted "
-                                       "under this name", replica=self.name)
-                obs.gauge_set("ff_serving_batch_occupancy",
-                              self.active_slots,
-                              help="occupied decode slots", replica=self.name)
+                self._iterate()
+            # stopping: the step queued ahead is booked by the loop's last
+            # iteration, which queues nothing behind it; a replica marked
+            # dead only waits for it (its slots go back to the queue)
+            if self._queued is not None:
+                if self.dead:
+                    self._settle()
+                else:
+                    self._iterate()
         except BaseException as e:  # replica died: hand off and stop
             self.dead = True
             self.death_cause = e
             logger.exception("serving replica %s died", self.name)
             obs.event("replica_died", cat="serving", replica=self.name,
                       error=type(e).__name__, detail=str(e)[:300])
+            self._settle()
             self._strand_slots()
             if self.on_dead is not None:
                 self.on_dead(self, e)

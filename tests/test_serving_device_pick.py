@@ -5,8 +5,8 @@ jitted argmax over the step's (slots, 1, vocabulary) output; the host
 blocks on and fetches `slots` ids and the step's counters. The CPU suite
 cannot see the time that saves. It holds that the served tokens are greedy
 decoding's, that the device's pick is numpy's (the first index of a
-maximum), what an iteration brings to the host, and that the pick is built
-in `_warmup_compiles` with the step.
+maximum), what an iteration brings to the host, and that the pick, and
+every other program the loop runs, is built in `_warmup_compiles`.
 """
 import jax
 import jax.numpy as jnp
@@ -17,7 +17,8 @@ from flexflow_tpu.runtime import serving
 from flexflow_tpu.runtime.serving import (AdmissionQueue, ContinuousBatcher,
                                           GenerationRequest,
                                           incremental_generate)
-from tests.test_decode_donation import hybrid, lm  # noqa: F401 (fixtures)
+from tests.test_decode_donation import (donating, hybrid,  # noqa: F401
+                                       lm)
 from tests.test_serving import VOCAB, _serve_cfg
 
 SLOTS = 3
@@ -145,27 +146,33 @@ def test_an_iteration_fetches_ids_not_logits(lm, inside, monkeypatch,
 
 # -- (e) built with the step ------------------------------------------------------
 @pytest.mark.parametrize("kind", ["attention", "hybrid"])
-def test_after_warmup_an_iteration_compiles_nothing(kind, request, inside):
-    """`_warmup_compiles` builds the batched step and the pick on caches
-    made as the served ones are: no served iteration traces, compiles or
-    loads a program."""
+def test_after_warmup_an_iteration_compiles_nothing(kind, request, donating):
+    """From empty in-memory caches and no persistent one, `_warmup_compiles`
+    builds the batched step, the feed of its tokens (from the host's and
+    from the ids of the step before), the pick, every prefill bucket, the
+    batch-1 cache and the insert, on caches made as the served ones are:
+    serving then traces, compiles or loads no program on any thread."""
+    import threading
+
     import jax.monitoring
 
     m, vocab = _model(kind, request)
-    events = []
+    events, after = [], threading.Event()
 
     def on_event(name, *_args, **kw):
-        if inside and name.startswith(("/jax/core/compile",
-                                       "/jax/compilation_cache")):
+        if after.is_set() and name.startswith(("/jax/core/compile",
+                                               "/jax/compilation_cache")):
             events.append((name, kw.get("fun_name")))
 
+    jax.clear_caches()
     b = ContinuousBatcher(m, _serve_cfg(slots=SLOTS),
                           AdmissionQueue(max_depth=16))
     b._warmup_compiles()
     jax.monitoring.register_event_duration_secs_listener(on_event)
+    after.set()
     try:
-        _serve(b, _requests(vocab, 5, seed=11))
+        _serve(b, _requests(vocab, 6, seed=11))
     finally:
         jax.monitoring.unregister_event_duration_listener(on_event)
-    assert b.stats["iterations"] >= 5
+    assert b.stats["iterations"] >= 5 and b.stats["decode_steps_ahead"] > 0
     assert events == []
